@@ -27,13 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.kernels import MAX_FUSED_K, _mask_hi, _merge_topk, use_pallas
-
-try:  # CPU interpret-mode tests import pltpu too
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 _I0 = np.int32(0)
 
@@ -268,7 +264,7 @@ def ann_gather_scan(
     auxd_slots = slot_aux(ann_dev["sq"], similarity)
     aux_q = query_aux(qvecs, similarity)
     tile_bytes = B * P * L * (D if tier == "int8" else 4 * D)
-    pallas_ok = kb <= MAX_FUSED_K and pltpu is not None
+    pallas_ok = kb <= MAX_FUSED_K
     if interpret is None:
         if not use_pallas(score_bytes=tile_bytes) or not pallas_ok:
             return _ann_scan_chunked(

@@ -8,9 +8,12 @@ common/breaker/ChildMemoryCircuitBreaker).
 
 The TPU analog budgets HBM instead of JVM heap: the long-lived child
 ("fielddata" here, as in the reference) accounts device-resident index
-packs; "request" accounts transient per-search scratch. The parent bound
-is the device memory the process may use. Budget defaults to the real
-accelerator memory when JAX exposes it, else 4GB host-mode."""
+packs; "request" accounts transient per-search scratch. Every budget is
+PER DEVICE: `total` is one device's memory (local devices are of one
+kind), percent limits are shares of it, and a caller charges what the
+most loaded device will hold — a pack spread over a mesh of S shard
+devices costs each of them one S-th (engine.EsIndex._account_packs), a
+pack without a mesh sits whole on the first device."""
 
 from __future__ import annotations
 
@@ -38,19 +41,19 @@ class CircuitBreakingError(ElasticsearchTpuError):
         return d
 
 
-def detect_device_memory_bytes() -> int:
-    try:
-        import jax
+HOST_MODE_BYTES = 4 << 30  # the CPU client reports no memory limit
 
-        d = jax.devices()[0]
-        stats = getattr(d, "memory_stats", None)
-        if callable(stats):
-            st = stats() or {}
-            if "bytes_limit" in st:
-                return int(st["bytes_limit"])
-    except Exception:
-        pass
-    return 4 << 30  # host-mode fallback
+
+def detect_device_memory_bytes() -> int:
+    """One local device's memory. An accelerator that cannot say how much
+    it has is an error: a guessed budget admits or refuses the wrong
+    packs without a word."""
+    import jax
+
+    d = jax.local_devices()[0]
+    if d.platform == "cpu":
+        return HOST_MODE_BYTES
+    return int(d.memory_stats()["bytes_limit"])
 
 
 class ChildBreaker:

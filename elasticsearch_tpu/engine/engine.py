@@ -689,8 +689,7 @@ class EsIndex:
             with refresh_stage("route"):
                 routed = self._route_docs(visible)
             sp = build_stacked_pack_routed(routed, self.mappings)
-            if self._breaker_account is not None:
-                self._breaker_account(sp.nbytes())
+            self._account_packs(sp.nbytes(), base.mesh)
             searcher = StackedSearcher(sp, mesh=base.mesh)
             # ---- atomic install: nothing above touched serving state
             self._invalidate_request_cache()
@@ -710,6 +709,15 @@ class EsIndex:
             )
             self._base_nbytes = sp.nbytes()
 
+    def _account_packs(self, nbytes: int, mesh) -> None:
+        """Admit `nbytes` of this index's packs before they ship. The
+        breaker budgets one device: a mesh spreads the pack's equal-shaped
+        shards over its "shards" axis, so each device holds one share;
+        without a mesh the whole pack sits on the first device."""
+        if self._breaker_account is not None:
+            spread = mesh.shape["shards"] if mesh is not None else 1
+            self._breaker_account(-(-nbytes // spread))
+
     def _refresh_full(self, mesh=None):
         """Rebuild everything from live docs (a full merge: one sealed base,
         no tail, stats reset to live-only)."""
@@ -723,13 +731,12 @@ class EsIndex:
         with refresh_stage("route"):
             routed = self._route_docs(live_docs)
         sp = build_stacked_pack_routed(routed, self.mappings)
-        if self._breaker_account is not None:
-            # admission control BEFORE shipping to the device: on trip, the
-            # old searcher stays live (HierarchyCircuitBreakerService analog)
-            self._breaker_account(sp.nbytes())
         if mesh is None:
             mesh = (self._searcher.mesh if self._searcher is not None
                     else make_mesh(self.num_shards))
+        # admission control BEFORE shipping to the device: on trip, the
+        # old searcher stays live (HierarchyCircuitBreakerService analog)
+        self._account_packs(sp.nbytes(), mesh)
         self._invalidate_request_cache()
         self._searcher = StackedSearcher(sp, mesh=mesh)
         self.shard_docs = routed
@@ -849,10 +856,10 @@ class EsIndex:
         # from df before promising an exact count (sharded._wand_plan)
         seg_sp.dead_count = sum(
             getattr(s.sp, "dead_count", 0) for s in self.tier_searchers())
-        if self._breaker_account is not None:
-            self._breaker_account(
-                self._base_nbytes
-                + sum(seg.nbytes for seg in self._tails) + seg_sp.nbytes())
+        self._account_packs(
+            self._base_nbytes
+            + sum(seg.nbytes for seg in self._tails) + seg_sp.nbytes(),
+            base.mesh)
         ordinal = len(self._tails)
         seg = _TailSegment(
             searcher=None, shard_docs=routed,
@@ -945,8 +952,7 @@ class EsIndex:
             sp = build_stacked_pack_routed(routed, self.mappings,
                                            dense_min_df=1 << 62)
             sp.dead_count = getattr(base.sp, "dead_count", 0)
-            if self._breaker_account is not None:
-                self._breaker_account(self._base_nbytes + sp.nbytes())
+            self._account_packs(self._base_nbytes + sp.nbytes(), base.mesh)
             merged = _TailSegment(
                 searcher=None, shard_docs=routed,
                 pos={doc_id: (s, d)
@@ -2493,6 +2499,11 @@ class Engine:
         out["tail_docs"] = tail
         out["base_docs"] = base
         out["refresh_lag_ms"] = round(lag, 3)
+        from ..native import get_lib
+
+        # which index accumulator this process loaded (the pure-Python
+        # fallback of a failed g++ build is ~4x slower and silent)
+        out["accumulator"] = "native" if get_lib() is not None else "python"
         if per_index:
             out["tail_by_index"] = per_index
         from ..telemetry import metrics

@@ -38,38 +38,25 @@ import os
 # device peak rates
 # ---------------------------------------------------------------------------
 
-# device_kind substring -> (peak bf16 matmul FLOP/s, peak HBM bytes/s).
-# Public spec-sheet numbers; first match wins (checked in order).
-DEVICE_PEAKS: list[tuple[str, float, float]] = [
-    ("v6e", 918e12, 1640e9),   # Trillium
-    ("v5p", 459e12, 2765e9),
-    ("v5e", 197e12, 819e9),    # the bench target (BENCH_NOTES.md)
-    ("v5", 197e12, 819e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-]
+# device_kind, exactly as `jax.devices()[0].device_kind` reports it ->
+# (peak bf16 matmul FLOP/s, peak HBM bytes/s, peak per-chip ICI bytes/s —
+# links x per-link rate, both directions summed the way the HBM number
+# is). One row per kind that has been seen on a chip, with the public
+# spec-sheet numbers of that chip. A TPU kind missing here is an error,
+# never a default: a new device must not inherit another's roofline, and
+# its row is added with the string the device itself reports.
+DEVICE_PEAKS: dict[str, tuple[float, float, float]] = {
+    # v5e: kind as reported on the chip (chip_smoke.py, PR 22); peaks
+    # from the Cloud TPU v5e page (197 TFLOP/s bf16, 819 GB/s HBM,
+    # 4 ICI links x 400 Gbps)
+    "TPU v5 lite": (197e12, 819e9, 200e9),
+}
 
 # CPU fallback: a nominal 32-vCPU host (AVX2 f32 FMA ~100 GFLOP/s/core
 # is generous; utilization numbers on CPU are illustrative only — the
 # cost model's flops/bytes stay exact, only the denominator is nominal)
 CPU_PEAK_FLOPS = 3.2e12
 CPU_PEAK_BW = 100e9
-
-# device_kind substring -> peak per-chip ICI (interchip interconnect)
-# bytes/s — the denominator for the collective kernels' traffic
-# (sharded.allgather_topk / sharded.global_merge, PR 10). Public
-# spec-sheet aggregates (links x per-link rate, both directions summed
-# the way the HBM number is); first match wins.
-DEVICE_ICI_PEAKS: list[tuple[str, float]] = [
-    ("v6e", 448e9),    # Trillium: 4 x 896 Gbps
-    ("v5p", 600e9),    # 6 x 800 Gbps
-    ("v5e", 200e9),    # 4 x 400 Gbps
-    ("v5", 200e9),
-    ("v4", 300e9),     # 6 x 400 Gbps
-    ("v3", 162e9),
-    ("v2", 62e9),
-]
 
 # virtual CPU meshes move "collectives" through memcpy; nominal only
 CPU_PEAK_ICI = 50e9
@@ -82,11 +69,8 @@ def ici_peak() -> float:
     if env:
         return float(env)
     _f, _b, kind = device_peaks()
-    lk = kind.lower().replace(" ", "")
-    for pat, bw in DEVICE_ICI_PEAKS:
-        if pat in lk:
-            return bw
-    return CPU_PEAK_ICI
+    row = DEVICE_PEAKS.get(kind)
+    return row[2] if row is not None else CPU_PEAK_ICI
 
 _peaks_cache: tuple[float, float, str] | None = None
 
@@ -100,21 +84,17 @@ def device_peaks() -> tuple[float, float, str]:
             os.environ.get("ES_TPU_PEAK_FLOPS")
             or os.environ.get("ES_TPU_PEAK_BW")):
         return _peaks_cache
-    kind = "cpu"
-    flops, bw = CPU_PEAK_FLOPS, CPU_PEAK_BW
-    try:
-        import jax
+    import jax
 
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", d.platform) or d.platform
-        if d.platform == "tpu":
-            lk = kind.lower().replace(" ", "")
-            for pat, f, b in DEVICE_PEAKS:
-                if pat in lk:
-                    flops, bw = f, b
-                    break
-    except Exception:  # noqa: BLE001 - no backend: nominal CPU peaks
-        pass
+    d = jax.devices()[0]
+    kind = getattr(d, "device_kind", d.platform) or d.platform
+    flops, bw = CPU_PEAK_FLOPS, CPU_PEAK_BW
+    if d.platform == "tpu":
+        if kind not in DEVICE_PEAKS:
+            raise ValueError(
+                f"no peak rates for TPU device kind [{kind}] — add its "
+                "row to monitoring/costmodel.DEVICE_PEAKS")
+        flops, bw, _ici = DEVICE_PEAKS[kind]
     env_f = os.environ.get("ES_TPU_PEAK_FLOPS")
     env_b = os.environ.get("ES_TPU_PEAK_BW")
     if env_f:

@@ -11,6 +11,7 @@ waste its fixed-shape compilation discipline trades for compile reuse.
 
 from __future__ import annotations
 
+import math
 import threading
 
 from ..telemetry import metrics
@@ -81,8 +82,8 @@ def device_memory_snapshot() -> dict:
     """Live device-array bytes (exact: jax.live_arrays) plus the
     allocator's own view when the backend exposes one (TPU memory_stats:
     bytes_in_use / peak_bytes_in_use / bytes_limit; CPU returns none).
-    The live/peak pair is the "driver-recorded device-bound proof"
-    VERDICT asked for: HBM residency measured, not asserted."""
+    The live/peak pair is the driver-recorded device-bound proof: HBM
+    residency measured, not asserted."""
     import jax
 
     out: dict = {"backend": None, "device_kind": None,
@@ -93,12 +94,32 @@ def device_memory_snapshot() -> dict:
         out["device_kind"] = getattr(d, "device_kind", d.platform)
         live = 0
         count = 0
+        # every local device, not only the first: a sharded pack that
+        # silently landed on one chip shows here as three empty rows.
+        # Shard bytes come from the sharding's shapes: touching
+        # `shard.data` would create per-shard arrays that the next call
+        # counts as live too
+        per_dev = {ld.id: {"id": ld.id, "live_bytes": 0}
+                   for ld in jax.local_devices()}
         for a in jax.live_arrays():
             try:
                 live += a.nbytes
                 count += 1
+                shard_bytes = a.dtype.itemsize * math.prod(
+                    a.sharding.shard_shape(a.shape))
+                for d in a.sharding.addressable_devices:
+                    per_dev[d.id]["live_bytes"] += shard_bytes
             except Exception:  # noqa: BLE001 - deleted buffer race
                 continue
+        for ld in jax.local_devices():
+            try:
+                ms = ld.memory_stats() or {}
+            except Exception:  # noqa: BLE001 - backend without memory stats
+                ms = {}
+            for key in ("bytes_in_use", "peak_bytes_in_use"):
+                if key in ms:
+                    per_dev[ld.id][key] = int(ms[key])
+        out["devices"] = list(per_dev.values())
         out["live_arrays"] = count
         out["live_bytes"] = int(live)
         out["device_count"] = len(jax.devices())

@@ -333,10 +333,9 @@ class _RawChunks:
     """Unsynchronized per-chunk device outputs of a chunked batch run.
 
     Deliberately NOT a flat device array: any eager device op issued on
-    not-yet-ready outputs (a concatenate, even a [:Q] slice) acts as a
-    dispatch barrier under remote runtimes — measured to serialize
-    multi-group batches ~6x. Stitching therefore happens host-side in
-    numpy after ONE device_get of everything (tuple(self) or np.asarray
+    not-yet-ready outputs (a concatenate, even a [:Q] slice) waits for
+    them and so serializes multi-group batches. Stitching therefore
+    happens host-side in numpy after ONE device_get of everything (tuple(self) or np.asarray
     via __iter__/resolve)."""
 
     def __init__(self, chunk_outs: list, Q: int, n_out: int):
@@ -489,8 +488,8 @@ class BatchTermSearcher:
             SCORE_BYTES_BUDGET, so the query axis is chunked;
           - chunks upload as per-chunk host slices, NOT device-side slices
             of one big array: any eager device op on a not-yet-ready
-            buffer (a slice included) acts as a dispatch barrier under
-            remote runtimes and serializes the whole batch;
+            buffer (a slice included) acts as a dispatch barrier and
+            serializes the whole batch;
           - for the same reason the outputs return UNRESOLVED
             (_RawChunks): no concatenate/[:Q] happens on device — callers
             stitch host-side after one device_get;
@@ -968,8 +967,8 @@ class BatchTermSearcher:
             parts.append((idxs, _run_first(plan)))
         # resolve every group with ONE device round-trip, and only after
         # every group was dispatched (no intermediate eager ops: those act
-        # as dispatch barriers under remote runtimes). Plain-array groups
-        # (the dense-only fused path under fast=False) join the same fetch.
+        # as dispatch barriers). Plain-array groups (the dense-only fused
+        # path under fast=False) join the same fetch.
         from ..telemetry import profile_event, time_kernel
 
         tier = ("impact" if use_impact else "fast") if fast else "exact"

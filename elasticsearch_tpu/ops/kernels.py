@@ -46,13 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _I0 = np.int32(0)  # index-map constant: python ints trace to i64 under x64
-
-try:  # pltpu import works on CPU too (needed for interpret-mode tests)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 _I32_MAX = np.int32(2**31 - 1)
 
@@ -497,8 +493,10 @@ def _impact_gather_kernel(rows_ref, w_ref, *refs, g):
     for i in range(g):
         c_ref = refs[i]
         d_ref = refs[g + i]
-        os_ref[0, i, :] = w_ref[0, i] * c_ref[0, :].astype(jnp.float32)
-        oi_ref[0, i, :] = d_ref[0, :]
+        # i32 hop: Mosaic has no direct u16/i8 -> f32 convert
+        codes = c_ref[...].astype(jnp.int32).astype(jnp.float32)
+        os_ref[i:i + 1, :] = w_ref[:, i:i + 1] * codes
+        oi_ref[i:i + 1, :] = d_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("g", "interpret"))
@@ -506,22 +504,31 @@ def _impact_gather_pallas(codes, docids, rows, row_w, *, g, interpret):
     Q, R = rows.shape  # R is a multiple of g (caller pads with row 0)
     block = codes.shape[1]
     kernel = functools.partial(_impact_gather_kernel, g=g)
+    # Mosaic wants a block's last two dims divisible by (8, 128) or equal
+    # to the array's: one gathered [BLOCK] row and one [g] weight group
+    # become whole trailing (1, BLOCK) / (1, g) planes of a reshaped
+    # array, the leading (gather) dims squeezed out of the kernel's view
+    codes3 = codes.reshape(-1, 1, block)
+    docids3 = docids.reshape(-1, 1, block)
+    w4 = row_w.reshape(Q, R // g, 1, g)
 
-    def _row_spec(arr, gi):
+    def _row_spec(gi):
         return pl.BlockSpec(
-            (1, block), lambda q, j, r, _gi=gi: (r[q, j * g + _gi], _I0))
+            (None, 1, block),
+            lambda q, j, r, _gi=gi: (r[q, j * g + _gi], _I0, _I0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q, R // g),
         in_specs=(
-            [pl.BlockSpec((1, g), lambda q, j, r: (q, j))]
-            + [_row_spec(codes, gi) for gi in range(g)]
-            + [_row_spec(docids, gi) for gi in range(g)]
+            [pl.BlockSpec((None, None, 1, g),
+                          lambda q, j, r: (q, j, _I0, _I0))]
+            + [_row_spec(gi) for gi in range(g)]
+            + [_row_spec(gi) for gi in range(g)]
         ),
         out_specs=[
-            pl.BlockSpec((1, g, block), lambda q, j, r: (q, j, _I0)),
-            pl.BlockSpec((1, g, block), lambda q, j, r: (q, j, _I0)),
+            pl.BlockSpec((None, g, block), lambda q, j, r: (q, j, _I0)),
+            pl.BlockSpec((None, g, block), lambda q, j, r: (q, j, _I0)),
         ],
     )
     out_s, out_i = pl.pallas_call(
@@ -532,7 +539,7 @@ def _impact_gather_pallas(codes, docids, rows, row_w, *, g, interpret):
             jax.ShapeDtypeStruct((Q, R, block), jnp.int32),
         ],
         interpret=interpret,
-    )(rows, row_w, *([codes] * g), *([docids] * g))
+    )(rows, w4, *([codes3] * g), *([docids3] * g))
     return out_i.reshape(Q, R * block), out_s.reshape(Q, R * block)
 
 
@@ -566,13 +573,10 @@ def impact_gather(
     if pad:
         rows = jnp.pad(rows, ((0, 0), (0, pad)))
         row_w = jnp.pad(row_w, ((0, 0), (0, pad)))
-    pallas_ok = pltpu is not None
     if interpret is None:
-        if not use_pallas(score_bytes=Q * (R + pad) * block * 8) or not pallas_ok:
+        if not use_pallas(score_bytes=Q * (R + pad) * block * 8):
             return _impact_gather_xla(codes, docids, rows, row_w)
         interpret = jax.default_backend() != "tpu"
-    if not pallas_ok:
-        return _impact_gather_xla(codes, docids, rows, row_w)
     return _impact_gather_pallas(
         codes, docids, rows, row_w, g=g, interpret=bool(interpret))
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.scoring import top_k_with_total
+from ..ops.scoring import top_k_with_total, topk_mode
 from ..query.dsl import parse_query
 from ..utils.jax_env import shard_map
 from ..utils.errors import IllegalArgumentError
@@ -462,6 +462,9 @@ class StackedSearcher:
             return g_scores, g_shard, g_doc, tot.sum(), agg_out
 
         fn = jax.jit(run)
+        # the selection tier this program is built with, decided once
+        # here: the Pallas streamed scan (fused_scan) or lax.top_k
+        fn.topk_tier = topk_mode(n, k_local)
         self._cache[cache_key] = fn
         return fn
 
@@ -1314,9 +1317,8 @@ class StackedSearcher:
     def search_batch(self, requests: list[dict]) -> list:
         """Execute several search/agg requests with batched device
         round-trips: every request's program is dispatched before any
-        result is fetched, so the fixed dispatch+fetch latency (the
-        dominant cost of a single agg request through a remote runtime —
-        BENCH_NOTES.md) is paid once per WAVE, not once per request.
+        result is fetched, so the fixed dispatch+fetch latency is paid
+        once per WAVE, not once per request.
         Two waves maximum: pass-1 for everything, then pass-2 for
         requests whose high-cardinality terms aggs use the two-pass
         candidate scheme. Each request dict: query (dict | QueryNode |
@@ -1375,7 +1377,9 @@ class StackedSearcher:
         k = min(max(size + from_, 1), max(self.sp.n_max * self.sp.S, 1))
         fn = self._compiled(node, tuple(keys), k, agg_nodes, agg_key)
         from ..monitoring.xla_introspect import check_dispatch
+        from ..telemetry import metrics
 
+        metrics.counter_inc("es.search.topk." + fn.topk_tier)
         check_dispatch("sharded.spmd_topk", fn,
                        (self.dev, params, agg_params),
                        fields={"queries": 1, "k": k,
@@ -2389,7 +2393,7 @@ def _fused_sharded_for(ss: "StackedSearcher"):
     pack shape can never qualify (no dense tier / no pallas)."""
     from ..ops import fused as F
 
-    if F.pltpu is None or F.fused_enabled() == "0":
+    if F.fused_enabled() == "0":
         return None
     if getattr(ss.sp, "dense_tf", None) is None or "dense_tfn" not in ss.dev:
         return None

@@ -296,20 +296,18 @@ def maybe_init_distributed() -> bool:
     process to the slice-wide device mesh (coordinator address +
     ES_TPU_DIST_NPROCS / ES_TPU_DIST_RANK) so `jax.devices()` spans
     every node and the same pjit programs compile slice-wide. Off by
-    default; failures log and degrade to the single-process mesh."""
+    default; a failed initialize raises — a node asked to join a mesh
+    must not carry on alone."""
     global _dist_initialized
     coord = os.environ.get("ES_TPU_DIST_COORD")
     if not coord or _dist_initialized:
         return _dist_initialized
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coord,
-            num_processes=int(os.environ.get("ES_TPU_DIST_NPROCS", "1")),
-            process_id=int(os.environ.get("ES_TPU_DIST_RANK", "0")),
-        )
-        _dist_initialized = True
-    except Exception:  # noqa: BLE001 - degrade to single-process
-        _dist_initialized = False
+    jax.distributed.initialize(
+        coordinator_address=coord,
+        num_processes=int(os.environ.get("ES_TPU_DIST_NPROCS", "1")),
+        process_id=int(os.environ.get("ES_TPU_DIST_RANK", "0")),
+    )
+    _dist_initialized = True
     return _dist_initialized
 
 

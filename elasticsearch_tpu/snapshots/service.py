@@ -425,11 +425,11 @@ class SnapshotService:
                             max_seq = max(max_seq, r.get("seq_no", 0))
                 routed.append(lst)
             sp = StackedPack(shards, idx.mappings)
-            if idx._breaker_account is not None:
-                # same admission control as every refresh-built searcher:
-                # a frozen mount must not overcommit device memory
-                idx._breaker_account(sp.nbytes())
-            idx._searcher = StackedSearcher(sp, mesh=make_mesh(len(shards)))
+            mesh = make_mesh(len(shards))
+            # same admission control as every refresh-built searcher:
+            # a frozen mount must not overcommit device memory
+            idx._account_packs(sp.nbytes(), mesh)
+            idx._searcher = StackedSearcher(sp, mesh=mesh)
             idx.shard_docs = routed
             idx._tail = None
             idx._tail_shard_docs = []

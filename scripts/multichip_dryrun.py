@@ -5,13 +5,11 @@ Runs `__graft_entry__.dryrun_multichip` — the production sharded stack
 asserted against single-device AND the shard_map fallback — and exits
 nonzero on any divergence.
 
-Gate semantics (tier1_gate.sh wires this in):
-  * jax.device_count() > 1 (a real slice): the check ENFORCES — a red
-    exits 1.
-  * single-device CPU: the dry run re-launches in a subprocess with 8
-    virtual CPU devices and the same checks run ADVISORY — failures
-    print but exit 0 (the virtual mesh is a lowering approximation, not
-    the target platform).
+A CPU tool: the child process gets `JAX_PLATFORMS=cpu` and 8 virtual CPU
+devices outright (a chip belongs to one process at a time; the four-chip
+path on hardware is `chip_smoke.py --chips 4`). The virtual mesh is a
+lowering approximation of the target platform, but parity is parity: a
+divergence exits 1.
 
 Optionally writes the MULTICHIP_rNN.json record shape with --record.
 """
@@ -26,31 +24,18 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _device_count(env) -> int:
-    out = subprocess.run(
-        [sys.executable, "-c", "import jax; print(len(jax.devices()))"],
-        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
-    try:
-        return int(out.stdout.strip().splitlines()[-1])
-    except Exception:  # noqa: BLE001 - no backend at all
-        return 1
-
-
 def main() -> int:
     record_path = None
     args = sys.argv[1:]
     if "--record" in args:
         record_path = args[args.index("--record") + 1]
 
+    n = 8
     env = dict(os.environ)
-    have = _device_count(env)
-    enforcing = have > 1
-    n = have if enforcing else 8
-    if not enforcing:
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count={n}"
-                            ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + f" --xla_force_host_platform_device_count={n}"
+                        ).strip()
 
     out = subprocess.run(
         [sys.executable, "-c",
@@ -58,23 +43,22 @@ def main() -> int:
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
     ok = out.returncode == 0
     tail = (out.stdout.strip().splitlines() or [""])[-1]
-    mode = "enforcing" if enforcing else "advisory (virtual CPU mesh)"
     print(tail)
     if not ok:
         err_tail = "\n".join(out.stderr.strip().splitlines()[-8:])
-        print(f"[multichip-dryrun] FAILED ({mode}):\n{err_tail}",
+        print(f"[multichip-dryrun] FAILED (virtual CPU mesh):\n{err_tail}",
               file=sys.stderr)
     else:
-        print(f"[multichip-dryrun] OK ({mode}, {n} devices)")
+        print(f"[multichip-dryrun] OK (virtual CPU mesh, {n} devices)")
     if record_path:
         rec = {"n_devices": n, "rc": out.returncode, "ok": ok,
-               "skipped": False, "enforcing": enforcing,
+               "skipped": False, "enforcing": True,
                "tail": out.stdout}
         if not ok:
             rec["stderr_tail"] = out.stderr[-2000:]
         with open(record_path, "w") as f:
             json.dump(rec, f, indent=1)
-    return (1 if (not ok and enforcing) else 0)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -1,0 +1,227 @@
+"""Compile the served lexical path's kernels for a described TPU v5e at
+the 1M-doc C1 width, with no chip attached (on-chip-measurement guide,
+section 2, rehearsal 3): what the chip's compiler refuses surfaces here
+at no chip time. Nothing runs, so these say nothing about results or
+times — a pass is not a chip run.
+
+Everything built from the topology lives in fixtures/tests (only one
+process may load libtpu; under xdist only the worker given this file
+does), and the persistent compile cache is off around the compiles (an
+AOT entry written here cannot be read back without a chip).
+"""
+
+import functools
+import os
+import types
+
+import numpy as np
+import pytest
+
+N_DOCS = 1_000_000   # bench.py C1 (chip_smoke.py --docs 1000000)
+V_DENSE = 896        # dense-tier rows at that corpus (bench.py preflight)
+N_BLOCKS = 400_000   # ~40M postings / 128 lanes, plus per-term padding
+TOP_K = 10
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices[:4]), ("shards",))
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from elasticsearch_tpu.utils.jax_env import ensure_x64
+
+    ensure_x64()
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fused_geometry(n_docs):
+    """The geometry FusedTermSearcher / _FusedShardedMsearch derive for a
+    pack of n_docs with the C1 dense tier (bench.py preflight recipe)."""
+    from elasticsearch_tpu.ops import fused as F
+
+    qsub = F._cfg_qsub()
+    vp2 = -(-2 * V_DENSE // 128) * 128
+    tile_n = min(F._cfg_tile(), F.auto_tile_matmul(vp2, qsub))
+    n_pad = -(-n_docs // tile_n) * tile_n
+    return qsub, vp2, tile_n, n_pad
+
+
+@pytest.mark.parametrize("bud", [16, 512])
+def test_fused_tile_candidates_compiles(one_chip, no_persistent_cache, bud):
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import fused as F
+
+    qsub, vp2, tile_n, n_pad = _fused_geometry(N_DOCS)
+    assert (qsub, vp2, tile_n) == (256, 1792, 4096)
+    njc, njf = n_pad // tile_n, n_pad // F.FINE_N
+    rows = 8 * bud
+    compiled = F.fused_tile_candidates.lower(
+        None,
+        _sds((1, n_pad), jnp.float32, one_chip),
+        _sds((rows, 128), jnp.int32, one_chip),
+        _sds((rows, 128), jnp.int32, one_chip),
+        _sds(((F.QC // qsub) * (njf + 1),), jnp.int32, one_chip),
+        w=_sds((F.QC, vp2), jnp.bfloat16, one_chip),
+        tstack=_sds((vp2, n_pad), jnp.bfloat16, one_chip),
+        t=F.tile_t_for(njc), bud=bud, tile_n=tile_n, qsub=qsub,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_scan_topk_streamed_compiles_under_vmap(one_chip,
+                                                no_persistent_cache):
+    """ops/scoring.top_k_with_total's Pallas arm as the one-shard server
+    runs it: streamed scan_topk inside the vmapped shard body (S=1)."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.kernels import scan_topk
+
+    def shard_body(scores, ok):
+        return scan_topk(None, scores[None, :], ok, TOP_K,
+                         count_positive=False, interpret=False)
+
+    compiled = jax.jit(jax.vmap(shard_body)).lower(
+        _sds((1, N_DOCS), jnp.float32, one_chip),
+        _sds((1, N_DOCS), jnp.bool_, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_impact_gather_pallas_compiles(one_chip, no_persistent_cache):
+    """The impact tier's scalar-prefetch gather, which `auto` selects on
+    tpu only (ops/scoring.impact_enabled), at a 64-query x 32-row wave."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.index.pack import BLOCK
+    from elasticsearch_tpu.ops.kernels import (
+        _IMPACT_G, _impact_gather_pallas,
+    )
+
+    compiled = _impact_gather_pallas.lower(
+        _sds((N_BLOCKS, BLOCK), jnp.uint16, one_chip),
+        _sds((N_BLOCKS, BLOCK), jnp.int32, one_chip),
+        _sds((64, 32), jnp.int32, one_chip),
+        _sds((64, 32), jnp.float32, one_chip),
+        g=_IMPACT_G, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_fused_region_compiles_on_four_chips(mesh4,
+                                                     no_persistent_cache):
+    """The one-program fused `_msearch` of a 4-shard index: the Pallas
+    pipeline inside the embedded shard_map region + the on-device
+    all-gather top-k merge (parallel/sharded._compiled_merged), on a
+    Mesh of the four described devices, 250k docs per shard."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticsearch_tpu.index.pack import BLOCK
+    from elasticsearch_tpu.ops import fused as F
+    from elasticsearch_tpu.parallel.sharded import _FusedShardedMsearch
+
+    S = 4
+    n_max = N_DOCS // S
+    ss = types.SimpleNamespace(
+        sp=types.SimpleNamespace(S=S, dense_v=V_DENSE, n_max=n_max),
+        mesh=mesh4,
+        ctx=types.SimpleNamespace(has_norms=frozenset({"body"})),
+    )
+    fs = _FusedShardedMsearch(ss)
+    assert fs._inkernel and fs.n_pad % fs._tile_n == 0
+    sharded = NamedSharding(mesh4, P("shards"))
+    replicated = NamedSharding(mesh4, P())
+    nb = N_BLOCKS // S
+    fa = {
+        "tier32": _sds((S, V_DENSE, n_max), jnp.float32, sharded),
+        "post_docids": _sds((S, nb, BLOCK), jnp.int32, sharded),
+        "post_tfs": _sds((S, nb, BLOCK), jnp.float32, sharded),
+        "post_dls": _sds((S, nb, BLOCK), jnp.float32, sharded),
+        "tier16_stack": _sds((S, fs._vp2, fs.n_pad), jnp.bfloat16, sharded),
+        "live": _sds((S, 1, fs.n_pad), jnp.float32, sharded),
+    }
+    C, R, Td, nreal = 1, 4096, 4, 3000
+    fn = fs._compiled_merged("body", C, R, Td, TOP_K, nreal, False)
+    compiled = fn.lower(
+        fa, _sds((), jnp.float32, replicated),
+        _sds((S, C, R), jnp.int32, sharded),
+        _sds((S, C, R), jnp.int32, sharded),
+        _sds((S, C, R), jnp.float32, sharded),
+        _sds((S, C, F.QC, Td), jnp.int32, sharded),
+        _sds((S, C, F.QC, Td), jnp.float32, sharded),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+
+
+def test_device_build_kernels_compile_at_one_bulk_burst(
+        one_chip, no_persistent_cache):
+    """index/device_build's jitted builders at one 5,000-document burst
+    (Poisson(40) tokens of ~6 chars): the analyze hash kernel, the CSR
+    blocked scatter and the impact quantization pass — the write path
+    `auto` takes on any backend that is not cpu."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.index import device_build as db
+    from elasticsearch_tpu.index.pack import BLOCK
+
+    docs = 5_000
+    bp, lp = db._pow2_pad(docs, floor=8), db._pow2_pad(420, floor=64)
+    db._analyze_hash_jit().lower(
+        _sds((bp, lp), jnp.uint8, one_chip),
+        _sds((bp,), jnp.int32, one_chip),
+    ).compile()
+    lanes = db._pow2_pad(docs * 40)
+    blocks = lanes // BLOCK + 20_000  # one partial block per distinct term
+    flat = functools.partial(_sds, (lanes,), sharding=one_chip)
+    db._csr_scatter_jit().lower(
+        flat(jnp.int32), flat(jnp.float32), flat(jnp.float32),
+        flat(jnp.int32), flat(jnp.int32),
+        total_blocks=blocks, block=BLOCK, n_sentinel=docs,
+    ).compile()
+    row = functools.partial(_sds, (blocks,), jnp.float32, one_chip)
+    db._impact_codes_jit().lower(
+        _sds((blocks, BLOCK), jnp.float32, one_chip),
+        _sds((blocks, BLOCK), jnp.float32, one_chip),
+        row(), row(), row(), qmax=65535, dtype="uint16",
+    ).compile()
