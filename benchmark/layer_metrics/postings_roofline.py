@@ -1,0 +1,23 @@
+"""Kernels: least time over device busy time in the traced span, in %.
+
+Least time = sum over the span's requests, over each query's terms, of
+df(term) x 8 bytes (int32 doc id + int32 tf: the exact tier's own posting)
+divided by the chip's HBM bytes/s. Bound: bytes. It reads the same work
+whatever program scores it. df comes from the generator's own arrays."""
+
+POSTING_BYTES = 8
+
+
+def least_seconds(df, queries, hbm_bytes_per_s: float) -> float:
+    return sum(int(df[t]) for q in queries for t in q) * POSTING_BYTES / hbm_bytes_per_s
+
+
+def read(run):
+    if run.trace is None or not run.traced or not run.peak:
+        return None
+    busy = run.trace["busy_s"]
+    if busy <= 0:
+        return None
+    df = run.df()
+    queries = [run.pool[r.query] for r in run.traced if r.ok]
+    return 100.0 * least_seconds(df, queries, run.peak["hbm_bytes_per_s"]) / busy
