@@ -1378,42 +1378,45 @@ class EsIndex:
         """Turn a StackedResult into the response body `_search_inner`
         returns — shared by the solo path and the serving wave lanes so a
         coalesced request's response is built by the identical code."""
-        from ..aggs.pipeline import apply_pipeline_aggs
+        from ..telemetry import TRACER
 
-        hits = []
-        for i, (s, d, score) in enumerate(zip(res.doc_shards, res.doc_ids, res.scores)):
-            doc_id, src = self.shard_docs[s][d]
-            h = {
-                "_index": self.name,
-                "_id": doc_id,
-                "_score": float(score),
-                "_source": src,
+        with TRACER.span("engine.collect"):
+            from ..aggs.pipeline import apply_pipeline_aggs
+
+            hits = []
+            for i, (s, d, score) in enumerate(zip(res.doc_shards, res.doc_ids, res.scores)):
+                doc_id, src = self.shard_docs[s][d]
+                h = {
+                    "_index": self.name,
+                    "_id": doc_id,
+                    "_score": float(score),
+                    "_source": src,
+                }
+                if collapse_keys is not None and i < len(collapse_keys):
+                    cfld = collapse.get("field") if isinstance(collapse, dict) else collapse
+                    h["fields"] = {cfld: [collapse_keys[i]]}
+                hits.append(h)
+            self._apply_script_fields(hits, script_fields)
+            if had_pipeline and res.aggregations is not None:
+                apply_pipeline_aggs(aggs_request, res.aggregations)
+            self._resolve_top_hits(res.aggregations)
+            relation = getattr(res, "total_relation", "eq")
+            total_value = res.total
+            if relation == "gte" and prune_floor:
+                # the threshold itself is also a proven lower bound (pruning only
+                # engages when max term df >= floor); report the larger
+                total_value = max(total_value, prune_floor)
+            hits_obj = {
+                "total": {"value": total_value, "relation": relation},
+                "max_score": res.max_score,
+                "hits": hits,
             }
-            if collapse_keys is not None and i < len(collapse_keys):
-                cfld = collapse.get("field") if isinstance(collapse, dict) else collapse
-                h["fields"] = {cfld: [collapse_keys[i]]}
-            hits.append(h)
-        self._apply_script_fields(hits, script_fields)
-        if had_pipeline and res.aggregations is not None:
-            apply_pipeline_aggs(aggs_request, res.aggregations)
-        self._resolve_top_hits(res.aggregations)
-        relation = getattr(res, "total_relation", "eq")
-        total_value = res.total
-        if relation == "gte" and prune_floor:
-            # the threshold itself is also a proven lower bound (pruning only
-            # engages when max term df >= floor); report the larger
-            total_value = max(total_value, prune_floor)
-        hits_obj = {
-            "total": {"value": total_value, "relation": relation},
-            "max_score": res.max_score,
-            "hits": hits,
-        }
-        if track_total_hits is False:
-            del hits_obj["total"]  # reference omits hits.total entirely
-        return {
-            "hits": hits_obj,
-            **({"aggregations": res.aggregations} if res.aggregations is not None else {}),
-        }
+            if track_total_hits is False:
+                del hits_obj["total"]  # reference omits hits.total entirely
+            return {
+                "hits": hits_obj,
+                **({"aggregations": res.aggregations} if res.aggregations is not None else {}),
+            }
 
     # ---- knn / ANN -------------------------------------------------------
 
@@ -1536,34 +1539,37 @@ class EsIndex:
         DISPATCH — a background fold may replace the live segment list
         before this merge runs, and (shard, docid) coordinates only mean
         anything against the lists the programs actually scanned."""
-        rows = []
-        for tier, r in enumerate((rb, *rts)):
-            for rank, (s, d, sc) in enumerate(
-                    zip(r.doc_shards, r.doc_ids, r.scores)):
-                rows.append((-float(sc), tier, rank, int(s), int(d)))
-        # (score desc, tier asc, per-tier rank asc) = Lucene TopDocs.merge
-        # order with segment shards indexed after base shards
-        rows.sort()
-        hits = []
-        for negsc, tier, _rank, s, d in rows[from_: from_ + size]:
-            docs = (self.shard_docs if tier == 0
-                    else tail_shard_docs[tier - 1])
-            doc_id, src = docs[s][d]
-            hits.append({"_index": self.name, "_id": doc_id,
-                         "_score": -negsc, "_source": src})
-        relations = [rb.total_relation] + [r.total_relation for r in rts]
-        relation = "gte" if "gte" in relations else "eq"
-        value = rb.total + sum(r.total for r in rts)
-        if relation == "gte" and prune_floor:
-            value = max(value, prune_floor)
-        max_score = max(
-            (x for x in (rb.max_score, *(r.max_score for r in rts))
-             if x is not None), default=None)
-        hits_obj = {"total": {"value": value, "relation": relation},
-                    "max_score": max_score, "hits": hits}
-        if track_total_hits is False:
-            del hits_obj["total"]
-        return {"hits": hits_obj}
+        from ..telemetry import TRACER
+
+        with TRACER.span("engine.collect"):
+            rows = []
+            for tier, r in enumerate((rb, *rts)):
+                for rank, (s, d, sc) in enumerate(
+                        zip(r.doc_shards, r.doc_ids, r.scores)):
+                    rows.append((-float(sc), tier, rank, int(s), int(d)))
+            # (score desc, tier asc, per-tier rank asc) = Lucene TopDocs.merge
+            # order with segment shards indexed after base shards
+            rows.sort()
+            hits = []
+            for negsc, tier, _rank, s, d in rows[from_: from_ + size]:
+                docs = (self.shard_docs if tier == 0
+                        else tail_shard_docs[tier - 1])
+                doc_id, src = docs[s][d]
+                hits.append({"_index": self.name, "_id": doc_id,
+                             "_score": -negsc, "_source": src})
+            relations = [rb.total_relation] + [r.total_relation for r in rts]
+            relation = "gte" if "gte" in relations else "eq"
+            value = rb.total + sum(r.total for r in rts)
+            if relation == "gte" and prune_floor:
+                value = max(value, prune_floor)
+            max_score = max(
+                (x for x in (rb.max_score, *(r.max_score for r in rts))
+                 if x is not None), default=None)
+            hits_obj = {"total": {"value": value, "relation": relation},
+                        "max_score": max_score, "hits": hits}
+            if track_total_hits is False:
+                del hits_obj["total"]
+            return {"hits": hits_obj}
 
     # ---- serving waves ---------------------------------------------------
 

@@ -22,10 +22,13 @@ _compile_installed = False
 
 
 def install_compile_listener() -> None:
-    """Register a jax.monitoring duration listener that meters every XLA
-    backend compile into the registry (es.jit.compiles counter +
-    es.jit.compile.ms histogram). Idempotent; survives metrics.reset()
-    (the listener re-creates its instruments on the next compile)."""
+    """Register jax.monitoring listeners that meter every XLA backend
+    compile into the registry (es.jit.compiles counter + es.jit.compile.ms
+    histogram) and every program that JAX's persistent compilation cache
+    served (es.jit.persistent_cache_hits: `backend_compile_duration` fires
+    for those too, so compiles - persistent_cache_hits is what XLA really
+    compiled). Idempotent; survives metrics.reset() (the listeners
+    re-create their instruments on the next compile)."""
     global _compile_installed
     with _compile_lock:
         if _compile_installed:
@@ -41,7 +44,15 @@ def install_compile_listener() -> None:
                     metrics.histogram_record("es.jit.compile.ms",
                                              duration * 1000.0)
 
+            def _on_event(event: str, **_kw):
+                if event == "/jax/compilation_cache/cache_hits":
+                    metrics.counter_inc("es.jit.persistent_cache_hits")
+
             jmon.register_event_duration_secs_listener(_on_duration)
+            jmon.register_event_listener(_on_event)
+            # shipped from the start: 0 is a reading (a cold cache), and a
+            # missing key is a server without this listener
+            metrics.counter_inc("es.jit.persistent_cache_hits", 0)
             _compile_installed = True
         except Exception:  # noqa: BLE001 - older jax: counters stay at 0
             _compile_installed = True
@@ -65,6 +76,8 @@ def jit_stats() -> dict:
     h = snap["histograms"].get("es.jit.compile.ms") or {}
     return {
         "compiles": int(c.get("es.jit.compiles", 0)),
+        "persistent_cache_hits": int(
+            c.get("es.jit.persistent_cache_hits", 0)),
         "compile_time_in_millis": int(c.get("es.jit.compile_time_ms", 0.0)),
         "compile_ms_max": h.get("max", 0.0),
         "executable_cache": {

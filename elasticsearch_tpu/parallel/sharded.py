@@ -313,7 +313,7 @@ class StackedSearcher:
             self._dense_slices = slices
             k1, b = self.ctx.k1, self.ctx.b
 
-            def compute(tf, norms, avgdls):
+            def dense_tfn(tf, norms, avgdls):
                 parts = []
                 for i, (fld, a, c, hn) in enumerate(slices):
                     tfa = tf[:, a:c, :]
@@ -324,7 +324,7 @@ class StackedSearcher:
                         parts.append(tfa / (tfa + k1))
                 return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
-            self._dense_tfn_fn = jax.jit(compute)
+            self._dense_tfn_fn = jax.jit(dense_tfn)
         avgdls = jnp.asarray(
             [max(self._avgdl(fld), 1e-9) for fld, _a, _c, _hn in self._dense_slices],
             jnp.float32,
@@ -400,8 +400,11 @@ class StackedSearcher:
         self.bump_epoch()
 
     def _compiled(self, node, key, k, agg_nodes, agg_key):
+        from ..monitoring.device import note_executable_cache
+
         cache_key = (key, k, agg_key, self._exec)
         fn = self._cache.get(cache_key)
+        note_executable_cache("search_solo", fn is not None)
         if fn is not None:
             return fn
         ctx = self.ctx
@@ -416,18 +419,23 @@ class StackedSearcher:
             # shard_map manual region, where the streamed Pallas scan is
             # legal (GSPMD never sees the custom call), so the selection
             # tier is the SAME one the single-device path picks
-            scores, match = node.device_eval(dev1, par1, ctx)
-            ts, ti, tot = top_k_with_total(scores, match, dev1["live"],
-                                           k_local)
+            # the scopes name phases, not implementations: they are HLO
+            # metadata that a capture's device operations carry
+            with jax.named_scope("score"):
+                scores, match = node.device_eval(dev1, par1, ctx)
+            with jax.named_scope("topk"):
+                ts, ti, tot = top_k_with_total(scores, match, dev1["live"],
+                                               k_local)
             agg_out = {}
             if agg_nodes:
-                ok = match[:n] & dev1["live"]
-                seg = jnp.where(ok, 0, 1).astype(jnp.int32)
-                dev_a = {**dev1, "_query_scores": scores[:n]}
-                for name, anode in agg_nodes.items():
-                    agg_out[name] = anode.device_eval_segmented(
-                        dev_a, agg_par1[name], seg, 1, ok, ctx
-                    )
+                with jax.named_scope("aggs"):
+                    ok = match[:n] & dev1["live"]
+                    seg = jnp.where(ok, 0, 1).astype(jnp.int32)
+                    dev_a = {**dev1, "_query_scores": scores[:n]}
+                    for name, anode in agg_nodes.items():
+                        agg_out[name] = anode.device_eval_segmented(
+                            dev_a, agg_par1[name], seg, 1, ok, ctx
+                        )
             return ts, ti, tot, agg_out
 
         from .spmd import constrain_shards, manual_shard_region
@@ -442,7 +450,7 @@ class StackedSearcher:
             return constrain_shards(region(dev, params, agg_params),
                                     self.mesh)
 
-        def run(dev, params, agg_params):
+        def search_solo(dev, params, agg_params):
             ts, ti, tot, agg_out = inner(dev, params, agg_params)
             # global merge: flat index order = (score desc, shard asc,
             # local rank asc) — Lucene TopDocs.merge order. In pjit mode
@@ -451,17 +459,20 @@ class StackedSearcher:
             # replicated, so the host fetch pulls k rows, not S*k.
             from .spmd import constrain
 
-            flat = ts.reshape(-1)
-            flat_i = ti.reshape(-1)
-            if self._exec == "pjit":
-                flat = constrain(flat, self.mesh, P())
-                flat_i = constrain(flat_i, self.mesh, P())
-            g_scores, g_idx = jax.lax.top_k(flat, k_global)
-            g_shard = (g_idx // k_local).astype(jnp.int32)
-            g_doc = flat_i[g_idx]
+            with jax.named_scope("topk"):
+                flat = ts.reshape(-1)
+                flat_i = ti.reshape(-1)
+                if self._exec == "pjit":
+                    flat = constrain(flat, self.mesh, P())
+                    flat_i = constrain(flat_i, self.mesh, P())
+                g_scores, g_idx = jax.lax.top_k(flat, k_global)
+                g_shard = (g_idx // k_local).astype(jnp.int32)
+                g_doc = flat_i[g_idx]
             return g_scores, g_shard, g_doc, tot.sum(), agg_out
 
-        fn = jax.jit(run)
+        # named for what it is: one compiled program per plan shape, all of
+        # one family in a capture's `XLA Modules` line
+        fn = jax.jit(search_solo)
         # the selection tier this program is built with, decided once
         # here: the Pallas streamed scan (fused_scan) or lax.top_k
         fn.topk_tier = topk_mode(n, k_local)
@@ -647,7 +658,7 @@ class StackedSearcher:
         def inner(dev, params):
             return constrain_shards(region(dev, params), self.mesh)
 
-        def run(dev, params):
+        def search_collapse(dev, params):
             gmax, gdoc, tot = inner(dev, params)  # [S, V+1] x2, [S]
             best = jnp.max(gmax, axis=0)  # [V+1]
             # winner shard: lowest shard index among maxima (merge tie-break)
@@ -664,7 +675,7 @@ class StackedSearcher:
                 tot.sum(),
             )
 
-        fn = jax.jit(run)
+        fn = jax.jit(search_collapse)
         self._cache[cache_key] = (fn, V)
         return fn, V
 
@@ -743,13 +754,13 @@ class StackedSearcher:
             def inner(dev, params):
                 return constrain_shards(region(dev, params), self.mesh)
 
-            def run(dev, params, sh, di):
+            def scores_at(dev, params, sh, di):
                 scores, match = inner(dev, params)  # [S, n]
                 s = scores[sh, di]
                 ok = match[sh, di]
                 return jnp.where(ok, s, 0.0), ok
 
-            fn = jax.jit(run)
+            fn = jax.jit(scores_at)
             self._cache[cache_key] = fn
         s, ok = jax.device_get(
             fn(self.dev, params, jnp.asarray(doc_shards), jnp.asarray(doc_ids))
@@ -1144,7 +1155,7 @@ class StackedSearcher:
         from ..query.wand import wand_enabled
 
         m = mappings if mappings is not None else self.sp.mappings
-        node = query if isinstance(query, QueryNode) else parse_query(query, m)
+        node, _ = self._parsed(query, m)
         if prune_floor is not None and not aggs and wand_enabled():
             # experimental (ES_TPU_WAND=1): six measured rounds say the
             # batched exhaustive/impact kernels dominate the two-pass
@@ -1205,8 +1216,7 @@ class StackedSearcher:
                         continue
                     misses += 1
                 m = mappings if mappings is not None else self.sp.mappings
-                node = (query if isinstance(query, QueryNode)
-                        else parse_query(query, m))
+                node, _ = self._parsed(query, m)
                 if prune_floor is not None and not aggs:
                     from ..query.wand import wand_enabled
 
@@ -1244,7 +1254,7 @@ class StackedSearcher:
             st["host"] = []
             return
         from ..common import faults
-        from ..telemetry import time_kernel
+        from ..telemetry import TRACER, time_kernel
 
         faults.check("device.fetch", shards=self.sp.S,
                      requests=len(st["pending"]))
@@ -1252,7 +1262,8 @@ class StackedSearcher:
                          requests=len(st["pending"]),
                          queries=len(st["pending"]),
                          num_docs=self.sp.S * self.sp.n_max):
-            st["host"] = jax.device_get(st["pending"])
+            with TRACER.span("engine.fetch"):
+                st["host"] = jax.device_get(st["pending"])
 
     def search_many_finish(self, st: dict,
                            raise_errors: bool = True) -> list:
@@ -1327,13 +1338,14 @@ class StackedSearcher:
         The reference has no agg-batching analog (each search is its own
         scatter/gather); this is the same discipline `ops/batched` applies
         to the query path, extended to aggregations."""
-        from ..telemetry import time_kernel
+        from ..telemetry import TRACER, time_kernel
 
         states = [self._agg_dispatch(**r) for r in requests]
         with time_kernel("sharded.spmd_topk", shards=self.sp.S,
                          requests=len(requests), queries=len(requests),
                          num_docs=self.sp.S * self.sp.n_max):
-            host = jax.device_get([s["outs"] for s in states])
+            with TRACER.span("engine.fetch"):
+                host = jax.device_get([s["outs"] for s in states])
         wave2 = []
         for s, ho in zip(states, host):
             s["host"] = ho
@@ -1345,37 +1357,57 @@ class StackedSearcher:
                 s["host2"] = h2
         return [self._agg_finalize(s) for s in states]
 
+    def _parsed(self, query, m, aggs=None):
+        """-> (the query's node, its aggregation nodes or None); a query
+        that arrives parsed and asks for no aggregations opens no span."""
+        if isinstance(query, QueryNode) and not aggs:
+            return query, None
+        from ..telemetry import TRACER
+
+        with TRACER.span("engine.parse"):
+            node = (query if isinstance(query, QueryNode)
+                    else parse_query(query, m))
+            if not aggs:
+                return node, None
+            from ..aggs import parse_aggs
+
+            return node, parse_aggs(aggs, m)
+
     def _agg_dispatch(self, query=None, size=10, from_=0, aggs=None,
                       mappings=None):
         """Plan + launch one request's pass-1 program (no device fetch)."""
-        m = mappings if mappings is not None else self.sp.mappings
-        node = query if isinstance(query, QueryNode) else parse_query(query, m)
-        agg_nodes = None
-        if aggs:
-            from ..aggs import parse_aggs
+        from ..telemetry import TRACER
 
-            agg_nodes = parse_aggs(aggs, m)
-        S = self.sp.S
-        views = [self.sp.shard_view(s) for s in range(S)]
-        per_shard = []
-        keys = []
-        for v in views:
-            p, k_ = node.prepare(v)
-            per_shard.append(p)
-            keys.append(k_)
-        params = _stack_shard_params(per_shard)
-        agg_params, agg_key = {}, ()
-        if agg_nodes:
-            per_shard_aggs = []
-            akeys = []
+        m = mappings if mappings is not None else self.sp.mappings
+        node, agg_nodes = self._parsed(query, m, aggs)
+        with TRACER.span("engine.plan") as plan:
+            S = self.sp.S
+            views = [self.sp.shard_view(s) for s in range(S)]
+            per_shard = []
+            keys = []
             for v in views:
-                parts = {nme: a.prepare(v, m) for nme, a in agg_nodes.items()}
-                per_shard_aggs.append({nme: p for nme, (p, _) in parts.items()})
-                akeys.append(tuple((nme, kk) for nme, (_, kk) in sorted(parts.items())))
-            agg_params = _stack_shard_params(per_shard_aggs)
-            agg_key = tuple(akeys)
-        k = min(max(size + from_, 1), max(self.sp.n_max * self.sp.S, 1))
-        fn = self._compiled(node, tuple(keys), k, agg_nodes, agg_key)
+                p, k_ = node.prepare(v)
+                per_shard.append(p)
+                keys.append(k_)
+            params = _stack_shard_params(per_shard)
+            agg_params, agg_key = {}, ()
+            if agg_nodes:
+                per_shard_aggs = []
+                akeys = []
+                for v in views:
+                    parts = {nme: a.prepare(v, m)
+                             for nme, a in agg_nodes.items()}
+                    per_shard_aggs.append(
+                        {nme: p for nme, (p, _) in parts.items()})
+                    akeys.append(tuple(
+                        (nme, kk) for nme, (_, kk) in sorted(parts.items())))
+                agg_params = _stack_shard_params(per_shard_aggs)
+                agg_key = tuple(akeys)
+            k = min(max(size + from_, 1), max(self.sp.n_max * self.sp.S, 1))
+            programs = len(self._cache)  # a miss adds one
+            fn = self._compiled(node, tuple(keys), k, agg_nodes, agg_key)
+            hit = len(self._cache) == programs
+            plan.attributes["program_cache"] = "hit" if hit else "miss"
         from ..monitoring.xla_introspect import check_dispatch
         from ..telemetry import metrics
 
@@ -1384,11 +1416,16 @@ class StackedSearcher:
                        (self.dev, params, agg_params),
                        fields={"queries": 1, "k": k,
                                "num_docs": self.sp.S * self.sp.n_max})
+        # argument transfer and launch; behind a miss also trace, lower and
+        # compile
+        with TRACER.span("engine.dispatch",
+                         **({} if hit else {"compiled": True})):
+            outs = fn(self.dev, params, agg_params)
         return {
             "node": node, "keys": tuple(keys), "k": k, "size": size,
             "from_": from_, "agg_nodes": agg_nodes, "agg_key": agg_key,
             "params": params, "agg_params": agg_params,
-            "outs": fn(self.dev, params, agg_params),
+            "outs": outs,
         }
 
     def _agg_pass2_dispatch(self, s) -> bool:
@@ -1424,34 +1461,37 @@ class StackedSearcher:
         return True
 
     def _agg_finalize(self, s) -> StackedResult:
-        g_scores, g_shard, g_doc, total, agg_out = s["host"]
-        agg_nodes = s["agg_nodes"]
-        aggregations = None
-        if agg_nodes:
-            merged = s.get("merged") or {
-                name: anode.merge_partials(agg_out[name])
-                for name, anode in agg_nodes.items()
-            }
-            if "host2" in s:
-                _s1, _s2, _s3, _t, agg_out2 = s["host2"]
-                for name, a in s["tp"].items():
-                    merged[name].update(a.merge_partials(agg_out2[name]))
-            aggregations = {
-                name: anode.finalize(merged[name], 1)[0]
-                for name, anode in agg_nodes.items()
-            }
-        size, from_ = s["size"], s["from_"]
-        valid = np.isfinite(g_scores)
-        max_score = float(g_scores[0]) if valid.any() else None
-        end = max(size + from_, 0)
-        return StackedResult(
-            g_shard[valid][from_:end].astype(np.int32),
-            g_doc[valid][from_:end].astype(np.int32),
-            g_scores[valid][from_:end].astype(np.float32),
-            int(total),
-            max_score,
-            aggregations,
-        )
+        from ..telemetry import TRACER
+
+        with TRACER.span("engine.collect"):
+            g_scores, g_shard, g_doc, total, agg_out = s["host"]
+            agg_nodes = s["agg_nodes"]
+            aggregations = None
+            if agg_nodes:
+                merged = s.get("merged") or {
+                    name: anode.merge_partials(agg_out[name])
+                    for name, anode in agg_nodes.items()
+                }
+                if "host2" in s:
+                    _s1, _s2, _s3, _t, agg_out2 = s["host2"]
+                    for name, a in s["tp"].items():
+                        merged[name].update(a.merge_partials(agg_out2[name]))
+                aggregations = {
+                    name: anode.finalize(merged[name], 1)[0]
+                    for name, anode in agg_nodes.items()
+                }
+            size, from_ = s["size"], s["from_"]
+            valid = np.isfinite(g_scores)
+            max_score = float(g_scores[0]) if valid.any() else None
+            end = max(size + from_, 0)
+            return StackedResult(
+                g_shard[valid][from_:end].astype(np.int32),
+                g_doc[valid][from_:end].astype(np.int32),
+                g_scores[valid][from_:end].astype(np.float32),
+                int(total),
+                max_score,
+                aggregations,
+            )
 
     def count(self, query=None) -> int:
         return self.search(query, size=1).total
@@ -1505,11 +1545,11 @@ class StackedSearcher:
             shard_body, self.mesh,
             in_specs=(P("shards"), P("shards"), P(), P("shards")))
 
-        def run(dev, params, after, agg_params):
+        def search_sorted(dev, params, after, agg_params):
             return constrain_shards(region(dev, params, after, agg_params),
                                     self.mesh)
 
-        fn = jax.jit(run)
+        fn = jax.jit(search_sorted)
         self._cache[cache_key] = fn
         return fn
 
@@ -2048,7 +2088,7 @@ def _msearch_impact_partials(ss: "StackedSearcher", fld: str,
     fn = ss._cache.get(cache_key)
     if fn is None:
         if ss.mesh is not None:
-            def run(dev, W_, rows_, ws_, iws_):
+            def msearch_impact(dev, W_, rows_, ws_, iws_):
                 specs = jax.tree_util.tree_map(lambda _: P("shards"), dev)
                 return shard_map(
                     shard_body, mesh=ss.mesh,
@@ -2056,7 +2096,7 @@ def _msearch_impact_partials(ss: "StackedSearcher", fld: str,
                     out_specs=(P("shards"), P("shards"), P("shards")),
                 )(dev, W_, rows_, ws_, iws_)
         else:
-            def run(dev, W_, rows_, ws_, iws_):
+            def msearch_impact(dev, W_, rows_, ws_, iws_):
                 def body(d1, w1, r1, s1, i1):
                     return shard_body(
                         jax.tree_util.tree_map(lambda x: x[None], d1),
@@ -2064,7 +2104,7 @@ def _msearch_impact_partials(ss: "StackedSearcher", fld: str,
                     )
                 v, i, t = jax.vmap(body)(dev, W_, rows_, ws_, iws_)
                 return v[:, 0], i[:, 0], t[:, 0]
-        fn = ss._cache[cache_key] = jax.jit(run)
+        fn = ss._cache[cache_key] = jax.jit(msearch_impact)
     from ..telemetry import profile_event, time_kernel
 
     code_bytes = int(np.dtype(ss.dev["impact_codes"].dtype).itemsize)
@@ -2227,7 +2267,7 @@ def _msearch_merged_arm_begin(ss: "StackedSearcher", fld: str,
                 impact_w=(iws1 if impact else None),
             )
 
-        def run(dev, W_, rows_, ws_, iws_):
+        def msearch_merged_exact(dev, W_, rows_, ws_, iws_):
             if ra is not None:
                 # replica groups: the query axis splits over the mesh's
                 # second axis, so each replica group scans the (shard-
@@ -2239,7 +2279,7 @@ def _msearch_merged_arm_begin(ss: "StackedSearcher", fld: str,
             v, i, t = constrain_shards(outs, mesh)
             return merge_topk_rows(v, i, t, mesh=mesh)
 
-        fn = ss._cache[cache_key] = jax.jit(run)
+        fn = ss._cache[cache_key] = jax.jit(msearch_merged_exact)
     iws = pl.get("iws")
     if iws is None:
         iws = np.zeros_like(pl["ws"])
@@ -2288,8 +2328,10 @@ def global_merge_rows(ss: "StackedSearcher", v, i, t):
     if fn is None:
         from .spmd import merge_topk_rows
 
-        fn = ss._cache[cache_key] = jax.jit(
-            lambda v_, i_, t_: merge_topk_rows(v_, i_, t_, mesh=ss.mesh))
+        def global_merge(v_, i_, t_):
+            return merge_topk_rows(v_, i_, t_, mesh=ss.mesh)
+
+        fn = ss._cache[cache_key] = jax.jit(global_merge)
     from ..monitoring.xla_introspect import check_dispatch
 
     check_dispatch("sharded.global_merge", fn, (v, i, t),
@@ -2340,7 +2382,7 @@ def _msearch_exact_partials(ss: "StackedSearcher", fld: str,
     fn = ss._cache.get(cache_key)
     if fn is None:
         if ss.mesh is not None:
-            def run(dev, W_, rows_, ws_):
+            def msearch_exact(dev, W_, rows_, ws_):
                 specs = jax.tree_util.tree_map(lambda _: P("shards"), dev)
                 return shard_map(
                     shard_body, mesh=ss.mesh,
@@ -2348,7 +2390,7 @@ def _msearch_exact_partials(ss: "StackedSearcher", fld: str,
                     out_specs=(P("shards"), P("shards"), P("shards")),
                 )(dev, W_, rows_, ws_)
         else:
-            def run(dev, W_, rows_, ws_):
+            def msearch_exact(dev, W_, rows_, ws_):
                 def body(d1, w1, r1, s1):
                     return shard_body(
                         jax.tree_util.tree_map(lambda x: x[None], d1),
@@ -2356,7 +2398,7 @@ def _msearch_exact_partials(ss: "StackedSearcher", fld: str,
                     )
                 v, i, t = jax.vmap(body)(dev, W_, rows_, ws_)
                 return v[:, 0], i[:, 0], t[:, 0]
-        fn = ss._cache[cache_key] = jax.jit(run)
+        fn = ss._cache[cache_key] = jax.jit(msearch_exact)
     if _return_program:
         # measurement hook (scripts/c5_mesh_probe.py): the compiled
         # program + its device inputs, so collective-merge overhead can be
@@ -2549,10 +2591,14 @@ class _FusedShardedMsearch:
 
         from .spmd import manual_shard_region
 
-        run = manual_shard_region(
+        region = manual_shard_region(
             shard_scan, self.ss.mesh,
             in_specs=(P("shards"), P()) + (P("shards"),) * 5)
-        fn = self._cache[key] = jax.jit(run)
+
+        def fused_pipeline(fa, avgdl, rows, row_q, row_w, dr, dw):
+            return region(fa, avgdl, rows, row_q, row_w, dr, dw)
+
+        fn = self._cache[key] = jax.jit(fused_pipeline)
         return fn
 
     def _compiled_merged(self, fld, C, R, Td, k, nreal, interpret):
@@ -2599,7 +2645,7 @@ class _FusedShardedMsearch:
             shard_scan, mesh,
             in_specs=(P("shards"), P()) + (P("shards"),) * 5)
 
-        def run(fa, avgdl, rows, row_q, row_w, dr, dw):
+        def fused_pipeline_merged(fa, avgdl, rows, row_q, row_w, dr, dw):
             v, i, tot, fl = region(fa, avgdl, rows, row_q, row_w, dr, dw)
             S_, C_, qc, kk = v.shape
             v2, i2, t2 = constrain_shards(
@@ -2609,7 +2655,7 @@ class _FusedShardedMsearch:
             flags = jnp.any(fl.reshape(S_, C_ * qc), axis=0)
             return mv, msh, mi, mt, flags
 
-        fn = self._cache[key] = jax.jit(run)
+        fn = self._cache[key] = jax.jit(fused_pipeline_merged)
         return fn
 
     def msearch(self, fld, queries, k):

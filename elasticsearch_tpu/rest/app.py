@@ -23,6 +23,7 @@ from aiohttp import web
 
 from .. import __version__
 from ..engine import Engine
+from ..telemetry import TRACER
 from ..utils.errors import ElasticsearchTpuError, IllegalArgumentError
 
 JSON = "application/json"
@@ -78,7 +79,7 @@ async def _tracing_middleware(request: web.Request, handler):
     tracer (telemetry.TRACER)."""
     import time as _time
 
-    from ..telemetry import (TRACER, TraceContext, activate_trace,
+    from ..telemetry import (TraceContext, activate_trace,
                              format_traceparent, metrics, new_trace_id,
                              parse_traceparent)
 
@@ -89,9 +90,13 @@ async def _tracing_middleware(request: web.Request, handler):
         task_id=request.headers.get("X-Opaque-Id"),
     )
     node = request.app["engine"].tasks.node
+    # named by the route's template, not by the literal path: a name per
+    # document id would be a name per document wherever names are summed
+    resource = request.match_info.route.resource
+    route = resource.canonical if resource is not None else "<unmatched>"
     t0 = _time.perf_counter()
     with activate_trace(ctx, node=node):
-        with TRACER.span(f"http {request.method} {request.path}",
+        with TRACER.span(f"http {request.method} {route}",
                          method=request.method, path=request.path,
                          **({"task_id": ctx.task_id} if ctx.task_id else {})
                          ) as span:
@@ -198,8 +203,23 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
         import contextvars
 
         ctx = contextvars.copy_context()
+        cur = TRACER.current_span()
+        handed = time.perf_counter_ns()
+
+        def on_engine_thread():
+            if cur is not None and cur.name == "rest.search":
+                # a search's wait behind the one engine thread, which no
+                # thread performs; any other endpoint's is in its root alone
+                TRACER.record("engine.queue", handed, time.perf_counter_ns())
+            return fn(*args, **kwargs)
+
         return await loop.run_in_executor(
-            app["pool"], lambda: ctx.run(fn, *args, **kwargs))
+            app["pool"], ctx.run, on_engine_thread)
+
+    def engine_search(fn, *args, **kwargs):
+        """Everything the engine thread does for one search, as one span."""
+        with TRACER.span("engine.search"):
+            return fn(*args, **kwargs)
 
     def handler(fn):
         async def wrapped(request: web.Request):
@@ -1852,7 +1872,13 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
 
     # ---- search ----------------------------------------------------------
 
-    async def _run_search(expression, body, query_params):
+    async def _run_search(expression, body, query_params, respond=None):
+        """-> the response body; `respond` (the `_search` handler's
+        `web.json_response`) turns it into the response inside the
+        `rest.respond` span, so that span holds the JSON encoding too."""
+        def answer(out):
+            return out if respond is None else respond(out)
+
         body = body or {}
         if query_params.get("routing"):
             # same resolution options as the search itself, so the guard
@@ -1879,12 +1905,12 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                 int(query_params.get("size", body.get("size", 10))),
                 int(query_params.get("from", body.get("from", 0))),
             )
-            return {
+            return answer({
                 "took": int((time.monotonic() - t0) * 1000),
                 "timed_out": False,
                 "_shards": {"total": 1, "successful": 1, "skipped": 0, "failed": 0},
                 **res,
-            }
+            })
         query = body.get("query")
         knn = body.get("knn")
         size = int(query_params.get("size", body.get("size", 10)))
@@ -1920,10 +1946,12 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                 if not isinstance(pit, dict) or "id" not in pit:
                     raise IllegalArgumentError("[pit] must be an object with an [id]")
                 res = await call(
-                    engine.search_pit, pit["id"], pit.get("keep_alive"), **kwargs
+                    engine_search, engine.search_pit, pit["id"],
+                    pit.get("keep_alive"), **kwargs
                 )
             elif scroll:
-                res = await call(engine.scroll_search, expression, scroll, **kwargs)
+                res = await call(engine_search, engine.scroll_search,
+                                 expression, scroll, **kwargs)
             else:
                 # continuous-batching front end: wave-eligible requests
                 # ride the coalescing queue (packed device waves, tenant
@@ -1952,7 +1980,7 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                         timeout_s=parse_duration_seconds(t_raw, None))
                 else:
                     res = await call(
-                        engine.search_multi, expression,
+                        engine_search, engine.search_multi, expression,
                         ignore_unavailable=_bool_param(query_params, "ignore_unavailable"),
                         allow_no_indices=_bool_param(query_params, "allow_no_indices", True),
                         **kwargs,
@@ -1961,153 +1989,154 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
             if _prof_cm is not None:
                 _prof_cm.__exit__(None, None, None)
         took = int((time.monotonic() - t0) * 1000)
-        from ..telemetry import metrics as _metrics
+        with TRACER.span("rest.respond"):
+            from ..telemetry import metrics as _metrics
 
-        _metrics.counter_inc("es.search.query.total")
-        _metrics.histogram_record("es.search.query.took_ms", took)
-        from ..search import apply_fetch_phase
+            _metrics.counter_inc("es.search.query.total")
+            _metrics.histogram_record("es.search.query.took_ms", took)
+            from ..search import apply_fetch_phase
 
-        # fetch options given as URL params (the reference accepts both)
-        if "_source" in query_params and "_source" not in body:
-            rs = query_params["_source"]
-            body = {**body, "_source": (rs == "true") if rs in ("true", "false")
-                    else rs.split(",")}
-        inc = query_params.get("_source_includes")
-        exc = query_params.get("_source_excludes")
-        if (inc or exc) and not isinstance(body.get("_source"), dict):
-            body = {**body, "_source": {
-                "includes": inc.split(",") if inc else [],
-                "excludes": exc.split(",") if exc else [],
-            }}
-        if "docvalue_fields" in query_params and "docvalue_fields" not in body:
-            body = {**body,
-                    "docvalue_fields": query_params["docvalue_fields"].split(",")}
-        if "stored_fields" in query_params and "stored_fields" not in body:
-            body = {**body,
-                    "stored_fields": query_params["stored_fields"].split(",")}
+            # fetch options given as URL params (the reference accepts both)
+            if "_source" in query_params and "_source" not in body:
+                rs = query_params["_source"]
+                body = {**body, "_source": (rs == "true") if rs in ("true", "false")
+                        else rs.split(",")}
+            inc = query_params.get("_source_includes")
+            exc = query_params.get("_source_excludes")
+            if (inc or exc) and not isinstance(body.get("_source"), dict):
+                body = {**body, "_source": {
+                    "includes": inc.split(",") if inc else [],
+                    "excludes": exc.split(",") if exc else [],
+                }}
+            if "docvalue_fields" in query_params and "docvalue_fields" not in body:
+                body = {**body,
+                        "docvalue_fields": query_params["docvalue_fields"].split(",")}
+            if "stored_fields" in query_params and "stored_fields" not in body:
+                body = {**body,
+                        "stored_fields": query_params["stored_fields"].split(",")}
 
-        def _mappings_of(name):
-            if ":" in name:  # remote (CCS) hit: sub-phases already applied there
-                return None
-            return engine.get_index(name).mappings
+            def _mappings_of(name):
+                if ":" in name:  # remote (CCS) hit: sub-phases already applied there
+                    return None
+                return engine.get_index(name).mappings
 
-        # `fields: [_tsid]` on a time-series index: computed from the full
-        # source BEFORE source filtering, attached after the fetch phase
-        # (never fetched by default — reference TimeSeriesIdFieldMapper)
-        want_tsid = any(
-            (f if isinstance(f, str) else (f or {}).get("field")) == "_tsid"
-            for f in (body.get("fields") or []))
-        tsids = {}
-        if want_tsid:
-            for pos, hit in enumerate(res["hits"]["hits"]):
-                tsm = getattr(engine.indices.get(hit.get("_index")),
-                              "ts_mode", None)
-                if tsm is not None and hit.get("_source"):
-                    tsids[pos] = tsm.tsid_of(hit["_source"])
-        _t_fetch = time.monotonic()
-        apply_fetch_phase(res["hits"]["hits"], body, _mappings_of)
-        _fetch_ms = (time.monotonic() - _t_fetch) * 1000
-        for pos, tsid in tsids.items():
-            res["hits"]["hits"][pos].setdefault("fields", {})["_tsid"] = [
-                tsid]
-        if body.get("suggest"):
-            res["suggest"] = await call(
-                engine.suggest_multi, expression, body["suggest"]
-            )
-        if body.get("profile"):
-            # per-query profile TREE with measured per-subtree timings
-            # (reference behavior: search/profile/query/QueryProfiler —
-            # every node reports type/description/breakdown/children).
-            # Each subtree times as its own device program: create_weight
-            # carries the trace+compile cost, score the fused execution.
-            def _profile():
-                from ..query.dsl import parse_query
-                from ..search.profile import empty_shard, profile_shards
-
-                shards = []
-                took_ns = int((time.monotonic() - t0) * 1e9)
-                phases = {"query_ms": took, "fetch_ms": round(_fetch_ms, 3)}
-                for idx, alias_filter in engine.resolve_search(
-                    expression or "_all", True, True
-                ):
-                    if idx.searcher is None:
-                        # never-refreshed index: the shard entry must still
-                        # exist (clients index into profile.shards)
-                        shards.append(empty_shard(idx, engine.tasks.node))
-                        continue
-                    q = body.get("query") or {"match_all": {}}
-                    if alias_filter:
-                        # profile the query that actually executed: a
-                        # filtered alias ANDs its filter in
-                        q = {"bool": {"must": [q],
-                                      "filter": [alias_filter]}}
-                    node = parse_query(q, idx.mappings)
-                    shards.extend(
-                        profile_shards(idx, node, took_ns, engine.tasks.node,
-                                       device_events=_prof_events,
-                                       phases=phases)
-                    )
-                return {"shards": shards}
-
-            res["profile"] = await call(_profile)
-        try:
-            n_shards = sum(
-                i.num_shards for i, _ in engine.resolve_search(
-                    expression, _bool_param(query_params, "ignore_unavailable"), True
+            # `fields: [_tsid]` on a time-series index: computed from the full
+            # source BEFORE source filtering, attached after the fetch phase
+            # (never fetched by default — reference TimeSeriesIdFieldMapper)
+            want_tsid = any(
+                (f if isinstance(f, str) else (f or {}).get("field")) == "_tsid"
+                for f in (body.get("fields") or []))
+            tsids = {}
+            if want_tsid:
+                for pos, hit in enumerate(res["hits"]["hits"]):
+                    tsm = getattr(engine.indices.get(hit.get("_index")),
+                                  "ts_mode", None)
+                    if tsm is not None and hit.get("_source"):
+                        tsids[pos] = tsm.tsid_of(hit["_source"])
+            _t_fetch = time.monotonic()
+            apply_fetch_phase(res["hits"]["hits"], body, _mappings_of)
+            _fetch_ms = (time.monotonic() - _t_fetch) * 1000
+            for pos, tsid in tsids.items():
+                res["hits"]["hits"][pos].setdefault("fields", {})["_tsid"] = [
+                    tsid]
+            if body.get("suggest"):
+                res["suggest"] = await call(
+                    engine.suggest_multi, expression, body["suggest"]
                 )
-            )
-        except ElasticsearchTpuError:
-            n_shards = 1  # e.g. remote-cluster expressions resolve elsewhere
-        if _bool_param(query_params, "rest_total_hits_as_int"):
-            tot = res.get("hits", {}).get("total")
-            if isinstance(tot, dict):
-                res["hits"]["total"] = tot["value"]
-        skipped = res.pop("skipped_shards", 0)
-        # honest `_shards` (PR 14): the fan-out reports its real outcome —
-        # failed shards + attributed failures ride the engine result, and
-        # allow_partial_search_results (body > query param > dynamic
-        # cluster default, ES semantics: default true) decides whether a
-        # partial response is served or the request fails with 503
-        failed = res.pop("failed_shards", 0)
-        failures = res.pop("shard_failures", None)
-        if failed:
-            allow = body.get("allow_partial_search_results")
-            if allow is None:
-                raw = query_params.get("allow_partial_search_results")
-                if raw is not None:
-                    allow = raw in ("", "true", "1")
-            if allow is None:
-                allow = bool(engine.settings.get(
-                    "search.default_allow_partial_results"))
-            if not allow:
-                from ..utils.errors import SearchPhaseExecutionError
+            if body.get("profile"):
+                # per-query profile TREE with measured per-subtree timings
+                # (reference behavior: search/profile/query/QueryProfiler —
+                # every node reports type/description/breakdown/children).
+                # Each subtree times as its own device program: create_weight
+                # carries the trace+compile cost, score the fused execution.
+                def _profile():
+                    from ..query.dsl import parse_query
+                    from ..search.profile import empty_shard, profile_shards
 
-                raise SearchPhaseExecutionError(
-                    f"{failed} shard failure(s) and "
-                    "allow_partial_search_results is false",
-                    failures=failures)
-        shards = {
-            "total": n_shards,
-            # the reference counts skipped shards as successful too
-            "successful": max(n_shards - failed, 0),
-            "skipped": skipped,
-            "failed": failed,
-        }
-        if failures:
-            shards["failures"] = failures
-        return {
-            "took": took,
-            "timed_out": False,
-            "_shards": shards,
-            **res,
-        }
+                    shards = []
+                    took_ns = int((time.monotonic() - t0) * 1e9)
+                    phases = {"query_ms": took, "fetch_ms": round(_fetch_ms, 3)}
+                    for idx, alias_filter in engine.resolve_search(
+                        expression or "_all", True, True
+                    ):
+                        if idx.searcher is None:
+                            # never-refreshed index: the shard entry must still
+                            # exist (clients index into profile.shards)
+                            shards.append(empty_shard(idx, engine.tasks.node))
+                            continue
+                        q = body.get("query") or {"match_all": {}}
+                        if alias_filter:
+                            # profile the query that actually executed: a
+                            # filtered alias ANDs its filter in
+                            q = {"bool": {"must": [q],
+                                          "filter": [alias_filter]}}
+                        node = parse_query(q, idx.mappings)
+                        shards.extend(
+                            profile_shards(idx, node, took_ns, engine.tasks.node,
+                                           device_events=_prof_events,
+                                           phases=phases)
+                        )
+                    return {"shards": shards}
+
+                res["profile"] = await call(_profile)
+            try:
+                n_shards = sum(
+                    i.num_shards for i, _ in engine.resolve_search(
+                        expression, _bool_param(query_params, "ignore_unavailable"), True
+                    )
+                )
+            except ElasticsearchTpuError:
+                n_shards = 1  # e.g. remote-cluster expressions resolve elsewhere
+            if _bool_param(query_params, "rest_total_hits_as_int"):
+                tot = res.get("hits", {}).get("total")
+                if isinstance(tot, dict):
+                    res["hits"]["total"] = tot["value"]
+            skipped = res.pop("skipped_shards", 0)
+            # honest `_shards` (PR 14): the fan-out reports its real outcome —
+            # failed shards + attributed failures ride the engine result, and
+            # allow_partial_search_results (body > query param > dynamic
+            # cluster default, ES semantics: default true) decides whether a
+            # partial response is served or the request fails with 503
+            failed = res.pop("failed_shards", 0)
+            failures = res.pop("shard_failures", None)
+            if failed:
+                allow = body.get("allow_partial_search_results")
+                if allow is None:
+                    raw = query_params.get("allow_partial_search_results")
+                    if raw is not None:
+                        allow = raw in ("", "true", "1")
+                if allow is None:
+                    allow = bool(engine.settings.get(
+                        "search.default_allow_partial_results"))
+                if not allow:
+                    from ..utils.errors import SearchPhaseExecutionError
+
+                    raise SearchPhaseExecutionError(
+                        f"{failed} shard failure(s) and "
+                        "allow_partial_search_results is false",
+                        failures=failures)
+            shards = {
+                "total": n_shards,
+                # the reference counts skipped shards as successful too
+                "successful": max(n_shards - failed, 0),
+                "skipped": skipped,
+                "failed": failed,
+            }
+            if failures:
+                shards["failures"] = failures
+            return answer({
+                "took": took,
+                "timed_out": False,
+                "_shards": shards,
+                **res,
+            })
 
     @handler
     async def search(request):
-        body = await body_json(request, {})
-        return web.json_response(
-            await _run_search(request.match_info.get("index"), body, request.query)
-        )
+        with TRACER.span("rest.search"):
+            body = await body_json(request, {})
+            return await _run_search(request.match_info.get("index"), body,
+                                     request.query, web.json_response)
 
     @handler
     async def msearch(request):
@@ -2801,7 +2830,7 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
         """Debug endpoint: stitch every span of one trace held by this
         process into a time-ordered tree (the single-node analog of the
         cluster gateway's fan-out collection)."""
-        from ..telemetry import TRACER, stitch_trace
+        from ..telemetry import stitch_trace
 
         trace_id = request.match_info["trace_id"].lower()
         spans = TRACER.spans_for_trace(trace_id)

@@ -14,6 +14,7 @@ Prometheus text exposition instead of an APM agent."""
 from __future__ import annotations
 
 import contextvars
+import itertools
 import logging
 import math
 import os
@@ -21,7 +22,7 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 log = logging.getLogger("elasticsearch_tpu")
 slowlog_search = logging.getLogger("elasticsearch_tpu.slowlog.search")
@@ -50,8 +51,14 @@ def new_trace_id() -> str:
     return os.urandom(16).hex()
 
 
-def new_span_id() -> str:
-    return os.urandom(8).hex()
+# span ids come from a process-local counter (no syscall per span) and are
+# formatted when read; the random base keeps two processes of one cluster
+# from minting the same id
+_span_ids = itertools.count(int.from_bytes(os.urandom(8), "big"))
+
+
+def _hex16(n: int) -> str:
+    return f"{n & 0xFFFFFFFFFFFFFFFF:016x}"
 
 
 _trace_ctx: contextvars.ContextVar[TraceContext | None] = contextvars.ContextVar(
@@ -133,65 +140,130 @@ def context_from_headers(headers: dict | None) -> TraceContext | None:
 # spans
 # ---------------------------------------------------------------------------
 
-@dataclass
+# The stages of a served search, from the REST handler down to the device
+# fetch. Inside a `rest.search` these names are summed into
+# `es.span.<name>.ns` / `.count` (what `_nodes/stats` -> metrics.counters
+# ships and the benchmark's per-layer readers divide into a mean a search).
+# Any other span, and a stage entered from elsewhere (`_msearch`, the serving
+# wave, a library call), is recorded and summed nowhere: the counters stay
+# one search's, and a span named by a request's data can never mint one.
+STAGES = (
+    "rest.search", "engine.queue", "engine.search", "engine.parse",
+    "engine.plan", "engine.dispatch", "engine.fetch", "engine.collect",
+    "rest.respond",
+)
+# The leaves among them also open a `jax.profiler.TraceAnnotation` while a
+# capture runs, which puts them on its host plane, on the capture's clock,
+# beside PJRT's own events. Parents stay off it: an idle gap of the device
+# runs from one request's fetch to the next one's launch, and a parent
+# would cover every gap and name none.
+ANNOTATED_STAGES = frozenset({
+    "engine.parse", "engine.plan", "engine.dispatch", "engine.fetch",
+    "engine.collect", "rest.respond",
+})
+_STAGE_COUNTERS = {name: (f"es.span.{name}.ns", f"es.span.{name}.count")
+                   for name in STAGES}
+_annotation = None  # jax.profiler.TraceAnnotation while a capture runs
+
+
+def annotate_stages(on: bool) -> None:
+    """ProfilerService's switch: the leaf stages open annotations only
+    between a capture's start and its stop, and cost nothing of it else."""
+    global _annotation
+    if on:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+
+
 class Span:
-    name: str
-    start: float
-    end: float | None = None
-    attributes: dict = field(default_factory=dict)
-    children: list = field(default_factory=list)
-    # trace identity (PR 4): every span carries the ids needed to stitch a
-    # cross-node trace plus the node it executed on
-    trace_id: str = ""
-    span_id: str = ""
-    parent_span_id: str | None = None
-    node: str = ""
-    wall_start: float = 0.0  # epoch seconds (cross-node alignment)
+    """One recorded interval. `Tracer.span` hands it out as its own context
+    manager: entering stamps the start and makes it the current span,
+    leaving stamps the end and files it under its parent. Times are
+    `time.perf_counter_ns` readings; the wall clock is read for a root
+    alone, and a child's `start_unix` is its root's plus the distance."""
+
+    __slots__ = ("name", "attributes", "children", "trace_id", "node",
+                 "_id", "_parent_id", "_t0", "_t1", "_wall", "_sums",
+                 "_tracer", "_parent", "_token", "_ann")
+
+    def __init__(self, tracer, name: str, attributes: dict):
+        self.name = name
+        self.attributes = attributes
+        self.children: list = []
+        self.node = _node_name.get() or "node-0"
+        self._id = next(_span_ids)
+        self._t1 = self._ann = None
+        self._tracer = tracer
+        parent = self._parent = tracer._current.get()
+        if parent is not None:
+            self.trace_id = parent.trace_id
+            self._parent_id = parent._id
+            self._wall = None
+            self._sums = parent._sums
+        else:
+            # a root: under the request's propagated context (REST headers,
+            # a transport request's) or a trace of its own
+            ctx = _trace_ctx.get()
+            self.trace_id = ctx.trace_id if ctx is not None else new_trace_id()
+            self._parent_id = ctx.parent_span_id if ctx is not None else None
+            self._wall = time.time()  # epoch seconds (cross-node alignment)
+            self._sums = None
+        if name == "rest.search":
+            self._sums = {}  # stage -> [ns, count] of this search's spans
+
+    def __enter__(self) -> "Span":
+        self._token = self._tracer._current.set(self)
+        if _annotation is not None and self.name in ANNOTATED_STAGES:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._tracer._current.reset(self._token)
+        self._token = None  # it holds the parent: no cycle through it
+        self._tracer._finish(self, t1)
+        return False
+
+    @property
+    def span_id(self) -> str:
+        return _hex16(self._id)
+
+    @property
+    def parent_span_id(self) -> str | None:
+        p = self._parent_id  # a local parent's counter, or a caller's hex id
+        return _hex16(p) if isinstance(p, int) else p
+
+    @property
+    def start(self) -> float:
+        return self._t0 * 1e-9      # seconds on time.perf_counter's clock
+
+    @property
+    def end(self) -> float | None:
+        return None if self._t1 is None else self._t1 * 1e-9
 
     @property
     def duration_ms(self) -> float:
-        return ((self.end or time.monotonic()) - self.start) * 1000
+        return ((self._t1 or time.perf_counter_ns()) - self._t0) * 1e-6
 
-    def to_dict(self) -> dict:
+    def to_dict(self, root: "Span | None" = None) -> dict:
+        root = root or self
         return {
             "name": self.name,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "parent_span_id": self.parent_span_id,
             "node": self.node,
-            "start_unix": self.wall_start,
+            "start_unix": root._wall + (self._t0 - root._t0) * 1e-9,
             "duration_ms": round(self.duration_ms, 3),
             "attributes": dict(self.attributes),
         }
-
-    def to_otlp(self) -> dict:
-        """One OTLP-shaped span record (the field names of
-        opentelemetry-proto trace Span, JSON encoding)."""
-        start_ns = int(self.wall_start * 1e9)
-        end_ns = start_ns + int(self.duration_ms * 1e6)
-        attrs = [{"key": "node.name",
-                  "value": {"stringValue": self.node}}]
-        for k, v in self.attributes.items():
-            if isinstance(v, bool):
-                attrs.append({"key": k, "value": {"boolValue": v}})
-            elif isinstance(v, int):
-                attrs.append({"key": k, "value": {"intValue": str(v)}})
-            elif isinstance(v, float):
-                attrs.append({"key": k, "value": {"doubleValue": v}})
-            else:
-                attrs.append({"key": k, "value": {"stringValue": str(v)}})
-        out = {
-            "traceId": self.trace_id,
-            "spanId": self.span_id,
-            "name": self.name,
-            "kind": 1,  # SPAN_KIND_INTERNAL
-            "startTimeUnixNano": str(start_ns),
-            "endTimeUnixNano": str(end_ns),
-            "attributes": attrs,
-        }
-        if self.parent_span_id:
-            out["parentSpanId"] = self.parent_span_id
-        return out
 
 
 def _walk_spans(span: Span):
@@ -202,9 +274,9 @@ def _walk_spans(span: Span):
 
 class Tracer:
     """In-memory tracer: spans nest via a context variable; the last
-    `keep` root spans are retained for inspection. Root spans finished
-    while ES_TPU_OTLP_FILE is set are appended there as OTLP-shaped JSON
-    lines (the APM/OTLP exporter of the reference maps to this sink)."""
+    `keep` root spans are retained for inspection (`GET /_trace/{id}`).
+    `span` is the one way to record a span around work a thread does;
+    `record` takes a wait that no thread performs, with explicit ends."""
 
     def __init__(self, keep: int = 256):
         self.finished: deque[Span] = deque(maxlen=keep)
@@ -214,36 +286,43 @@ class Tracer:
     def current_span(self) -> Span | None:
         return self._current.get()
 
-    @contextmanager
-    def span(self, name: str, **attributes):
-        parent = self._current.get()
-        ctx = _trace_ctx.get()
-        if parent is not None:
-            trace_id = parent.trace_id or new_trace_id()
-            parent_id = parent.span_id or None
-        elif ctx is not None:
-            trace_id = ctx.trace_id
-            parent_id = ctx.parent_span_id
-        else:
-            trace_id = new_trace_id()
-            parent_id = None
-        s = Span(name=name, start=time.monotonic(),
-                 attributes=dict(attributes),
-                 trace_id=trace_id, span_id=new_span_id(),
-                 parent_span_id=parent_id, node=current_node_name(),
-                 wall_start=time.time())
-        token = self._current.set(s)
-        try:
-            yield s
-        finally:
-            s.end = time.monotonic()
-            self._current.reset(token)
-            if parent is not None:
-                parent.children.append(s)
+    def span(self, name: str, **attributes) -> Span:
+        return Span(self, name, attributes)
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               **attributes) -> Span:
+        """A finished span from two `time.perf_counter_ns()` readings, as a
+        child of the current span: counters and span tree, no annotation."""
+        s = Span(self, name, attributes)
+        s._t0 = start_ns
+        if s._wall is not None:
+            s._wall -= (time.perf_counter_ns() - start_ns) * 1e-9
+        self._finish(s, end_ns)
+        return s
+
+    def _finish(self, s: Span, end_ns: int) -> None:
+        s._t1 = end_ns
+        sums = s._sums
+        if sums is not None and s.name in _STAGE_COUNTERS:
+            rec = sums.get(s.name)
+            if rec is None:
+                sums[s.name] = [end_ns - s._t0, 1]
             else:
-                self.finished.append(s)
-                self._export_otlp(s)
-                log.debug("span %s %.2fms %s", name, s.duration_ms, s.attributes)
+                rec[0] += end_ns - s._t0
+                rec[1] += 1
+            if s.name == "rest.search":
+                # one search's stages reach the counters together, under
+                # one acquisition of the lock: every stage's count moves by
+                # the same searches, whatever window the counters are read in
+                metrics.counters_add(
+                    (keys, sums[name]) for name, keys in _STAGE_COUNTERS.items()
+                    if name in sums)
+        parent, s._parent = s._parent, None  # children point down only
+        if parent is not None:
+            parent.children.append(s)
+        else:
+            self.finished.append(s)
+            log.debug("span %s %.2fms %s", s.name, s.duration_ms, s.attributes)
 
     # -- inspection / export ------------------------------------------------
 
@@ -253,7 +332,7 @@ class Tracer:
         for root in list(self.finished):
             if root.trace_id != trace_id:
                 continue
-            out.extend(s.to_dict() for s in _walk_spans(root))
+            out.extend(s.to_dict(root) for s in _walk_spans(root))
         return out
 
     def recent_spans(self, n: int = 20) -> list[dict]:
@@ -265,19 +344,6 @@ class Tracer:
             d["span_count"] = sum(1 for _ in _walk_spans(root))
             out.append(d)
         return out
-
-    def _export_otlp(self, root: Span) -> None:
-        path = os.environ.get("ES_TPU_OTLP_FILE")
-        if not path:
-            return
-        import json as _json
-
-        try:
-            with open(path, "a") as f:
-                for s in _walk_spans(root):
-                    f.write(_json.dumps(s.to_otlp()) + "\n")
-        except OSError:  # an unwritable sink must never fail the request
-            log.debug("OTLP export to %s failed", path)
 
 
 TRACER = Tracer()
@@ -622,6 +688,15 @@ class MetricsRegistry:
     def counter_inc(self, name: str, value: float = 1.0) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0.0) + value
+
+    def counters_add(self, pairs) -> None:
+        """Integer counters by twos, ((name_a, name_b), (a, b)), under one
+        acquisition of the lock (a search's stages: nanoseconds and count)."""
+        with self._lock:
+            c = self._counters
+            for (name_a, name_b), (a, b) in pairs:
+                c[name_a] = c.get(name_a, 0) + a
+                c[name_b] = c.get(name_b, 0) + b
 
     def gauge_set(self, name: str, value) -> None:
         """value: a number, or a zero-arg callable sampled at snapshot."""

@@ -1,11 +1,9 @@
 #!/usr/bin/env python
 """Pretty-print a stored trace as a time-aligned tree.
 
-Sources (pick one):
+Source:
   --url http://host:port --trace <trace_id>   fetch GET /_trace/{id} from a
                                               node or cluster gateway
-  --otlp spans.jsonl --trace <trace_id>       read OTLP JSON lines written
-                                              by ES_TPU_OTLP_FILE
 
 Output: one line per span, indented by depth, with a time-aligned bar over
 the trace's wall-clock window, the owning node, and duration — enough to
@@ -33,44 +31,6 @@ def _fetch_url(url: str, trace_id: str) -> dict:
     with urllib.request.urlopen(
             f"{url.rstrip('/')}/_trace/{trace_id}", timeout=30.0) as r:
         return json.loads(r.read())
-
-
-def _from_otlp_lines(path: str, trace_id: str) -> dict:
-    """Rebuild the /_trace response shape from OTLP JSON lines."""
-    spans = []
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line:
-                continue
-            rec = json.loads(line)
-            if rec.get("traceId") != trace_id:
-                continue
-            start_ns = int(rec["startTimeUnixNano"])
-            end_ns = int(rec["endTimeUnixNano"])
-            attrs = {}
-            node = ""
-            for a in rec.get("attributes", []):
-                v = a.get("value", {})
-                val = (v.get("stringValue") or v.get("intValue")
-                       or v.get("doubleValue") or v.get("boolValue"))
-                if a.get("key") == "node.name":
-                    node = val
-                else:
-                    attrs[a.get("key")] = val
-            spans.append({
-                "name": rec["name"],
-                "trace_id": rec["traceId"],
-                "span_id": rec["spanId"],
-                "parent_span_id": rec.get("parentSpanId"),
-                "node": node,
-                "start_unix": start_ns / 1e9,
-                "duration_ms": (end_ns - start_ns) / 1e6,
-                "attributes": attrs,
-            })
-    from elasticsearch_tpu.telemetry import stitch_trace
-
-    return stitch_trace(spans)
 
 
 def _window(roots: list[dict]) -> tuple[float, float]:
@@ -414,7 +374,6 @@ def render_esql(snap: dict, out=None) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--url", help="node/gateway base URL to fetch from")
-    ap.add_argument("--otlp", help="OTLP JSON-lines file (ES_TPU_OTLP_FILE)")
     ap.add_argument("--trace", help="trace id (32 hex)")
     ap.add_argument("--flight", nargs="?", const="-",
                     help="render the serving flight recorder instead of a "
@@ -474,10 +433,9 @@ def main(argv=None) -> int:
     if not args.trace:
         ap.error("--trace is required (or use --flight / --refresh / "
                  "--esql)")
-    if bool(args.url) == bool(args.otlp):
-        ap.error("exactly one of --url / --otlp is required")
-    trace = (_fetch_url(args.url, args.trace) if args.url
-             else _from_otlp_lines(args.otlp, args.trace))
+    if not args.url:
+        ap.error("--url is required with --trace")
+    trace = _fetch_url(args.url, args.trace)
     if not trace.get("spans"):
         print(f"trace {args.trace}: no spans found", file=sys.stderr)
         return 1

@@ -138,6 +138,53 @@ def _engine_part(out: dict) -> None:
     e.close()
 
 
+def _host_event_names(capture: dict) -> list[str]:
+    """Names of the events on the capture's host planes."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        capture["dir"], "**", "*.xplane.pb"), recursive=True))[-1]
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    return sorted(names)
+
+
+async def _capture_of_a_search(client, out: dict) -> None:
+    """One search under `POST /_profiler/start`'s capture: the host planes'
+    event names (PR 26)."""
+    await client.put("/p", json={"mappings": {"properties": {
+        "body": {"type": "text"}}}})
+    lines = []
+    for i in range(300):  # past the 256 docs an incremental refresh takes
+        lines.append(json.dumps({"index": {"_id": str(i)}}))
+        lines.append(json.dumps(
+            {"body": f"{WORDS[i % 7]} {WORDS[(i + 3) % 7]} common"}))
+    r = await client.post("/p/_bulk", data="\n".join(lines) + "\n",
+                          headers={"Content-Type": "application/x-ndjson"})
+    assert r.status == 200, await r.text()
+    await client.post("/p/_refresh")
+
+    async def search(text):  # one plan shape, and never the request cache's
+        r = await client.post("/p/_search",
+                              json={"query": {"match": {"body": text}}})
+        assert r.status == 200, await r.text()
+
+    await search("alpha gamma")
+    r = await client.post("/_profiler/start", json={})
+    assert r.status == 200, await r.text()
+    await search("beta delta")
+    stopped = await (await client.post("/_profiler/stop")).json()
+    out["capture_host_events"] = _host_event_names(stopped)
+    from elasticsearch_tpu import telemetry
+
+    out["annotating_after_stop"] = telemetry._annotation is not None
+
+
 async def _rest_part(out: dict) -> None:
     from aiohttp.test_utils import TestClient, TestServer
 
@@ -155,6 +202,7 @@ async def _rest_part(out: dict) -> None:
         r4 = await client.post("/_profiler/stop")
         out["rest_stop_again_status"] = r4.status
         out["rest_status"] = await (await client.get("/_profiler")).json()
+        await _capture_of_a_search(client, out)
     finally:
         engine = client.server.app["engine"]
         if engine._serving is not None:

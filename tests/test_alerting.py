@@ -533,10 +533,16 @@ def test_cluster_e2e_scheduled_watch_alert_and_health():
             time.sleep(0.5)
         assert alert is not None, "alert doc never replicated to node C"
         assert alert["watch_id"] == "p99-breach"
-        # execution history replicated too
-        st, res = _http("POST", port_c, "/.watcher-history-8-*/_search", {
-            "query": {"term": {"watch_id": "p99-breach"}}, "size": 1},
-            timeout=90.0)
+        # execution history replicated too: the master exports it after the
+        # alert doc, as an op of its own, so it may reach node C a tick later
+        while True:
+            st, res = _http("POST", port_c, "/.watcher-history-8-*/_search", {
+                "query": {"term": {"watch_id": "p99-breach"}}, "size": 1},
+                timeout=90.0)
+            if (st == 200 and res["hits"]["total"]["value"] >= 1) \
+                    or time.time() >= deadline:
+                break
+            time.sleep(0.5)
         assert st == 200 and res["hits"]["total"]["value"] >= 1, res
         # _health_report on ANOTHER node: the fan-out merges every
         # node's indicators; slo-compliance is yellow and its diagnosis

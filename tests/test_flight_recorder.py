@@ -282,6 +282,22 @@ def test_rest_profiler_lifecycle(harness):
     assert harness["rest_status"]["max_duration_s"] == 10.0
 
 
+def test_a_capture_holds_stage_annotations_and_no_python_frame(harness):
+    """POST /_profiler/start: the host planes hold one annotation named as
+    each leaf stage of a search, PJRT's own events, and no
+    `$file.py:line function` frame of the Python tracer (PR 26)."""
+    from elasticsearch_tpu.telemetry import ANNOTATED_STAGES
+
+    names = set(harness["capture_host_events"])
+    assert ANNOTATED_STAGES <= names
+    assert "PjitFunction(search_solo)" in names
+    assert not [n for n in names if n.startswith("$")]
+
+
+def test_the_stages_stop_annotating_when_the_capture_stops(harness):
+    assert harness["annotating_after_stop"] is False
+
+
 # ---------------------------------------------------------------------------
 # REST surface
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ one trace_id on every involved node), `"profile": true` device sections
 with kernel wall timings for the fused and escalated tiers, exponential-
 bucket histogram percentiles against numpy, the Prometheus exposition
 endpoint (hand-rolled text-format parser — no new dependency), hot
-threads, slowlog trace enrichment, and OTLP JSON-lines export."""
+threads, and slowlog trace enrichment."""
 
 import asyncio
 import json
@@ -570,41 +570,3 @@ def test_cluster_trace_propagation_e2e():
             g.close()
         for s in servers.values():
             s.close()
-
-
-# ---------------------------------------------------------------------------
-# OTLP export
-# ---------------------------------------------------------------------------
-
-def test_otlp_json_lines_export(tmp_path, monkeypatch):
-    path = tmp_path / "spans.jsonl"
-    monkeypatch.setenv("ES_TPU_OTLP_FILE", str(path))
-    ctx = TraceContext(trace_id=telemetry.new_trace_id())
-    with activate_trace(ctx, node="otlp-node"):
-        with telemetry.TRACER.span("parent", index="i"):
-            with telemetry.TRACER.span("kid"):
-                pass
-    lines = [json.loads(ln) for ln in path.read_text().splitlines()]
-    assert len(lines) == 2
-    by_name = {rec["name"]: rec for rec in lines}
-    assert by_name["parent"]["traceId"] == ctx.trace_id
-    assert by_name["kid"]["parentSpanId"] == by_name["parent"]["spanId"]
-    for rec in lines:
-        assert int(rec["endTimeUnixNano"]) >= int(rec["startTimeUnixNano"])
-        keys = {a["key"] for a in rec["attributes"]}
-        assert "node.name" in keys
-    # trace_dump renders the OTLP file as a time-aligned tree
-    import importlib.util
-    import io
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "trace_dump", os.path.join(os.path.dirname(__file__), "..",
-                                   "scripts", "trace_dump.py"))
-    td = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(td)
-    trace = td._from_otlp_lines(str(path), ctx.trace_id)
-    buf = io.StringIO()
-    td.render(trace, out=buf)
-    text = buf.getvalue()
-    assert "parent" in text and "kid" in text and "otlp-node" in text
