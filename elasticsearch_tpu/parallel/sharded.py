@@ -30,6 +30,7 @@ from ..query.dsl import parse_query
 from ..utils.jax_env import shard_map
 from ..utils.errors import IllegalArgumentError
 from ..query.nodes import ExecContext, QueryNode
+from .param_pack import pack, packed_counts, unpack
 from .stacked import StackedPack
 
 
@@ -399,10 +400,13 @@ class StackedSearcher:
                 self.dev["live"] = jnp.asarray(self.sp.live)
         self.bump_epoch()
 
-    def _compiled(self, node, key, k, agg_nodes, agg_key):
+    def _compiled(self, node, key, k, agg_nodes, agg_key, layout):
+        """The program of one plan shape. It takes the request's parameters
+        packed (`param_pack.pack`) and unpacks them by `layout`, which is
+        therefore part of its identity."""
         from ..monitoring.device import note_executable_cache
 
-        cache_key = (key, k, agg_key, self._exec)
+        cache_key = (key, k, agg_key, self._exec, layout)
         fn = self._cache.get(cache_key)
         note_executable_cache("search_solo", fn is not None)
         if fn is not None:
@@ -450,7 +454,8 @@ class StackedSearcher:
             return constrain_shards(region(dev, params, agg_params),
                                     self.mesh)
 
-        def search_solo(dev, params, agg_params):
+        def search_solo(dev, buffers):
+            params, agg_params = unpack(buffers, layout)
             ts, ti, tot, agg_out = inner(dev, params, agg_params)
             # global merge: flat index order = (score desc, shard asc,
             # local rank asc) — Lucene TopDocs.merge order. In pjit mode
@@ -476,6 +481,8 @@ class StackedSearcher:
         # the selection tier this program is built with, decided once
         # here: the Pallas streamed scan (fused_scan) or lax.top_k
         fn.topk_tier = topk_mode(n, k_local)
+        # host arrays a call of it is handed, and the leaves packed in them
+        fn.packed = packed_counts(layout)
         self._cache[cache_key] = fn
         return fn
 
@@ -1011,11 +1018,12 @@ class StackedSearcher:
             for i in infos
         ]
         params1, keys1 = synth(p1_rows)
-        fn1 = self._compiled(node, ("wand1", keys1), k, None, ())
+        fn1, buffers1 = self._packed_program(
+            node, ("wand1", keys1), k, None, (), params1, {})
         return {
             "node": node, "terms": terms, "infos": infos, "win_ub": win_ub,
             "synth": synth, "k": k, "size": size, "from_": from_,
-            "outs1": fn1(self.dev, params1, {}),
+            "outs1": self._launch(fn1, buffers1),
         }
 
     def _wand_dispatch2(self, st) -> bool:
@@ -1062,9 +1070,10 @@ class StackedSearcher:
         if dropped == 0:
             return False  # pruning bought nothing; use the exhaustive plan
         params2, keys2 = st["synth"](None, p2_inline)
-        fn2 = self._compiled(node, ("wand2", keys2), k, None, ())
+        fn2, buffers2 = self._packed_program(
+            node, ("wand2", keys2), k, None, (), params2, {})
         st.update(theta=theta, kept=kept, dropped=dropped,
-                  outs2=fn2(self.dev, params2, {}))
+                  outs2=self._launch(fn2, buffers2))
         return True
 
     def _wand_finalize(self, st) -> "StackedResult":
@@ -1405,28 +1414,45 @@ class StackedSearcher:
                 agg_key = tuple(akeys)
             k = min(max(size + from_, 1), max(self.sp.n_max * self.sp.S, 1))
             programs = len(self._cache)  # a miss adds one
-            fn = self._compiled(node, tuple(keys), k, agg_nodes, agg_key)
+            fn, buffers = self._packed_program(
+                node, tuple(keys), k, agg_nodes, agg_key, params, agg_params)
             hit = len(self._cache) == programs
             plan.attributes["program_cache"] = "hit" if hit else "miss"
         from ..monitoring.xla_introspect import check_dispatch
         from ..telemetry import metrics
 
         metrics.counter_inc("es.search.topk." + fn.topk_tier)
-        check_dispatch("sharded.spmd_topk", fn,
-                       (self.dev, params, agg_params),
+        check_dispatch("sharded.spmd_topk", fn, (self.dev, buffers),
                        fields={"queries": 1, "k": k,
                                "num_docs": self.sp.S * self.sp.n_max})
-        # argument transfer and launch; behind a miss also trace, lower and
-        # compile
+        # one transfer per buffer and the launch; behind a miss also trace,
+        # lower and compile
         with TRACER.span("engine.dispatch",
                          **({} if hit else {"compiled": True})):
-            outs = fn(self.dev, params, agg_params)
+            outs = self._launch(fn, buffers)
         return {
             "node": node, "keys": tuple(keys), "k": k, "size": size,
             "from_": from_, "agg_nodes": agg_nodes, "agg_key": agg_key,
             "params": params, "agg_params": agg_params,
             "outs": outs,
         }
+
+    def _packed_program(self, node, key, k, agg_nodes, agg_key, params,
+                        agg_params):
+        """-> (the plan's program, the parameters packed as it unpacks
+        them). Host preparation: no transfer happens here."""
+        buffers, layout = pack((params, agg_params))
+        return (self._compiled(node, key, k, agg_nodes, agg_key, layout),
+                buffers)
+
+    def _launch(self, fn, buffers):
+        """Call a `_compiled` program on packed parameters, counted."""
+        from ..telemetry import metrics
+
+        n_buffers, n_leaves = fn.packed
+        metrics.counter_inc("es.search.dispatch.buffers", n_buffers)
+        metrics.counter_inc("es.search.dispatch.leaves", n_leaves)
+        return fn(self.dev, buffers)
 
     def _agg_pass2_dispatch(self, s) -> bool:
         """Launch pass 2 (two-pass terms candidates) if the request needs
@@ -1453,11 +1479,13 @@ class StackedSearcher:
                 **agg_params[name],
                 "cand": np.broadcast_to(cm, (S, len(cm))).copy(),
             }
-        fn2 = self._compiled(
+        # the candidates are new leaves: the host tree is packed again
+        fn2, buffers = self._packed_program(
             s["node"], s["keys"], s["k"], agg_nodes,
             (s["agg_key"], "tp2",
-             tuple(sorted((n, a._C) for n, a in tp.items()))))
-        s["outs2"] = fn2(self.dev, s["params"], agg_params)
+             tuple(sorted((n, a._C) for n, a in tp.items()))),
+            s["params"], agg_params)
+        s["outs2"] = self._launch(fn2, buffers)
         return True
 
     def _agg_finalize(self, s) -> StackedResult:

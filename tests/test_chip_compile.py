@@ -147,6 +147,52 @@ def test_impact_gather_pallas_compiles(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_packed_parameters_feed_the_solo_scoring_and_scan(
+        one_chip, no_persistent_cache):
+    """PR 27: the solo program takes a `match`'s parameters as one
+    int32[S, W] buffer; its slices (and 32-bit bitcasts) feed the dense
+    row index, the impact tier's gather and the streamed scan."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.index.pack import BLOCK
+    from elasticsearch_tpu.ops.kernels import scan_topk
+    from elasticsearch_tpu.ops.scoring import (dense_term_scores,
+                                               impact_term_scores)
+    from elasticsearch_tpu.parallel.param_pack import pack, unpack
+
+    f32, i32 = np.float32, np.int32
+    dense = (np.zeros((1,), i32), np.ones((1,), f32), np.ones((1,), f32))
+    sparse = (np.zeros((1, 64), i32), np.ones((1,), f32), np.ones((1,), f32),
+              np.ones((1,), f32))
+    buffers, layout = pack(((dense, sparse), np.ones((1,), f32)))
+    assert [(b.shape, b.dtype) for b in buffers] == [((1, 71), np.dtype(i32))]
+
+    def shard_body(dense_tfn, codes, docids, live, params):
+        ((dr, weight, _), (rows, _, _, wscale)), boost = params
+        s1, m1 = dense_term_scores(dense_tfn[dr], weight, N_DOCS)
+        s2, m2 = impact_term_scores(codes, docids, rows, wscale, N_DOCS)
+        scores, ok = boost * (s1 + s2), (m1 | m2)[:N_DOCS] & live
+        return scan_topk(None, scores[None, :N_DOCS], ok, TOP_K,
+                         count_positive=False, interpret=False)
+
+    def solo(dense_tfn, codes, docids, live, buffers):
+        return jax.vmap(shard_body)(dense_tfn, codes, docids, live,
+                                    unpack(buffers, layout))
+
+    compiled = jax.jit(solo).lower(
+        _sds((1, V_DENSE, N_DOCS), jnp.float32, one_chip),
+        _sds((1, N_BLOCKS, BLOCK), jnp.uint16, one_chip),
+        _sds((1, N_BLOCKS, BLOCK), jnp.int32, one_chip),
+        _sds((1, N_DOCS), jnp.bool_, one_chip),
+        tuple(_sds(b.shape, b.dtype, one_chip) for b in buffers),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # no 64-bit bitcast is ever asked of the chip
+    assert "s64" not in text and "f64" not in text
+
+
 def test_sharded_fused_region_compiles_on_four_chips(mesh4,
                                                      no_persistent_cache):
     """The one-program fused `_msearch` of a 4-shard index: the Pallas

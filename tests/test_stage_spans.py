@@ -406,10 +406,11 @@ def test_the_compiled_search_is_named_search_solo_and_carries_its_scopes(
         searcher):
     st = searcher._agg_dispatch(query={"match": {"body": "alpha gamma"}},
                                 size=3)
-    fn = searcher._compiled(st["node"], st["keys"], st["k"], None, ())
+    fn, buffers = searcher._packed_program(
+        st["node"], st["keys"], st["k"], None, (), st["params"],
+        st["agg_params"])
     assert fn.__name__ == "search_solo"
-    text = fn.lower(searcher.dev, st["params"], st["agg_params"]).as_text(
-        debug_info=True)
+    text = fn.lower(searcher.dev, buffers).as_text(debug_info=True)
     assert "jit(search_solo)" in text
     # a transformation wraps the scope it passes through: `vmap(score)`
     for scope in ("score", "topk"):
