@@ -24,17 +24,14 @@ Nothing of `elasticsearch_tpu` is imported here.
 
 from __future__ import annotations
 
-import re
-
 from . import trace
 
 SCOPE_STAT = "tf_op"
 SCOPES = ("score", "topk")    # the program also scopes `aggs`; no cell has any
 SEARCH = "rest.search"
-MODULES_LINE = "XLA Modules"
-# a stage annotation is named as its stage; nothing else on a host plane is
-STAGE = re.compile(r"^(engine|rest)\.[a-z_]+$")
-NO_STAGE = "no stage"
+# `trace.py` lays the gaps against the stages since PR 28 and holds these; the
+# names stay here for their readers (`tests/test_stage_spans.py` among them)
+MODULES_LINE, STAGE, NO_STAGE = trace.MODULES_LINE, trace.STAGE, trace.NO_STAGE
 
 
 def _delta(run, key: str):
@@ -160,7 +157,10 @@ def scopes_of(xspace: bytes) -> dict:
     """-> {"scopes": {scope: s}, "programs": {program: s}, "unscoped_s": s}
     of a serialized capture: seconds of the first device's `XLA Ops` by the
     scope their `tf_op` carries, and of its `XLA Modules` by program name.
-    Empty where there is no device plane."""
+    Empty where there is no device plane. On several device planes (a cell on
+    four chips) it is still the first plane's alone, one chip's seconds: the
+    chips of one sharded program run the same scopes side by side, so this is
+    the time a request holds each of them, not their sum."""
     out = {"scopes": {}, "programs": {}, "unscoped_s": 0.0}
     for n, plane in _fields(memoryview(xspace)):
         name = _text(_first(plane, 2)) if n == 1 else ""
@@ -184,14 +184,14 @@ def scopes_of(xspace: bytes) -> dict:
             if m != 3:
                 continue
             line_name = _text(_first(line, 2))
-            if line_name not in (trace.OPS_LINE, MODULES_LINE):
+            if line_name not in (trace.OPS_LINE, trace.MODULES_LINE):
                 continue
             for k, event in _fields(line):
                 if k != 4:
                     continue
                 meta_id = _first(event, 1, 0)
                 seconds = _first(event, 3, 0) * 1e-12
-                if line_name == MODULES_LINE:
+                if line_name == trace.MODULES_LINE:
                     key = program_of(name_by_id.get(meta_id, ""))
                     out["programs"][key] = (out["programs"].get(key, 0.0)
                                             + seconds)
@@ -214,42 +214,21 @@ def scope_ms_per_request(run, scope: str):
     return seconds * 1e3 / n
 
 
-def idle_by_stage(run, lead_s: float = 0.0) -> dict:
+def idle_by_stage(run, lead_s: float | None = None) -> dict:
     """Seconds of the first device's idle gaps under each leaf stage's
-    annotation, and under none (`no stage`). A builder's aid for PERF.md and
-    no metric: `breakdown.idle_gaps` is the driver's view of the same gaps,
-    one name a gap; this one splits a gap over every stage that overlaps it.
-    `lead_s` sets the device's clock back first: in a v5e capture the device
-    plane runs 0.3-1.5 ms ahead of the host planes (the median, over the
-    programs, of the host's `DoEnqueueProgram` start less the module's start
-    is a lower bound of it), which moves idle time from the dispatch to the
-    fetch in both views."""
+    annotation, and under none (`no stage`), on the host's clock: what
+    `breakdown.idle_gaps` gives the driver (`trace.reduce`, `trace.
+    idle_by_stage`), from the run's own capture. The device plane's clock is
+    set back by `lead_s` first, by default `trace.device_lead`'s estimate (in
+    a v5e capture the device plane runs 0.24-1.5 ms ahead of the host planes,
+    which left alone moves idle time from the dispatch to the fetch); 0.0
+    gives the capture as it stands."""
     profile = _capture(run)
     dev = trace.device_events(profile) if profile is not None else {}
     if not dev:
         return {}
-    gaps, end = [], None
-    for s, e, _ in next(iter(dev.values())):
-        if end is not None and s > end:
-            gaps.append((end + lead_s, s + lead_s))
-        end = e if end is None else max(end, e)
-    stages = sorted(
-        (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, e.name)
-        for plane in profile.planes if plane.name.startswith("/host:")
-        for ln in plane.lines for e in ln.events if STAGE.match(e.name))
-    out = {NO_STAGE: 0.0}
-    i = 0
-    for g0, g1 in gaps:
-        while i < len(stages) and stages[i][1] <= g0:
-            i += 1
-        covered = []
-        j = i
-        while j < len(stages) and stages[j][0] < g1:
-            s, e, name = stages[j]
-            lo, hi = max(s, g0), min(e, g1)
-            if hi > lo:
-                out[name] = out.get(name, 0.0) + (hi - lo)
-                covered.append((lo, hi))
-            j += 1
-        out[NO_STAGE] += (g1 - g0) - trace.union_seconds(covered)
-    return out
+    if lead_s is None:
+        lead_s = trace.device_lead(profile)
+    gaps = [(g0 + lead_s, g1 + lead_s)
+            for g0, g1 in trace.idle_gaps_of(next(iter(dev.values())))]
+    return trace.idle_by_stage(gaps, trace.stage_events(profile))

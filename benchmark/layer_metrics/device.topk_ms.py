@@ -1,6 +1,8 @@
 """Kernels: device milliseconds a request spends under the scope `topk`
 (`XLA Ops` whose op_name carries it: the streamed Pallas top-k and the global
-merge), over the requests sent and answered inside the capture."""
+merge), over the requests sent and answered inside the capture. On several
+device planes it is the first plane's time: what one of the chips that share
+a request spends on it, not the sum over the chips."""
 
 from benchlib import spans
 

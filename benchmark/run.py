@@ -8,7 +8,8 @@ The harness never touches JAX while it measures: it starts
 the chip), drives it over HTTP and stops it before it prints its last line.
 
 One run, in this order: corpus and query pool from --seed -> start server ->
-cluster settings of the configuration -> create index, _bulk, _refresh ->
+cluster settings of the configuration -> create index, _bulk, _refresh -> on
+several chips, see that the pack lies on all of them (`pack_spread`) ->
 warm-up (whole pool through the cell's own clients, until one full pass
 compiles nothing) -> window of --seconds -> read counters -> stop server ->
 compare the window's own answers with the NumPy reference -> last line.
@@ -132,10 +133,35 @@ def device_of(node: dict, peaks: dict, chips: int, require_chip: bool) -> dict:
     return device
 
 
+def memory_peaks(node: dict) -> list[int]:
+    """Peak bytes in use on each device the server reports, in its order."""
+    return [int(d.get("peak_bytes_in_use", 0))
+            for d in node["device"]["memory"].get("devices", [])]
+
+
 def memory_peak(node: dict) -> int:
-    mem = node["device"]["memory"]
-    per = [d.get("peak_bytes_in_use", 0) for d in mem.get("devices", [])]
-    return int(max(per + [mem.get("peak_bytes_in_use", 0)]))
+    """Peak bytes in use on the fullest device."""
+    return max(memory_peaks(node)
+               + [int(node["device"]["memory"].get("peak_bytes_in_use", 0))])
+
+
+def pack_spread(node: dict, chips: int, require_chip: bool) -> list[int]:
+    """Bytes each device holds once the index is loaded (the allocator's own
+    `bytes_in_use` where the backend has one, else the live arrays'), for a
+    cell on several chips. An error unless the `chips` fullest devices each
+    hold at least an eighth of all bytes held (chip_smoke.py's rule): the
+    program falls back to one device in silence where it finds no mesh, and
+    a pack on one chip of four is another deployment. On the chips there are
+    exactly `chips` devices; off them (`require_chip` false) the server may
+    report more, and the rule is the same."""
+    held = [int(d.get("bytes_in_use", d.get("live_bytes", 0)))
+            for d in node["device"]["memory"].get("devices", [])]
+    fullest = sorted(held, reverse=True)[:chips]
+    if (len(held) < chips or (require_chip and len(held) != chips)
+            or any(h * 8 < sum(held) for h in fullest)):
+        raise BenchError(f"the pack is not spread over {chips} devices: "
+                         f"bytes held per device {held}")
+    return held
 
 
 def counters_of(node: dict) -> dict:
@@ -250,9 +276,13 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         run.setup = load(c, INDEX, run.corpus, config["search"]["field"],
                          int(config["bulk_docs"]),
                          int(config["number_of_shards"]), say)
-        fd = c.node_stats()["breakers"]["fielddata"]
+        node = c.node_stats()
+        fd = node["breakers"]["fielddata"]
         say(f"breaker: the packs charge {fd['estimated_size_in_bytes']} bytes "
             f"to one device, fielddata limit {fd['limit_size_in_bytes']} bytes")
+        if int(config["chips"]) > 1:
+            held = pack_spread(node, int(config["chips"]), require_chip)
+            say(f"spread: bytes held per device {held}")
 
         lg = LoadGenerator(server.port, INDEX, bodies, int(traffic["clients"]),
                            traffic.get("rate"))
@@ -305,7 +335,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
             run.untraced = run.requests
         node = c.node_stats()
         run.after = counters_of(node)
-        peak_bytes = memory_peak(node)
+        peak_bytes, peaks_per_device = memory_peak(node), memory_peaks(node)
         if not server.alive():
             raise BenchError("the server died during the window")
     except Exception as e:
@@ -339,7 +369,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     units = {m["name"]: m["unit"] for m in spec["end_to_end"] + spec["per_layer"]}
     result = {"correct": verdict["correct"], "attempted": summary["attempted"],
               "failed": summary["failed"], "metrics": metrics,
-              "device": dict(device, memory_peak_bytes=peak_bytes)}
+              "device": dict(device, memory_peak_bytes=peak_bytes,
+                             memory_peak_bytes_per_device=peaks_per_device)}
     if traced:
         cap_dir = stopped.get("dir") or os.path.join(work, "trace")
         xplane = trace.find_xplane(cap_dir)
@@ -353,6 +384,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         if run.trace is not None:
             result["device"]["busy_s"] = run.trace["busy_s"]
             result["device"]["window_s"] = run.trace["span_s"]
+            result["device"]["device_lead_s"] = run.trace["device_lead_s"]
             result["breakdown"] = {"device_ops": run.trace["device_ops"],
                                    "idle_gaps": run.trace["idle_gaps"]}
         for name, read in readers.items():

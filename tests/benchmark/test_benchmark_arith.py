@@ -344,13 +344,111 @@ def test_hand_written_device_plane_busy_idle_top_ops_and_gap_names():
     assert got["idle_gaps"] == [["engine: plan", pytest.approx(4e-3)]]
 
 
-def test_postings_roofline_least_time():
+FOUR_PLANES = '''
+planes { name: "/device:TPU:0"
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 1000000000 duration_ps: 1000000000 }
+    events { metadata_id: 2 offset_ps: 2000000000 duration_ps: 1000000000 }
+    events { metadata_id: 1 offset_ps: 6000000000 duration_ps: 2000000000 } }
+  lines { name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 3 offset_ps: 1000000000 duration_ps: 2000000000 }
+    events { metadata_id: 3 offset_ps: 6000000000 duration_ps: 2000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%scan = f32[8] custom-call()" } }
+  event_metadata { key: 2 value { id: 2 name: "%all-gather.1 = f32[32] all-gather()" } }
+  event_metadata { key: 3 value { id: 3 name: "jit_search_solo(5)" } } }
+planes { name: "/device:TPU:1"
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 2000000000 }
+    events { metadata_id: 2 offset_ps: 2000000000 duration_ps: 1000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%scan = f32[8] custom-call()" } }
+  event_metadata { key: 2 value { id: 2 name: "%all-gather.1 = f32[32] all-gather()" } } }
+planes { name: "/device:TPU:2"
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 1500000000 duration_ps: 500000000 }
+    events { metadata_id: 2 offset_ps: 2000000000 duration_ps: 1000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%scan = f32[8] custom-call()" } }
+  event_metadata { key: 2 value { id: 2 name: "%all-gather.1 = f32[32] all-gather()" } } }
+planes { name: "/device:TPU:3"
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 1000000000 duration_ps: 1500000000 }
+    events { metadata_id: 1 offset_ps: 2000000000 duration_ps: 1000000000 }
+    events { metadata_id: 2 offset_ps: 8500000000 duration_ps: 1000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%scan = f32[8] custom-call()" } }
+  event_metadata { key: 2 value { id: 2 name: "%all-gather.1 = f32[32] all-gather()" } } }
+planes { name: "/device:TPU:0 CUSTOM trace"
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 9000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "not an operation" } } }
+planes { name: "/host:CPU"
+  lines { name: "python" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 3500000000 duration_ps: 2000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "engine.plan" } } }'''
+
+
+def test_four_device_planes_with_unequal_busy_time():
+    """A cell on four chips: each plane's own busy time, their mean, the span
+    from the first operation on any plane to the last on any, operations in
+    seconds a plane, and the first plane's gaps."""
+    from jax.profiler import ProfileData
+
+    got = trace.reduce(ProfileData.from_text_proto(FOUR_PLANES))
+    # plane 0: 1-3 and 6-8 ms; plane 1: 0-3; plane 2: 1.5-3; plane 3: 1-3
+    # (two operations overlap) and 8.5-9.5
+    assert got["per_device_busy_s"] == {
+        "/device:TPU:0": pytest.approx(4e-3), "/device:TPU:1": pytest.approx(3e-3),
+        "/device:TPU:2": pytest.approx(1.5e-3), "/device:TPU:3": pytest.approx(3e-3)}
+    assert got["busy_s"] == pytest.approx((4 + 3 + 1.5 + 3) / 4 * 1e-3)
+    assert got["span_s"] == pytest.approx(9.5e-3)     # 0 on plane 1, 9.5 on plane 3
+    assert got["device_ops"] == [["scan", pytest.approx(8 / 4 * 1e-3)],
+                                 ["all-gather.1", pytest.approx(4 / 4 * 1e-3)]]
+    # the first plane idles 3-6 ms; the plan's annotation covers 3.5-5.5
+    assert got["idle_gap_s"] == pytest.approx(3e-3)
+    assert got["idle_gaps"] == [["engine.plan", pytest.approx(2e-3)],
+                                [trace.NO_STAGE, pytest.approx(1e-3)]]
+    assert got["device_lead_s"] == 0.0
+
+
+def _roofline():
     import importlib.util
 
     path = os.path.join(BENCH, "layer_metrics", "postings_roofline.py")
     spec = importlib.util.spec_from_file_location("pr", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("devices, want", [(1, 1.0), (4, 0.25)])
+def test_postings_roofline_least_time(devices, want):
     df = np.array([100, 10, 1])
-    # (100 + 10) + (1) postings x 8 bytes at 888 bytes/s
-    assert mod.least_seconds(df, [[0, 1], [2]], 888.0) == pytest.approx(1.0)
+    # (100 + 10) + (1) postings x 8 bytes at 888 bytes/s a device
+    mod = _roofline()
+    assert mod.least_seconds(df, [[0, 1], [2]], 888.0, devices) == \
+        pytest.approx(want)
+    if devices == 1:     # as it was before it took a number of devices
+        assert mod.least_seconds(df, [[0, 1], [2]], 888.0) == \
+            111 * 8 / 888.0
+
+
+def test_postings_roofline_on_four_planes_is_bytes_over_four_chips_rate():
+    from jax.profiler import ProfileData
+
+    class Run:
+        trace = trace.reduce(ProfileData.from_text_proto(FOUR_PLANES))
+        peak = {"hbm_bytes_per_s": 819e9}
+        pool = [[0, 1], [2]]
+        traced = [Request(0, 0, 1, 200), Request(1, 1, 2, 200),
+                  Request(1, 1, 2, 503)]
+
+        @staticmethod
+        def df():
+            return np.array([3_000_000, 500_000, 70_000])
+
+    mean_busy = (4 + 3 + 1.5 + 3) / 4 * 1e-3
+    want = 100.0 * (3_570_000 * 8 / (4 * 819e9)) / mean_busy
+    assert _roofline().read(Run) == pytest.approx(want, rel=1e-12)
+    # one plane of the same capture: one chip's rate over that plane's time
+    Run.trace = dict(Run.trace, busy_s=4e-3,
+                     per_device_busy_s={"/device:TPU:0": 4e-3})
+    assert _roofline().read(Run) == pytest.approx(
+        100.0 * (3_570_000 * 8 / 819e9) / 4e-3, rel=1e-12)

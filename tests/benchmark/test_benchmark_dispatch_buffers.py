@@ -6,12 +6,12 @@ import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH = os.path.join(REPO, "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+HERE = os.path.dirname(os.path.abspath(__file__))
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)
 
-import run as harness  # noqa: E402
+import checks  # noqa: E402
+from checks import BENCH, REPO, harness  # noqa: E402
 
 BUFFERS = "es.search.dispatch.buffers"
 SEARCHES = "es.span.rest.search.count"
@@ -53,16 +53,7 @@ def test_nothing_to_read_gives_none_and_never_raises(before, after):
 
 
 def test_the_metric_is_declared_in_both_cells_under_its_layer():
-    import json
-
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        spec = json.load(f)
-    entry = next(m for m in spec["per_layer"]
-                 if m["name"] == "engine.dispatch_buffers")
-    assert entry == {
-        "name": "engine.dispatch_buffers", "unit": "count", "better": "lower",
-        "source": "program_counter",
-        "layer": "host planning, dispatch and fetch",
-        "moves": "search_p50_ms",
-        "workloads": ["passage.solo.c1", "passage.solo.c8"]}
-    assert spec["per_layer"][-1] is entry       # appended, nothing moved
+    # by what the entry says and by who reports it; where it stands in the
+    # list, and who else reports it, is the next PR's to change
+    checks.check_declared(REPO, checks.DISPATCH_BUFFERS,
+                          ["passage.solo.c1", "passage.solo.c8"])

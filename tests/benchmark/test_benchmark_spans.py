@@ -216,12 +216,14 @@ def test_an_idle_gap_is_split_over_the_stages_that_overlap_it(tmp_path):
         "engine.fetch": pytest.approx(1e-3),
         "engine.plan": pytest.approx(2e-3),
         "rest.respond": pytest.approx(1e-3),
-        spans.NO_STAGE: pytest.approx(0.5e-3)}
+        trace.NO_STAGE: pytest.approx(0.5e-3)}
+    # no program is shown enqueued, so no lead is taken out by default
+    assert spans.idle_by_stage(run, 0.0) == spans.idle_by_stage(run)
     # the device's clock set back by 1 ms: the gap is 4-8 ms
     assert spans.idle_by_stage(run, 1e-3) == {
         "engine.plan": pytest.approx(2e-3),
         "rest.respond": pytest.approx(1e-3),
-        spans.NO_STAGE: pytest.approx(1.5e-3)}
+        trace.NO_STAGE: pytest.approx(1.5e-3)}
 
 
 @pytest.mark.parametrize("metric, want", [("device.topk_ms", 1.0),
@@ -272,29 +274,101 @@ def test_the_recorded_capture_names_its_gaps_by_stage(recorded, tmp_path):
     profile, want = recorded
     reduced = trace.reduce(profile)
     assert reduced["busy_s"] == pytest.approx(want["busy_s"], rel=1e-4)
-    named = [name for name, _ in reduced["idle_gaps"]]
-    assert named[0].split(": ", 1)[1] == want["largest_gap"]
-    assert not [n for n in named if ".py:" in n or "$" in n]
-    # the expected split is from a timeline of 1 ns cells
+    # what the driver gets: the gaps on the host's clock, split over the stages
+    assert reduced["device_lead_s"] == pytest.approx(want["device_lead_s"],
+                                                     abs=1e-6)
+    assert dict(reduced["idle_gaps"]) == {
+        k: pytest.approx(v, rel=1e-4)
+        for k, v in want["idle_split_on_host_clock"].items()}
+    stages = [name for name, _ in reduced["idle_gaps"] if name != trace.NO_STAGE]
+    assert stages[0] == want["largest_gap"] == "engine.dispatch"
+    assert not [n for n in stages if ".py:" in n or "$" in n]
+    # the expected splits are from a timeline of 1 ns cells
     with open(RECORDED, "rb") as f:
         run = _run_with_capture(tmp_path, f.read())
-    got = spans.idle_by_stage(run)
+    got = spans.idle_by_stage(run, 0.0)              # as the capture stands
     assert got == {k: pytest.approx(v, rel=1e-4)
                    for k, v in want["idle_split"].items()}
     assert sum(got.values()) == pytest.approx(
         want["span_s"] - want["busy_s"], rel=1e-4)
+    assert spans.idle_by_stage(run) == dict(reduced["idle_gaps"])
 
 
 def test_the_device_clock_leads_the_host_clock_in_the_recorded_capture(
         recorded, tmp_path):
     """A program starts on the device plane before the host has enqueued it:
-    the two clocks of a v5e capture differ, and the idle split moves from
-    the fetch to the dispatch once the lead (measured with the capture, by
-    hand) is taken out."""
+    the two clocks of a v5e capture differ. `trace.device_lead` estimates the
+    lead as PR 26 measured it by hand (the expected file's `device_lead_s`,
+    over the cut's four programs), and the idle split moves from the fetch to
+    the dispatch once it is taken out, which is the default."""
+    profile, want = recorded
+    assert trace.device_lead(profile) == pytest.approx(want["device_lead_s"],
+                                                       abs=1e-6)
     with open(RECORDED, "rb") as f:
         run = _run_with_capture(tmp_path, f.read())
-    raw = spans.idle_by_stage(run)
-    set_back = spans.idle_by_stage(run, recorded[1]["device_lead_s"])
+    raw = spans.idle_by_stage(run, 0.0)
+    set_back = spans.idle_by_stage(run)
+    assert set_back == spans.idle_by_stage(run, want["device_lead_s"])
     assert sum(set_back.values()) == pytest.approx(sum(raw.values()))
     assert raw["engine.fetch"] > raw["engine.dispatch"]
     assert set_back["engine.dispatch"] > set_back["engine.fetch"]
+    # idle time under the dispatch, a program, is the dispatch itself: the
+    # device waits through all of it (1.70 ms, the annotations' mean, less
+    # what the launch's own latency hides from the estimate)
+    dispatches = [e.duration_ns * 1e-9 for plane in profile.planes
+                  for ln in plane.lines for e in ln.events
+                  if e.name == "engine.dispatch"]
+    assert len(dispatches) == 4
+    assert set_back["engine.dispatch"] / 4 == pytest.approx(
+        sum(dispatches) / 4, abs=0.2e-3)
+
+
+LEAD = '''
+planes { name: "/device:TPU:0"
+  lines { name: "XLA Modules" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 1000000000 duration_ps: 500000000 }
+    events { metadata_id: 1 offset_ps: 5000000000 duration_ps: 500000000 }
+    events { metadata_id: 1 offset_ps: 9000000000 duration_ps: 500000000 } }
+  lines { name: "XLA Ops" timestamp_ns: 0
+    events { metadata_id: 2 offset_ps: 1000000000 duration_ps: 500000000 }
+    events { metadata_id: 2 offset_ps: 5000000000 duration_ps: 500000000 }
+    events { metadata_id: 2 offset_ps: 9000000000 duration_ps: 500000000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_search_solo(7)" } }
+  event_metadata { key: 2 value { id: 2 name: "%fusion = f32[8] fusion()" } } }
+planes { name: "/host:CPU"
+  lines { name: "launcher" timestamp_ns: 0
+    ENQUEUED }
+  lines { name: "python" timestamp_ns: 0
+    events { metadata_id: 2 offset_ps: 5200000000 duration_ps: 600000000 } }
+  event_metadata { key: 1 value { id: 1 name: "DoEnqueueProgram" } }
+  event_metadata { key: 2 value { id: 2 name: "engine.dispatch" } } }'''
+
+
+def _enqueued_at(*ms):
+    from jax.profiler import ProfileData
+
+    events = " ".join(f"events {{ metadata_id: 1 offset_ps: {int(t * 1e9)} "
+                      "duration_ps: 20000000 }" for t in ms)
+    return ProfileData.from_text_proto(LEAD.replace("ENQUEUED", events))
+
+
+@pytest.mark.parametrize("enqueued_ms, want_ms", [
+    ((1.8, 5.8, 9.8), 0.8),          # every program shown enqueued 0.8 ms late
+    ((1.7, 5.8, 9.9), 0.8),          # the median of the readings
+    ((1.7, 5.9), 0.9),               # of two, the upper: each is a lower bound
+    ((5.8, 9.8), 0.8),               # the first program was enqueued before the capture
+    ((0.9, 4.9, 8.9), 0.0),          # the host's clock is not behind: never below 0
+    ((), 0.0),                       # no such event: the capture as it stands
+])
+def test_the_lead_of_a_hand_written_capture(enqueued_ms, want_ms):
+    profile = _enqueued_at(*enqueued_ms)
+    assert trace.device_lead(profile) == pytest.approx(want_ms * 1e-3, abs=1e-9)
+    reduced = trace.reduce(profile)
+    assert reduced["device_lead_s"] == pytest.approx(want_ms * 1e-3, abs=1e-9)
+    # the device idles 1.5-5 and 5.5-9 ms on its own clock; the dispatch's
+    # annotation runs 5.2-5.8 ms on the host's
+    under = dict(reduced["idle_gaps"]).get("engine.dispatch", 0.0)
+    assert under == pytest.approx(
+        {0.0: 0.3e-3, 0.8: 0.6e-3, 0.9: 0.6e-3}[want_ms], abs=1e-9)
+    assert reduced["busy_s"] == pytest.approx(1.5e-3)
+    assert reduced["span_s"] == pytest.approx(8.5e-3)
