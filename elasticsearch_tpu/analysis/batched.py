@@ -76,12 +76,6 @@ def analyze_device_min() -> int:
         return 1 << 16
 
 
-def analyze_overlap_enabled() -> bool:
-    """Depth-1 analyze(k) / build(k-1) pipelining in the stacked build
-    (parallel/stacked.py); ES_TPU_ANALYZE_OVERLAP=0 disables."""
-    return os.environ.get("ES_TPU_ANALYZE_OVERLAP", "1") != "0"
-
-
 def _empty_i64() -> np.ndarray:
     return np.empty(0, np.int64)
 

@@ -87,6 +87,13 @@ class Setting:
             raise IllegalArgumentError(f"must be >= 0, got [{raw}]")
         return v
 
+    @staticmethod
+    def at_least_one(raw):
+        v = int(raw)
+        if v < 1:
+            raise IllegalArgumentError(f"must be >= 1, got [{raw}]")
+        return v
+
 
 class ClusterSettings:
     """Registry + live values + update consumers + persistence.
@@ -354,6 +361,12 @@ def default_cluster_settings() -> list[Setting]:
         # its new docs as one sealed segment; beyond this many segments
         # a background fold merges them (the Lucene merge-policy analog)
         Setting("indexing.tiers.max_segments", 4, Setting.positive_int,
+                dynamic=True),
+        # shards a refresh builds at once (analysis + pack build of each,
+        # on its own thread, its device stages on the shard's own device;
+        # parallel/stacked.py). Unset: one builder a shard, as far as the
+        # host has cores. It bounds the host memory a refresh holds
+        Setting("indexing.refresh.shard_builders", None, Setting.at_least_one,
                 dynamic=True),
         # serving-wave flight recorder (PR 12): bounded ring of per-wave
         # segment timings / tenant mix / kernel deltas, dumped to the

@@ -23,6 +23,7 @@ index/seqno/LocalCheckpointTracker.java — a documented simplification).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -154,6 +155,10 @@ class EsIndex:
         self._op_log_min = 0
         self.data_dir = data_dir
         self._wal = None
+        # inside `wal_sync_deferred` (one `_bulk` request) records are
+        # written and the one sync waits for the request's last item
+        self._wal_deferred = False
+        self._wal_unsynced = False
         self._dirty = True
         # refresh lag (PR 13): monotonic stamp of the OLDEST write not yet
         # made visible by a refresh — the write-path analog of queue wait,
@@ -228,12 +233,43 @@ class EsIndex:
         with open(os.path.join(self.data_dir, "meta.json"), "w", encoding="utf-8") as f:
             json.dump({"mappings": self.mappings.to_dict(), "settings": self.settings}, f)
 
-    def _wal_append(self, record: dict):
+    def _wal_append(self, line: str):
+        """One record (a JSON line) to the WAL, synced before the write is
+        acknowledged: at once for a single-document write, once for all the
+        records of a `_bulk` request (`wal_sync_deferred`)."""
         if self._wal is None:
             return
-        self._wal.write(json.dumps(record, separators=(",", ":")) + "\n")
+        self._wal.write(line)
+        if self._wal_deferred:
+            self._wal_unsynced = True
+        else:
+            self._wal_sync()
+
+    def _wal_sync(self):
+        from ..telemetry import metrics
+
         self._wal.flush()
         os.fsync(self._wal.fileno())
+        self._wal_unsynced = False
+        metrics.counter_inc("es.wal.syncs")
+
+    @contextlib.contextmanager
+    def wal_sync_deferred(self):
+        """The records appended inside are synced once, on the way out and
+        so before anything of the request is acknowledged: the reference's
+        default `index.translog.durability: request` (Translog.java,
+        Durability.REQUEST; TransportWriteAction syncs the translog once a
+        bulk shard request). Nested use syncs at the outermost exit."""
+        if self._wal_deferred:
+            yield
+            return
+        self._wal_deferred = True
+        try:
+            yield
+        finally:
+            self._wal_deferred = False
+            if self._wal_unsynced and self._wal is not None:
+                self._wal_sync()
 
     def flush(self):
         """Commit: snapshot live state + truncate the WAL + purge tombstones
@@ -440,7 +476,11 @@ class EsIndex:
         self.docs[doc_id] = _DocEntry(source, version, seq, True)
         self._op_log_append(seq, doc_id)
         self._pending.add(doc_id)
-        self._wal_append({"op": "index", "id": doc_id, "source": source, "version": version, "seq_no": seq})
+        # the record `json.dumps` would give, around the source's own
+        # serialization above
+        self._wal_append(f'{{"op":"index","id":{json.dumps(doc_id)},'
+                         f'"source":{src_json},"version":{version},'
+                         f'"seq_no":{seq}}}\n')
         if len(self.mappings.fields) != n_fields:
             self._persist_meta()  # dynamic mappings grew
         self._dirty = True
@@ -468,7 +508,9 @@ class EsIndex:
         self.seq_no += 1
         self._op_log_append(e.seq_no, doc_id)
         self._pending.add(doc_id)
-        self._wal_append({"op": "delete", "id": doc_id, "version": e.version, "seq_no": e.seq_no})
+        self._wal_append(json.dumps(
+            {"op": "delete", "id": doc_id, "version": e.version,
+             "seq_no": e.seq_no}, separators=(",", ":")) + "\n")
         self._dirty = True
         if self._dirty_since is None:
             self._dirty_since = time.monotonic()
@@ -688,7 +730,8 @@ class EsIndex:
                             nbytes=self._base_nbytes):
             with refresh_stage("route"):
                 routed = self._route_docs(visible)
-            sp = build_stacked_pack_routed(routed, self.mappings)
+            sp = build_stacked_pack_routed(
+                routed, self.mappings, **self._shard_build_args(base.mesh))
             self._account_packs(sp.nbytes(), base.mesh)
             searcher = StackedSearcher(sp, mesh=base.mesh)
             # ---- atomic install: nothing above touched serving state
@@ -730,10 +773,11 @@ class EsIndex:
         # _source snapshot (the analog of stored fields in a sealed segment)
         with refresh_stage("route"):
             routed = self._route_docs(live_docs)
-        sp = build_stacked_pack_routed(routed, self.mappings)
         if mesh is None:
             mesh = (self._searcher.mesh if self._searcher is not None
                     else make_mesh(self.num_shards))
+        sp = build_stacked_pack_routed(routed, self.mappings,
+                                       **self._shard_build_args(mesh))
         # admission control BEFORE shipping to the device: on trip, the
         # old searcher stays live (HierarchyCircuitBreakerService analog)
         self._account_packs(sp.nbytes(), mesh)
@@ -850,8 +894,9 @@ class EsIndex:
             return
         with refresh_stage("route"):
             routed = self._route_docs(sorted(new_docs.items()))
-        seg_sp = build_stacked_pack_routed(routed, self.mappings,
-                                           dense_min_df=1 << 62)
+        seg_sp = build_stacked_pack_routed(
+            routed, self.mappings, dense_min_df=1 << 62,
+            **self._shard_build_args(base.mesh))
         # total deadness across tiers: the WAND prune floor subtracts it
         # from df before promising an exact count (sharded._wand_plan)
         seg_sp.dead_count = sum(
@@ -887,6 +932,24 @@ class EsIndex:
             self._schedule_tail_merge()
 
     # ---- LSM tail-segment merging (PR 15) --------------------------------
+
+    def _shard_build_args(self, mesh) -> dict:
+        """How `build_stacked_pack_routed` builds this index's shards: as
+        many at once as the dynamic `indexing.refresh.shard_builders` says
+        (unset: one a shard, as far as the host has cores), each shard's
+        device stages on the device of `mesh` that will hold it."""
+        builders = None
+        try:
+            if self.engine is not None:
+                builders = self.engine.settings.get(
+                    "indexing.refresh.shard_builders")
+        except Exception:  # noqa: BLE001 - default for standalone indices
+            pass
+        devices = None
+        if mesh is not None:
+            grid = np.asarray(mesh.devices).reshape(self.num_shards, -1)
+            devices = list(grid[:, 0])
+        return {"shard_builders": builders, "devices": devices}
 
     def max_tail_segments(self) -> int:
         """Segment-count bound before a tail fold is scheduled (dynamic
@@ -949,8 +1012,9 @@ class EsIndex:
                             nbytes=old_nbytes):
             with refresh_stage("route"):
                 routed = self._route_docs(visible)
-            sp = build_stacked_pack_routed(routed, self.mappings,
-                                           dense_min_df=1 << 62)
+            sp = build_stacked_pack_routed(
+                routed, self.mappings, dense_min_df=1 << 62,
+                **self._shard_build_args(base.mesh))
             sp.dead_count = getattr(base.sp, "dead_count", 0)
             self._account_packs(self._base_nbytes + sp.nbytes(), base.mesh)
             merged = _TailSegment(
@@ -3651,8 +3715,10 @@ class Engine:
         ingest timestamp per run instead of per doc — while every
         per-item error envelope and result stays identical to the
         per-doc path (asserted by tests/test_ingest.py)."""
+        from ..telemetry import metrics
         from ..utils.errors import ElasticsearchTpuError
 
+        metrics.counter_inc("es.bulk.requests")
         items: list = []
         errors = False
         name_cache: dict = {}   # raw name -> (concrete index name, EsIndex)
@@ -3733,50 +3799,56 @@ class Engine:
                 transformed[k] = out
             i = j
 
-        # pass 3: apply, in original order, with per-item envelopes
-        for k, (action, index_name, idx, doc_id, source, err) in (
-                enumerate(resolved)):
-            if err is not None:
-                items.append(err)
-                continue
-            try:
-                if action in ("index", "create"):
-                    if k in transformed:
-                        source = transformed[k]
-                        if isinstance(source, Exception):
-                            raise source
-                    if source is None:  # dropped by pipeline
-                        items.append({action: {
-                            "_index": index_name, "_id": doc_id,
-                            "result": "noop", "status": 200,
-                        }})
-                        continue
-                    r = idx.index_doc(doc_id, source, op_type=action)
-                    status = 201 if r["result"] == "created" else 200
-                    items.append({action: {"_index": index_name, **r,
-                                           "status": status}})
-                elif action == "delete":
-                    r = idx.delete_doc(doc_id)
-                    items.append({action: {"_index": index_name, **r,
-                                           "status": 200}})
-                elif action == "update":
-                    if not isinstance(source, dict) or not isinstance(
-                            source.get("doc"), dict):
+        # pass 3: apply, in original order, with per-item envelopes. Every
+        # item's record goes to its index's WAL as it is applied; each
+        # touched WAL is synced once, after the last item and before the
+        # response is built, so nothing is acknowledged unsynced
+        with contextlib.ExitStack() as wal_syncs:
+            for _name, idx in name_cache.values():
+                wal_syncs.enter_context(idx.wal_sync_deferred())
+            for k, (action, index_name, idx, doc_id, source, err) in (
+                    enumerate(resolved)):
+                if err is not None:
+                    items.append(err)
+                    continue
+                try:
+                    if action in ("index", "create"):
+                        if k in transformed:
+                            source = transformed[k]
+                            if isinstance(source, Exception):
+                                raise source
+                        if source is None:  # dropped by pipeline
+                            items.append({action: {
+                                "_index": index_name, "_id": doc_id,
+                                "result": "noop", "status": 200,
+                            }})
+                            continue
+                        r = idx.index_doc(doc_id, source, op_type=action)
+                        status = 201 if r["result"] == "created" else 200
+                        items.append({action: {"_index": index_name, **r,
+                                               "status": status}})
+                    elif action == "delete":
+                        r = idx.delete_doc(doc_id)
+                        items.append({action: {"_index": index_name, **r,
+                                               "status": 200}})
+                    elif action == "update":
+                        if not isinstance(source, dict) or not isinstance(
+                                source.get("doc"), dict):
+                            raise IllegalArgumentError(
+                                "update action requires a [doc] object")
+                        e = idx.docs.get(doc_id)
+                        if e is None or not e.alive:
+                            raise DocumentMissingError(
+                                f"[{doc_id}]: document missing")
+                        merged = {**e.source, **source["doc"]}
+                        r = idx.index_doc(doc_id, merged)
+                        items.append({action: {"_index": index_name, **r,
+                                               "status": 200}})
+                    else:
                         raise IllegalArgumentError(
-                            "update action requires a [doc] object")
-                    e = idx.docs.get(doc_id)
-                    if e is None or not e.alive:
-                        raise DocumentMissingError(
-                            f"[{doc_id}]: document missing")
-                    merged = {**e.source, **source["doc"]}
-                    r = idx.index_doc(doc_id, merged)
-                    items.append({action: {"_index": index_name, **r,
-                                           "status": 200}})
-                else:
-                    raise IllegalArgumentError(
-                        f"unknown bulk action [{action}]")
-            except Exception as ex:  # per-item error envelope
-                items.append(_item_error(action, index_name, doc_id, ex))
+                            f"unknown bulk action [{action}]")
+                except Exception as ex:  # per-item error envelope
+                    items.append(_item_error(action, index_name, doc_id, ex))
         return {"errors": errors, "items": items}
 
     def close(self):

@@ -57,6 +57,7 @@ __all__ = [
     "device_build_enabled",
     "device_build_min",
     "use_device_build",
+    "use_device_csr_scatter",
     "kmeans_device",
     "csr_blocked_scatter_device",
     "ann_tiles_device",
@@ -89,6 +90,21 @@ def device_build_min() -> int:
 def use_device_build(elements: int) -> bool:
     """The per-stage gate: enabled AND the dispatch is big enough."""
     return device_build_enabled() and elements >= device_build_min()
+
+
+def use_device_csr_scatter(postings: int) -> bool:
+    """The gate of the blocked-CSR scatter alone. Its input is the
+    accumulator's flat CSR, host arrays, its output goes back into the
+    pack's host arrays, and `total_blocks` is a static shape of the
+    program: on a v5e every shard of every refresh compiled it anew for
+    12-13 s, and compiled it ran no faster than the host's scatter (2.16 s
+    against 1.68 s for one 294,912-passage shard, 0.64 s of it on the
+    device; PERF.md, PR 29). So by default the host assembles the blocks;
+    the kernel engages where `ES_TPU_DEVICE_BUILD_MIN` sets a floor by
+    hand (the parity tests, an A/B), and is what a pack that stays on
+    the device would start from."""
+    return ("ES_TPU_DEVICE_BUILD_MIN" in os.environ
+            and use_device_build(postings))
 
 
 # ---------------------------------------------------------------------------
@@ -203,9 +219,19 @@ def _csr_scatter_jit():
     return run
 
 
+def _put(arrays, device):
+    """Host arrays committed to `device`, so that the jitted stage they
+    feed runs there; None leaves them to the default device."""
+    if device is None:
+        return arrays
+    import jax
+
+    return jax.device_put(arrays, device)
+
+
 def csr_blocked_scatter_device(flat_docs, flat_tfs, flat_dls,
                                dest_row, dest_col, total_blocks: int,
-                               block: int, n_sentinel: int):
+                               block: int, n_sentinel: int, device=None):
     """Blocked-postings assembly on device: flat CSR lanes scatter into
     [total_blocks, BLOCK] and block max-tf / min-len derive via
     scatter-max/min (order-independent — exactly the host reduceat).
@@ -225,7 +251,7 @@ def csr_blocked_scatter_device(flat_docs, flat_tfs, flat_dls,
                          np.full(pad, total_blocks, np.int32)])
     dc = np.concatenate([np.asarray(dest_col, np.int32),
                          np.zeros(pad, np.int32)])
-    out = _csr_scatter_jit()(fd, ft, fl, dr, dc,
+    out = _csr_scatter_jit()(*_put((fd, ft, fl, dr, dc), device),
                              int(total_blocks), int(block),
                              int(n_sentinel))
     # np.array (not asarray): writable host copies — callers normalize
@@ -410,14 +436,16 @@ def _impact_codes_jit():
 
 
 def impact_codes_device(tfs, dls, k_base, k_slope, scale_inv, *,
-                        qmax: int, dtype: str):
+                        qmax: int, dtype: str, device=None):
     """Impact-code derivation as one elementwise device pass — the twin
     of index/pack.impact_codes_host (asserted equal by tests). Accepts
     device or host arrays; returns a device array (callers fetching to
-    host wrap in np.asarray)."""
+    host wrap in np.asarray). `device`: where host arrays are put and the
+    pass runs (a build's shard device); None is the default device."""
     import jax.numpy as jnp
 
-    return _impact_codes_jit()(
-        jnp.asarray(tfs), jnp.asarray(dls), jnp.asarray(k_base),
-        jnp.asarray(k_slope), jnp.asarray(scale_inv),
-        qmax=int(qmax), dtype=dtype)
+    args = (tfs, dls, k_base, k_slope, scale_inv)
+    if device is None:
+        args = tuple(jnp.asarray(a) for a in args)
+    return _impact_codes_jit()(*_put(args, device),
+                               qmax=int(qmax), dtype=dtype)

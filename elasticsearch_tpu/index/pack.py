@@ -360,6 +360,15 @@ class ShardPack:
         return terms
 
 
+def _standard_fast_path(analyzer) -> bool:
+    """The C accumulator's ASCII tokenizer is exactly this analyzer: the
+    plain standard one, no stop words, 255-char tokens."""
+    from ..analysis.analyzers import StandardAnalyzer
+
+    return (type(analyzer) is StandardAnalyzer and not analyzer.stopwords
+            and analyzer.max_token_length == 255)
+
+
 class PackBuilder:
     """Accumulates parsed documents for one shard, then packs.
 
@@ -531,14 +540,8 @@ class PackBuilder:
         """Text-field token routing into the C++ accumulator. The ASCII fast
         path requires exact standard-analyzer semantics; anything else is
         Python-analyzed and fed as pre-tokenized terms."""
-        from ..analysis.analyzers import StandardAnalyzer
-
         nat = self._native
-        fast = (
-            type(analyzer) is StandardAnalyzer
-            and not analyzer.stopwords
-            and analyzer.max_token_length == 255
-        )
+        fast = _standard_fast_path(analyzer)
         length = 0
         pos_base = 0
         for v in values:
@@ -605,23 +608,16 @@ class PackBuilder:
 
     def _native_burst_eligible(self, ba, vals: list[str], mode: str) -> bool:
         """auto + C accumulator + plain standard analyzer: the C
-        tokenizer (builder_add_text) is the measured-fastest host
-        analyze+insert route at every burst size (BENCH_NOTES round 20)
-        and is byte-compatible with the oracle by the per-doc path's own
-        contract, so auto prefers it — unless the device kernel claims
-        the burst (accelerator backend, burst past ES_TPU_ANALYZE_MIN).
-        Forced modes (host/batched/device) never take this route: their
-        dispatch is the thing the parity tests pin down."""
-        if mode != "auto" or self._native is None or not ba.device_eligible:
-            return False
-        import jax
-
-        from ..analysis.batched import analyze_device_min
-        from . import device_build as db
-
-        return not (jax.default_backend() != "cpu"
-                    and db.device_build_enabled()
-                    and sum(map(len, vals)) >= analyze_device_min())
+        tokenizer (builder_add_text) is the measured-fastest
+        analyze+insert route at every burst size, on the CPU (BENCH_NOTES
+        round 20) and on a v5e's host (PERF.md, PR 29: 22-43 us a document
+        against the device hash kernel's 90, and 57 against 191 with four
+        shards' builders at once), and is byte-compatible with the oracle
+        by the per-doc path's own contract, so auto takes it on every
+        backend. Forced modes (host/batched/device) never take this
+        route: their dispatch is the thing the parity tests pin down."""
+        return (mode == "auto" and self._native is not None
+                and ba.device_eligible)
 
     def _ingest_text_burst_native(self, fld: str, fdocs: list[int],
                                   vals: list[str], vdoc: list[int],
@@ -636,6 +632,8 @@ class PackBuilder:
 
         with build_stage("build.analyze", nbytes=sum(map(len, vals)),
                          values=len(vals), docs=len(fdocs)):
+            if self._add_texts_native(fld, fdocs, vals, vdoc, ba.analyzer):
+                return
             i = 0
             n = len(vdoc)
             for d_ord, docid in enumerate(fdocs):
@@ -644,6 +642,27 @@ class PackBuilder:
                     j += 1
                 self._add_text_native(fld, docid, ba.analyzer, vals[i:j])
                 i = j
+
+    def _add_texts_native(self, fld: str, fdocs: list[int], vals: list[str],
+                          vdoc: list[int], analyzer) -> bool:
+        """The burst in ONE call of the C accumulator where every value is
+        ASCII and the analyzer is the plain standard one: the state N
+        _add_text_native calls leave, without the interpreter lock taken
+        and given back once a document (four shards' builders at once
+        otherwise spend their time handing it to each other). False, and
+        nothing added, where the burst must go document by document."""
+        if not _standard_fast_path(analyzer) or not vals:
+            return False
+        docid_of = np.asarray(fdocs, np.int32)
+        v_ord = np.asarray(vdoc, np.int64)
+        counts = self._native.add_texts(fld, docid_of[v_ord], vals)
+        if counts is None:
+            return False
+        lengths = np.bincount(v_ord, weights=counts,
+                              minlength=len(fdocs)).astype(np.int64)
+        self.doc_field_lengths.setdefault(fld, []).extend(
+            zip(fdocs, lengths.tolist()))
+        return True
 
     def _ingest_text_burst(self, fld: str, docids: list[int], burst) -> None:
         """Route one analyzed burst into the accumulator — the batch
@@ -736,7 +755,11 @@ class PackBuilder:
                     s += 1
         return keys, post_offsets, flat_docs, flat_tfs, pos_offsets, flat_pos
 
-    def build(self, dense_min_df: int | None = None) -> ShardPack:
+    def build(self, dense_min_df: int | None = None,
+              device=None) -> ShardPack:
+        """`device`: where the device build stages (the blocked-CSR scatter,
+        the impact quantization) run: the device that will hold this shard
+        (parallel/stacked.py); None is the default device."""
         from ..monitoring.refresh_profile import build_stage, refresh_stage
 
         N = self.num_docs
@@ -788,15 +811,17 @@ class PackBuilder:
         # handled at query time by norm fallback.
 
         # ---- blocked postings (segment scatter from flat CSR) ------------
-        # PR 15: above the device-build floor the scatter + block-stat
-        # derivation runs as one jitted segment-scatter kernel
+        # PR 15: the scatter + block-stat derivation also exists as one
+        # jitted segment-scatter kernel
         # (index/device_build.csr_blocked_scatter_device) — byte parity
-        # with the host path asserted by tests/test_device_build.py
+        # with the host path asserted by tests/test_device_build.py. Since
+        # PR 29 the host's scatter is the default (use_device_csr_scatter
+        # says why)
         from .device_build import (csr_blocked_scatter_device,
-                                   use_device_build)
+                                   use_device_build, use_device_csr_scatter)
 
         NP = len(flat_docs) if T else 0
-        csr_dev = use_device_build(NP)
+        csr_dev = use_device_csr_scatter(NP)
         with build_stage("build.csr_assemble", postings=NP, num_docs=N,
                          terms=T, basis="device" if csr_dev else "host"):
             df = post_offsets[1:] - post_offsets[:-1]
@@ -834,7 +859,7 @@ class PackBuilder:
                 (post_docids, post_tfs, post_dls, block_max_tf,
                  block_min_len) = csr_blocked_scatter_device(
                     flat_docs, flat_tfs, post_dl_flat, dest_row,
-                    dest_col, total_blocks, BLOCK, N)
+                    dest_col, total_blocks, BLOCK, N, device=device)
             else:
                 post_docids = np.full((total_blocks, BLOCK), N,
                                       dtype=np.int32)
@@ -1002,7 +1027,7 @@ class PackBuilder:
 
                     impact_codes = np.array(impact_codes_device(
                         post_tfs, post_dls, k_base, k_slope, scale_inv,
-                        qmax=qmax, dtype=dtype))
+                        qmax=qmax, dtype=dtype, device=device))
                 else:
                     impact_codes = impact_codes_host(
                         post_tfs, post_dls, k_base, k_slope, scale_inv,

@@ -62,16 +62,16 @@ class StageCollector:
         self._last = self._t0
         self._stack: list[str] = [OTHER_STAGE]
         self.stages: dict[str, float] = {}  # name -> seconds
-        # (name, start_s, end_s) spans relative to t0 — the overlap
-        # evidence: main-thread stage windows (events) plus worker
-        # spans (async_events, via note_span), so analyze(k) ∥
-        # build(k−1) is visible in the RefreshProfile timestamps, not
-        # just inferable from sums
+        # (name, start_s, end_s) spans relative to t0: main-thread stage
+        # windows (events) and the spans of work done on other threads
+        # (async_events: a shard's build, parallel/stacked.py), so that
+        # shards built side by side are visible in the RefreshProfile
+        # timestamps, not just inferable from sums
         self.events: list[tuple[str, float, float]] = []
         self.async_events: list[tuple[str, float, float]] = []
-        # seconds of concurrent work per stage name, charged via
-        # note_span by worker threads — kept OUT of `stages` so the
-        # flat-sum invariant (sum(stages) == wall) stays per-thread
+        # seconds of work per stage name done on other threads — kept OUT
+        # of `stages` so the flat-sum invariant (sum(stages) == wall)
+        # stays one thread's
         self.async_stages: dict[str, float] = {}
         self._elock = threading.Lock()
 
@@ -95,15 +95,19 @@ class StageCollector:
                 self.events.append(
                     (name, t_en - self._t0, self._last - self._t0))
 
-    def note_span(self, name: str, t_start: float, t_end: float) -> None:
-        """Record work done on ANOTHER thread (perf_counter timestamps):
-        an event span for the overlap timeline plus an async stage
-        charge. Thread-safe; never touches the flat-sum clock."""
+    def note_worker(self, name: str, t_start: float, t_end: float,
+                    stages: dict[str, float]) -> None:
+        """Record work done on ANOTHER thread: the span `name` between two
+        perf_counter readings for the timeline, and `stages`, that
+        thread's own flat-sum stage seconds (they add up to the span), as
+        async stage charges. Thread-safe; never touches the flat-sum
+        clock."""
         with self._elock:
             self.async_events.append(
                 (name, t_start - self._t0, t_end - self._t0))
-            self.async_stages[name] = (self.async_stages.get(name, 0.0)
-                                       + (t_end - t_start))
+            for stage, seconds in stages.items():
+                self.async_stages[stage] = (
+                    self.async_stages.get(stage, 0.0) + seconds)
 
     def finish(self) -> tuple[float, dict[str, float]]:
         """-> (wall_seconds, {stage: seconds}). wall is the last boundary
@@ -118,9 +122,9 @@ _collector: contextvars.ContextVar[StageCollector | None] = (
 
 def active_collector() -> StageCollector | None:
     """The collector of the refresh being profiled on THIS thread, if
-    any — captured by the stacked build before spawning analyze
-    workers, whose fresh thread contexts see None and report back via
-    note_span."""
+    any. The stacked build's shard workers start in fresh contexts, run
+    under a collector of their own and are reported to this one through
+    note_worker."""
     return _collector.get()
 
 
@@ -217,13 +221,18 @@ class RefreshRecorder:
             self._ring.append(profile)
             kind = profile.get("kind", "full")
             self._counts[kind] = self._counts.get(kind, 0) + 1
-            for stage, ms in (profile.get("stages_ms") or {}).items():
-                self._stage_ms[stage] = self._stage_ms.get(stage, 0.0) + ms
-            # worker-thread stage time (analyze/build overlap) counts in
-            # the cumulative accounting — the SLO analyze fraction and
+            # worker-thread stage time (shards built side by side) counts
+            # in the cumulative accounting: the SLO analyze fraction and
             # the health dominant-stage diagnosis must see every
-            # millisecond, overlapped or not
-            for stage, ms in (profile.get("async_stages_ms") or {}).items():
+            # millisecond, overlapped or not. The main thread's `build`
+            # stage is then the wait for those workers, whose own stages
+            # are what it was spent on: counted once, by them
+            workers_ms = profile.get("async_stages_ms") or {}
+            for stage, ms in (profile.get("stages_ms") or {}).items():
+                if stage == "build" and workers_ms:
+                    continue
+                self._stage_ms[stage] = self._stage_ms.get(stage, 0.0) + ms
+            for stage, ms in workers_ms.items():
                 self._stage_ms[stage] = self._stage_ms.get(stage, 0.0) + ms
             docs = int(profile.get("docs", 0))
             self._docs_total += docs
@@ -348,7 +357,7 @@ def profile_refresh(index, kind: str):
             + [[name, round(s * 1000, 3), round(e * 1000, 3), "worker"]
                for name, s, e in async_events])
         if async_stages:
-            # worker-thread time (analyze overlap pipeline): outside the
+            # worker-thread time (the shards' builders): outside the
             # flat-sum stages by construction, folded into the
             # recorder's cumulative stage accounting by record()
             profile["async_stages_ms"] = {

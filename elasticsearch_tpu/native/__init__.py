@@ -62,6 +62,16 @@ def _build_lib() -> ctypes.CDLL | None:
         ctypes.c_void_p, ctypes.c_uint32, ctypes.c_int32,
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
     ]
+    lib.route_ascii_ids.restype = None
+    lib.route_ascii_ids.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_void_p,
+    ]
+    lib.builder_add_texts.restype = ctypes.c_int64
+    lib.builder_add_texts.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint32, ctypes.c_void_p, ctypes.c_char_p,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int, ctypes.c_void_p,
+    ]
     lib.builder_add_tokens.argtypes = [
         ctypes.c_void_p, ctypes.c_uint32, ctypes.c_int32,
         ctypes.c_char_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,

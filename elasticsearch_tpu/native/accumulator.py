@@ -46,6 +46,28 @@ class NativeAccumulator:
             self.h, self._fid(fld), docid, raw, len(raw), pos_base, 1
         )
 
+    def add_texts(self, fld: str, docids: np.ndarray,
+                  texts: list[str]) -> np.ndarray | None:
+        """A whole burst through the ASCII fast path in one call, which
+        holds no interpreter lock while it runs: `texts[k]` is a value of
+        document `docids[k]` (int32; one document's values adjacent, their
+        positions chained with the +100 gap as add_text's caller chains
+        them). -> token counts a value, or None with nothing added where a
+        value is not ASCII (the caller goes value by value)."""
+        joined = "".join(texts)
+        if not joined.isascii():
+            return None
+        off = np.zeros(len(texts) + 1, np.int64)
+        np.cumsum(np.fromiter(map(len, texts), np.int64, count=len(texts)),
+                  out=off[1:])
+        counts = np.zeros(len(texts), np.int64)
+        docids = np.ascontiguousarray(docids, np.int32)
+        rc = self.lib.builder_add_texts(
+            self.h, self._fid(fld), docids.ctypes.data_as(ctypes.c_void_p),
+            joined.encode("ascii"), off.ctypes.data_as(ctypes.c_void_p),
+            len(texts), 1, counts.ctypes.data_as(ctypes.c_void_p))
+        return None if rc < 0 else counts
+
     def add_tokens(
         self, fld: str, docid: int, terms: list[str], positions: list[int] | None
     ):

@@ -130,6 +130,69 @@ int64_t builder_add_text(void* h, uint32_t field_id, int32_t docid,
     return pos;
 }
 
+// Document -> shard for n ASCII ids (cluster/routing.py shard_for_id, one
+// call for all of them): MurmurHash3 x86 32-bit, seed 0, over the id's
+// UTF-16 code units little-endian, as the reference's Murmur3HashFunction
+// hashes a String (an ASCII char c is the two bytes c, 0), then
+// floorMod(hash, routing_num_shards) / routing_factor. id k is
+// ids[off[k], off[k+1]).
+void route_ascii_ids(const char* ids, const int64_t* off, int64_t n,
+                     int32_t routing_num_shards, int32_t routing_factor,
+                     int32_t* out) {
+    const uint32_t c1 = 0xCC9E2D51u, c2 = 0x1B873593u;
+    auto rotl = [](uint32_t x, int r) { return (x << r) | (x >> (32 - r)); };
+    for (int64_t d = 0; d < n; d++) {
+        const unsigned char* s = (const unsigned char*)ids + off[d];
+        const int64_t chars = off[d + 1] - off[d];
+        uint32_t h = 0;
+        int64_t i = 0;
+        for (; i + 1 < chars; i += 2) {          // four bytes: two chars
+            uint32_t k = (uint32_t)s[i] | ((uint32_t)s[i + 1] << 16);
+            k *= c1; k = rotl(k, 15); k *= c2;
+            h ^= k; h = rotl(h, 13); h = h * 5 + 0xE6546B64u;
+        }
+        if (i < chars) {                         // a tail of two bytes: c, 0
+            uint32_t k = (uint32_t)s[i];
+            k *= c1; k = rotl(k, 15); k *= c2;
+            h ^= k;
+        }
+        h ^= (uint32_t)(2 * chars);
+        h ^= h >> 16; h *= 0x85EBCA6Bu; h ^= h >> 13; h *= 0xC2B2AE35u;
+        h ^= h >> 16;
+        int64_t m = (int64_t)(int32_t)h % routing_num_shards;
+        if (m < 0) m += routing_num_shards;
+        out[d] = (int32_t)(m / routing_factor);
+    }
+}
+
+// A whole burst through builder_add_text in ONE call, so that the caller
+// (one Python thread a shard, ctypes releasing the interpreter lock for the
+// call) holds that lock once a burst and not once a value: n values, value
+// k being text[off[k], off[k+1]) of document docids[k], a document's
+// values adjacent. Positions chain across one document's values with the
+// +100 gap, exactly as PackBuilder._add_text_native chains them value by
+// value; counts[k] receives value k's token count. All or nothing: -1, and
+// nothing added, if any byte is not ASCII (the caller then goes value by
+// value); else 0.
+int64_t builder_add_texts(void* h, uint32_t field_id, const int32_t* docids,
+                          const char* text, const int64_t* off, int64_t n,
+                          int record_positions, int64_t* counts) {
+    const int64_t total = n ? off[n] : 0;
+    for (int64_t i = 0; i < total; i++) {
+        if ((unsigned char)text[i] >= 0x80) return -1;
+    }
+    int64_t pos_base = 0;
+    for (int64_t k = 0; k < n; k++) {
+        if (k == 0 || docids[k] != docids[k - 1]) pos_base = 0;
+        int64_t ret = builder_add_text(h, field_id, docids[k], text + off[k],
+                                       off[k + 1] - off[k], pos_base,
+                                       record_positions);
+        counts[k] = ret;
+        pos_base += ret + 100;
+    }
+    return 0;
+}
+
 // Pre-tokenized path (Python analyzer fallback / keyword terms).
 // terms = concatenated UTF-8 bytes; lens[i] each term's length;
 // positions[i] absolute position or -1 (skip position key); tf_inc added

@@ -25,7 +25,7 @@ array S-fold in HBM.
 
 from __future__ import annotations
 
-import threading
+import contextlib
 import time
 from dataclasses import dataclass
 
@@ -248,17 +248,32 @@ class StackedPack:
             self.global_docvalues[fld] = g
 
         # ---- stacked postings & norms ------------------------------------
-        self.post_docids = np.full((self.S, self.nb_max, BLOCK), self.n_max, np.int32)
-        self.post_tfs = np.zeros((self.S, self.nb_max, BLOCK), np.float32)
-        self.post_dls = np.ones((self.S, self.nb_max, BLOCK), np.float32)
+        # each [S, nb_max, BLOCK] array is written once: a shard's blocks are
+        # copied into its slice and only the rows past them are filled, the
+        # shards side by side (the copies release the GIL). A shard's blocks
+        # are dictionary-sized at least (every term pays a whole block), so
+        # a fill of the whole array first would be paid once a shard again
+        self.post_docids = np.empty((self.S, self.nb_max, BLOCK), np.int32)
+        self.post_tfs = np.empty((self.S, self.nb_max, BLOCK), np.float32)
+        self.post_dls = np.empty((self.S, self.nb_max, BLOCK), np.float32)
         self.live = np.zeros((self.S, self.n_max), bool)
-        for i, p in enumerate(shards):
-            d = p.post_docids.copy()
-            d[d == p.num_docs] = self.n_max  # re-sentinel padding to n_max
-            self.post_docids[i, : p.num_blocks] = d
-            self.post_tfs[i, : p.num_blocks] = p.post_tfs
-            self.post_dls[i, : p.num_blocks] = p.post_dls
+
+        def _stack_postings(i: int) -> None:
+            p = shards[i]
+            nb = p.num_blocks
+            d = self.post_docids[i]
+            d[:nb] = p.post_docids
+            if p.num_docs != self.n_max:
+                # re-sentinel padding to n_max
+                np.putmask(d[:nb], d[:nb] == p.num_docs, self.n_max)
+            d[nb:] = self.n_max
+            self.post_tfs[i, :nb] = p.post_tfs
+            self.post_tfs[i, nb:] = 0.0
+            self.post_dls[i, :nb] = p.post_dls
+            self.post_dls[i, nb:] = 1.0
             self.live[i, : p.num_docs] = p.live
+
+        self._each_shard(_stack_postings)
         # ---- impact tier planning state (BM25S) --------------------------
         # Per-shard row->term/field maps + the static per-row code scale
         # (avgdl-INDEPENDENT: ubf bounds tfn over any doc length, see
@@ -408,10 +423,11 @@ class StackedPack:
         self.dense_tf = None
         if dense_keys:
             self.dense_tf = np.zeros((self.S, len(dense_keys), self.n_max), np.float32)
-            for i, k in enumerate(dense_keys):
-                fld = k[0]
-                for s, p in enumerate(shards):
-                    s0, nb, _df = p.term_blocks(fld, k[1])
+
+            def _stack_dense(s: int) -> None:
+                p = shards[s]
+                for i, k in enumerate(dense_keys):
+                    s0, nb, _df = p.term_blocks(k[0], k[1])
                     if nb == 0:
                         continue
                     docs = p.post_docids[s0 : s0 + nb].ravel()
@@ -419,6 +435,22 @@ class StackedPack:
                     docs = docs[valid]
                     tfs = p.post_tfs[s0 : s0 + nb].ravel()[valid]
                     self.dense_tf[s, i, docs] = tfs
+
+            # a shard's rows are its own slab of the array: side by side
+            self._each_shard(_stack_dense)
+
+    def _each_shard(self, fn) -> None:
+        """`fn(s)` for every shard, the shards side by side where there are
+        several: what it does is NumPy copies into the shard's own slice of
+        an [S, ...] array, which release the GIL."""
+        if self.S <= 1:
+            for s in range(self.S):
+                fn(s)
+            return
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(default_shard_builders(self.S)) as ex:
+            list(ex.map(fn, range(self.S)))
 
     def dense_tfn_host(self, row: int, shard: int, avgdl: float,
                        k1: float | None = None, b: float | None = None) -> np.ndarray:
@@ -479,9 +511,11 @@ class StackedPack:
         seen: set[int] = set()
         total = 0
 
+        scalars = (str, int, float, bool, type(None))
+
         def walk(obj):
             nonlocal total
-            if isinstance(obj, (str, int, float, bool, type(None))):
+            if isinstance(obj, scalars):
                 return
             if id(obj) in seen:
                 return
@@ -489,14 +523,21 @@ class StackedPack:
             if isinstance(obj, np.ndarray):
                 total += obj.nbytes
             elif isinstance(obj, dict):
+                # dictionary-sized maps of scalars (term -> id, term -> df):
+                # no call a value
                 for v in obj.values():
-                    walk(v)
+                    if type(v) not in scalars:
+                        walk(v)
             elif isinstance(obj, (list, tuple)):
                 for v in obj:
-                    walk(v)
+                    if type(v) not in scalars:
+                        walk(v)
             elif hasattr(obj, "__dict__"):
-                for v in vars(obj).values():
-                    walk(v)
+                for k, v in vars(obj).items():
+                    # the documents' own sources (JSON values, one dict a
+                    # document) hold no array: not walked
+                    if k != "doc_sources":
+                        walk(v)
 
         walk({k: v for k, v in vars(self).items() if k != "mappings"})
         if self.impact_meta is not None:
@@ -526,9 +567,40 @@ def route_docs(
     source of truth for doc->shard placement; pack building and hit-id
     resolution both consume this."""
     routed: list[list[tuple[str, dict]]] = [[] for _ in range(num_shards)]
+    shards = _shards_for_ids([doc_id for doc_id, _ in docs], num_shards)
+    if shards is not None:
+        for s, doc in zip(shards, docs):
+            routed[s].append(doc)
+        return routed
     for doc_id, source in docs:
         routed[shard_for_id(doc_id, num_shards)].append((doc_id, source))
     return routed
+
+
+def _shards_for_ids(ids: list[str], num_shards: int) -> list[int] | None:
+    """`shard_for_id` of every id in one call of the native library (a
+    refresh routes every live document: 6 us a document in Python, 7 s of
+    a 1,179,648-document refresh); None where there is no library or an
+    id is not ASCII, and the caller goes id by id."""
+    from ..cluster.routing import default_routing_num_shards
+    from ..native import get_lib
+
+    lib = get_lib()
+    joined = "".join(ids)
+    if lib is None or not ids or not joined.isascii():
+        return None
+    import ctypes
+
+    off = np.zeros(len(ids) + 1, np.int64)
+    np.cumsum(np.fromiter(map(len, ids), np.int64, count=len(ids)),
+              out=off[1:])
+    out = np.empty(len(ids), np.int32)
+    routing_num_shards = default_routing_num_shards(num_shards)
+    lib.route_ascii_ids(
+        joined.encode("ascii"), off.ctypes.data_as(ctypes.c_void_p),
+        len(ids), routing_num_shards, routing_num_shards // num_shards,
+        out.ctypes.data_as(ctypes.c_void_p))
+    return out.tolist()
 
 
 def _ingest_shard(builder: PackBuilder,
@@ -542,74 +614,96 @@ def _ingest_shard(builder: PackBuilder,
         parsed, doc_ids=[doc_id for doc_id, _ in shard_docs])
 
 
+def default_shard_builders(num_shards: int) -> int:
+    """Shards a refresh builds at once where `indexing.refresh.shard_builders`
+    is not set: one builder a shard, as far as the host has cores."""
+    import os
+
+    return max(1, min(num_shards, os.cpu_count() or 1))
+
+
 def build_stacked_pack_routed(
     routed: list[list[tuple[str, dict]]], mappings: Mappings,
-    dense_min_df: int | None = None,
+    dense_min_df: int | None = None, shard_builders: int | None = None,
+    devices: list | None = None,
 ) -> StackedPack:
-    from ..analysis.batched import analyze_mode, analyze_overlap_enabled
-    from ..monitoring.refresh_profile import active_collector, refresh_stage
+    """One pack a shard (analysis + `PackBuilder.build`), `shard_builders` of
+    them at once, then the stack. The native accumulator, NumPy and XLA
+    release the GIL, so shards built side by side are real wall-clock
+    overlap; `shard_builders` bounds the host memory a refresh holds (that
+    many shards' accumulators and flat postings at once). `devices[s]` is the
+    device that will hold shard `s` (the mesh's `shards` axis): the shard's
+    device build stages run there, so four scatters run on four chips and
+    not all through the default device. The pack is the serial build's,
+    array for array: a shard's build reads nothing of another's.
 
-    builders = [PackBuilder(mappings) for _ in range(len(routed))]
-    # analyze stays a named collector stage (the batch dispatch nested
-    # inside charges build.analyze; parse + residual stay in `analyze`)
-    overlap = (len(builders) > 1 and analyze_overlap_enabled()
-               and analyze_mode() != "host")
-    packs: list = []
-    if not overlap:
-        with refresh_stage("analyze"):
-            for b, shard_docs in zip(builders, routed):
-                _ingest_shard(b, shard_docs, mappings)
-        # per-shard dense tiers disabled: StackedPack builds its own
-        # global one (global df decisions + global avgdl), so a local
-        # tier would only burn build time and host RAM
-        packs = [b.build(dense_min_df=1 << 62) for b in builders]
-    else:
-        # depth-1 double buffer (the C3/serving pattern applied to
-        # ingest): a worker thread analyzes shard k+1 while the main
-        # thread builds shard k — the builds release the GIL in the
-        # native accumulator / XLA, so analyze(k+1) ∥ build(k) is real
-        # wall-clock overlap. Worker time can't charge the flat-sum
-        # collector (sum(stages) == wall is per-thread by construction);
-        # it lands as an async span (note_span) so the RefreshProfile
-        # timestamps show the overlap and the cumulative stage
-        # accounting still sees every analyze millisecond.
-        coll = active_collector()
+    Every shard's build is a span `refresh.shard_build` (attributes `shard`,
+    `device`) and, in a profiled refresh, an async span of that name in
+    `_refresh/profile`; the counters `es.refresh.shard_build.ns` (the sum of
+    the shards' own time) over `es.refresh.build_wall.ns` (the wall time of
+    the `build` stage that held them) say how many were built at once."""
+    from concurrent.futures import ThreadPoolExecutor
 
-        def _spawn(s: int):
-            box: list[BaseException] = []
+    from ..monitoring.refresh_profile import (
+        active_collector, collect_build_stages, refresh_stage)
+    from ..telemetry import TRACER, metrics
 
-            def _run():
-                t0 = time.perf_counter()
-                try:
+    S = len(routed)
+    builders = [PackBuilder(mappings) for _ in range(S)]
+    at_once = max(1, min(int(shard_builders or default_shard_builders(S)), S))
+
+    def _build_shard(s: int, on_worker: bool):
+        """-> (pack, start ns, end ns, the worker's own stage seconds).
+        A worker thread starts in a fresh context: it runs under a stage
+        collector of its own (a flat-sum clock is one thread's) and hands
+        its stages back; on the calling thread the stages inside (analyze,
+        flat_csr, build.*) charge the refresh's own collector."""
+        t0 = time.perf_counter_ns()
+        with (collect_build_stages() if on_worker
+              else contextlib.nullcontext()) as wc:
+            with refresh_stage("refresh.shard_build"):
+                # analyze stays a named stage: the batch dispatch nested
+                # inside charges build.analyze, parse + residual stay in
+                # `analyze`
+                with refresh_stage("analyze"):
                     _ingest_shard(builders[s], routed[s], mappings)
-                except BaseException as ex:  # noqa: BLE001 - rethrown on join
-                    box.append(ex)
-                finally:
-                    if coll is not None:
-                        coll.note_span("build.analyze", t0,
-                                       time.perf_counter())
+                # per-shard dense tiers disabled: StackedPack builds its
+                # own global one (global df decisions + global avgdl), so a
+                # local tier would only burn build time and host RAM
+                pack = builders[s].build(
+                    dense_min_df=1 << 62,
+                    device=devices[s] if devices is not None else None)
+        builders[s] = None  # the accumulator's memory goes with its shard
+        return (pack, t0, time.perf_counter_ns(),
+                wc.finish()[1] if on_worker else None)
 
-            th = threading.Thread(target=_run, daemon=True,
-                                  name=f"analyze-shard-{s}")
-            th.start()
-            return th, box
-
-        with refresh_stage("analyze"):
-            _ingest_shard(builders[0], routed[0], mappings)
-        pending = None
-        try:
-            for s in range(len(builders)):
-                pending = _spawn(s + 1) if s + 1 < len(builders) else None
-                packs.append(builders[s].build(dense_min_df=1 << 62))
-                if pending is not None:
-                    th, box = pending
-                    th.join()
-                    pending = None
-                    if box:
-                        raise box[0]
-        finally:
-            if pending is not None:
-                pending[0].join()
+    t_wall = time.perf_counter_ns()
+    with refresh_stage("build"):
+        if at_once == 1:
+            built = [_build_shard(s, False) for s in range(S)]
+        else:
+            with ThreadPoolExecutor(at_once,
+                                    thread_name_prefix="shard-build") as ex:
+                futures = [ex.submit(_build_shard, s, True)
+                           for s in range(S)]
+                try:
+                    built = [f.result() for f in futures]
+                except BaseException:
+                    for f in futures:
+                        f.cancel()
+                    raise
+    wall_ns = time.perf_counter_ns() - t_wall
+    coll = active_collector()
+    for s, (_pack, t0, t1, stages) in enumerate(built):
+        device = str(devices[s]) if devices is not None else "default"
+        TRACER.record("refresh.shard_build", t0, t1, shard=s, device=device)
+        if coll is not None and stages is not None:
+            coll.note_worker("refresh.shard_build", t0 * 1e-9, t1 * 1e-9,
+                             stages)
+    metrics.counter_inc("es.refresh.shard_build.ns",
+                        sum(b[2] - b[1] for b in built))
+    metrics.counter_inc("es.refresh.build_wall.ns", wall_ns)
+    packs = [b[0] for b in built]
     for p, shard_docs in zip(packs, routed):
         # source references (shared with EsIndex.shard_docs) for host-side
         # per-object matching (nested queries, query/nested.py)

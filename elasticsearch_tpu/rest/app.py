@@ -1770,9 +1770,9 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
     @handler
     async def bulk(request):
         default_index = request.match_info.get("index")
-        raw = (await request.read()).decode("utf-8")
+        body = await request.read()
         ops = []
-        lines = [ln for ln in raw.split("\n")]
+        lines = body.decode("utf-8").split("\n")
         i = 0
         while i < len(lines):
             line = lines[i].strip()
@@ -1813,7 +1813,7 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
             engine.metering.note_ingest(
                 normalize_tenant(
                     getattr(current_trace(), "task_id", None)),
-                len(raw.encode("utf-8")), docs=len(ops))
+                len(body), docs=len(ops))
         except Exception:  # noqa: BLE001 - metering must not fail a bulk
             pass
         if request.query.get("refresh") in ("", "true", "wait_for"):

@@ -302,7 +302,8 @@ def test_batched_memo_invalidates_with_analysis_generation():
 
 
 # ---------------------------------------------------------------------------
-# the analyze/build overlap pipeline
+# shards built at once (the concurrent build that replaced the depth-1
+# analyze/build double buffer)
 # ---------------------------------------------------------------------------
 
 def test_overlap_pipeline_same_packs_and_worker_spans(monkeypatch):
@@ -311,24 +312,34 @@ def test_overlap_pipeline_same_packs_and_worker_spans(monkeypatch):
             for i in range(150)]
     m = Mappings({"properties": {"body": {"type": "text"}}})
     with collect_build_stages() as c:
-        sp = build_stacked_pack_routed(route_docs(docs, 3), m)
+        sp = build_stacked_pack_routed(route_docs(docs, 3), m,
+                                       shard_builders=3)
     assert sp.S == 3
     assert sum(p.num_docs for p in sp.shards) == len(docs)
-    # shards 1..2 analyzed on worker threads: async spans recorded, and
-    # the main-thread flat-sum invariant untouched (workers never write
-    # `stages`)
+    # every shard analyzed and built on a worker thread: one span a shard,
+    # the workers' own stages as async charges, and the main-thread
+    # flat-sum invariant untouched (workers never write `stages`)
     assert c.async_stages.get("build.analyze", 0.0) > 0.0
-    assert len(c.async_events) == 2
+    assert [n for n, _s, _e in c.async_events] == ["refresh.shard_build"] * 3
     assert all(e >= s for _n, s, e in c.async_events)
-    # the serial build (overlap off) produces the same global stats
-    monkeypatch.setenv("ES_TPU_ANALYZE_OVERLAP", "0")
-    sp2 = build_stacked_pack_routed(route_docs(docs, 3), m)
+    assert "build.analyze" not in c.stages and c.stages["build"] > 0.0
+    wall, stages = c.finish()
+    assert sum(stages.values()) == pytest.approx(wall)
+    # the serial build (one builder) produces the same packs, on the
+    # calling thread: its stages charge the collector itself
+    with collect_build_stages() as c1:
+        sp2 = build_stacked_pack_routed(route_docs(docs, 3), m,
+                                        shard_builders=1)
+    assert not c1.async_events and c1.stages["build.analyze"] > 0.0
     assert [p.num_docs for p in sp.shards] == [p.num_docs
                                                for p in sp2.shards]
     assert sp.field_stats == sp2.field_stats
+    assert np.array_equal(sp.post_docids, sp2.post_docids)
+    assert np.array_equal(sp.post_tfs, sp2.post_tfs)
 
 
-def test_overlap_worker_exception_propagates(monkeypatch):
+@pytest.mark.parametrize("shard_builders", [3, 1])
+def test_overlap_worker_exception_propagates(monkeypatch, shard_builders):
     monkeypatch.setenv("ES_TPU_ANALYZE", "batched")
     docs = [(str(i), {"body": f"w{i}"}) for i in range(40)]
     m = Mappings({"properties": {"body": {"type": "text"}}})
@@ -339,13 +350,14 @@ def test_overlap_worker_exception_propagates(monkeypatch):
 
     def bad(self, parsed_docs, doc_ids=None):
         calls["n"] += 1
-        if calls["n"] == 2:  # the first worker-analyzed shard
+        if calls["n"] == 2:  # one shard's builder, whichever thread has it
             raise boom
         return orig(self, parsed_docs, doc_ids=doc_ids)
 
     monkeypatch.setattr(PackBuilder, "add_documents_batch", bad)
     with pytest.raises(RuntimeError, match="analyze worker exploded"):
-        build_stacked_pack_routed(route_docs(docs, 3), m)
+        build_stacked_pack_routed(route_docs(docs, 3), m,
+                                  shard_builders=shard_builders)
 
 
 def test_engine_refresh_shows_overlap_in_profile(monkeypatch):

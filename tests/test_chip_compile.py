@@ -193,6 +193,80 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
     assert "s64" not in text and "f64" not in text
 
 
+def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
+        mesh4, no_persistent_cache):
+    """PR 29: the program of `passage-4chip.solo.c8`, one `match` over four
+    shards of 294,912 documents, one a chip: the packed `int32[4, W]`
+    parameters, the impact tier's gather and the streamed Pallas top-k
+    inside `manual_shard_region` (294,912 lanes a shard is over the 1 << 18
+    at which `_fused_scan_engages`), then the replication constraint that is
+    the all-gather, and the global top-k: `_compiled`'s shape, built from
+    the same pieces."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticsearch_tpu.index.pack import BLOCK
+    from elasticsearch_tpu.ops.kernels import scan_topk
+    from elasticsearch_tpu.ops.scoring import (dense_term_scores,
+                                               impact_term_scores)
+    from elasticsearch_tpu.parallel.param_pack import pack, unpack
+    from elasticsearch_tpu.parallel.spmd import (constrain, constrain_shards,
+                                                 manual_shard_region)
+
+    S, n = 4, 294_912
+    assert n >= 1 << 18
+    nb = N_BLOCKS // 3          # ~1/3 of the 1M-doc pack's blocks a shard
+    f32, i32 = np.float32, np.int32
+    dense = (np.zeros((S,), i32), np.ones((S,), f32), np.ones((S,), f32))
+    sparse = (np.zeros((S, 64), i32), np.ones((S,), f32), np.ones((S,), f32),
+              np.ones((S,), f32))
+    buffers, layout = pack(((dense, sparse), np.ones((S,), f32)))
+    assert [(b.shape, b.dtype) for b in buffers] == [((S, 71), np.dtype(i32))]
+
+    def shard_body(dev1, params):
+        ((dr, weight, _), (rows, _, _, wscale)), boost = params
+        with jax.named_scope("score"):
+            s1, m1 = dense_term_scores(dev1["dense_tfn"][dr], weight, n)
+            s2, m2 = impact_term_scores(dev1["codes"], dev1["docids"], rows,
+                                        wscale, n)
+            scores, match = boost * (s1 + s2), m1 | m2
+        with jax.named_scope("topk"):
+            # top_k_with_total's Pallas arm, which asks the backend and so
+            # cannot be steered from here
+            v, i, t = scan_topk(None, scores[None, :n],
+                                match[:n] & dev1["live"], TOP_K,
+                                count_positive=False, interpret=False)
+            return v[0], i[0], t[0]
+
+    region = manual_shard_region(shard_body, mesh4,
+                                 in_specs=(P("shards"), P("shards")))
+
+    def search_solo(dev, buffers):
+        ts, ti, tot = constrain_shards(region(dev, unpack(buffers, layout)),
+                                       mesh4)
+        with jax.named_scope("topk"):
+            flat = constrain(ts.reshape(-1), mesh4, P())
+            flat_i = constrain(ti.reshape(-1), mesh4, P())
+            g_scores, g_idx = jax.lax.top_k(flat, TOP_K)
+        return g_scores, g_idx // TOP_K, flat_i[g_idx], tot.sum()
+
+    sharded = NamedSharding(mesh4, P("shards"))
+    dev = {"dense_tfn": _sds((S, V_DENSE, n), jnp.float32, sharded),
+           "codes": _sds((S, nb, BLOCK), jnp.uint16, sharded),
+           "docids": _sds((S, nb, BLOCK), jnp.int32, sharded),
+           "live": _sds((S, n), jnp.bool_, sharded)}
+    # the parameters arrive as one host array: no sharding of their own
+    compiled = jax.jit(search_solo).lower(
+        dev, tuple(jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buffers),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    assert "all-gather" in text, "no all-gather in the program"
+    # each chip is handed its own row of the packed parameters
+    assert "s32[1,71]" in text
+
+
 def test_sharded_fused_region_compiles_on_four_chips(mesh4,
                                                      no_persistent_cache):
     """The one-program fused `_msearch` of a 4-shard index: the Pallas
