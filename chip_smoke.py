@@ -381,7 +381,6 @@ def run_requests(c: Client, port: int, ref: Reference, queries,
                  chips: int) -> dict:
     solo_q, ms_q, conc_q = queries[:8], queries[8:520], queries[520:584]
     solo = solo_searches(c, solo_q)
-    after_solo = node_stats(c)
     # the fused `_msearch` arm is reached through the serving front end
     # only: with serving off, REST runs the sub-searches one by one
     c.call("PUT", "/_cluster/settings",
@@ -411,14 +410,6 @@ def run_requests(c: Client, port: int, ref: Reference, queries,
     if not fused_dispatches(after_ms):
         fail("the fused Pallas pipeline (parallel/sharded._compiled_merged) "
              "served no _msearch wave")
-    topk = after_solo["metrics"]["counters"]
-    if chips == 1 and int(
-            topk.get("es.search.topk.fused_scan", 0)) < len(solo_q):
-        # (four shards of 250k docs sit under the scan's 1<<18 floor)
-        fail("the per-request top-k did not take the Pallas scan "
-             f"(es.search.topk.*: {topk.get('es.search.topk.fused_scan', 0)}"
-             f" fused_scan, {topk.get('es.search.topk.xla_topk', 0)} "
-             "xla_topk)")
     if chips == 4:
         per_dev = stats["device"]["memory"]["devices"]
         say("bytes held per device: " + json.dumps(per_dev))

@@ -22,6 +22,7 @@ from __future__ import annotations
 import fnmatch
 import json
 import os
+import threading
 
 from ..utils.errors import (
     IllegalArgumentError,
@@ -90,7 +91,9 @@ class MetadataStore:
         f = self._file()
         if not f:
             return
-        tmp = f + ".tmp"
+        # the saver's own temp file: two threads sharing one would find it
+        # gone at the later `os.replace` (the watcher's executor saves too)
+        tmp = f"{f}.{threading.get_ident()}.tmp"
         with open(tmp, "w", encoding="utf-8") as fh:
             json.dump(
                 {

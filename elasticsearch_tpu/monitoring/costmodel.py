@@ -125,9 +125,10 @@ def matmul_cost(m: int, k: int, n: int, *, passes: int = 1,
 
 
 def topk_scan_cost(q: int, n: int, *, score_bytes: int = 4) -> dict:
-    """Streamed top-k over a [q, n] score field: one bandwidth-bound read
-    of the scores, 2 ops (compare + select) per element. The in-VMEM
-    running top-k never round-trips HBM, so k does not appear."""
+    """Top-k over a [q, n] score field: one read of the scores, 2 ops
+    (compare + select) per element. What is kept beside the pass (a running
+    top-k in VMEM; the k candidate blocks of ops/scoring.top_k_with_total)
+    is small against it, so k does not appear."""
     return {
         "flops": 2.0 * q * n,
         "bytes": float(q * n * score_bytes),
@@ -267,7 +268,7 @@ def _fused_pallas_scan(fields: dict) -> dict | None:
 
 def _compiled_plan(fields: dict) -> dict | None:
     """Per-query compiled plan (query/executor): dense accumulator
-    scatter + streamed/xla selection over [1, N]. Coarse by design — the
+    scatter + selection over [1, N]. Coarse by design — the
     query's term mix is not in the fields; the selection pass dominates."""
     n = fields.get("num_docs")
     if not n:

@@ -11,12 +11,12 @@ the FLOPs on the MXU and the heap in VMEM:
     sequentially on a core, so the scratch accumulator is race-free — the
     Pallas analog of Lucene's per-segment collector state.
 
-Two input modes share the merge machinery:
-  - matmul mode: q [B, D] against mat_t [D, N] — serves batched dense-tier
-    BM25 (q = per-query term weights, mat_t = dense tfn rows) and exact kNN
-    scans (q = query vectors, mat_t = transposed doc vectors).
-  - streamed mode: precomputed scores [B, N] — a bandwidth-optimal top-k
-    + match-count pass replacing sort-based `lax.top_k`.
+The scan takes q [B, D] against mat_t [D, N]: it serves batched dense-tier
+BM25 (q = per-query term weights, mat_t = dense tfn rows) and exact kNN
+scans (q = query vectors, mat_t = transposed doc vectors). One query's
+already-scored row is not its job (ops/scoring.top_k_with_total selects it
+in plain XLA): B = 1 leaves seven of eight sublanes of every vreg idle and
+the merge's serial rounds run once a 512-lane tile with nothing to overlap.
 
 Why fusion matters: materializing [B, N] f32 scores for a 4k-query batch over
 a 1M-doc shard is ~16 GB of HBM traffic before top-k even starts; the fused
@@ -111,7 +111,7 @@ def _scan_topk_kernel(
     q_ref, m_ref, live_ref, auxd_ref, auxq_ref,
     ov_ref, oi_ref, ot_ref,
     acc_v, acc_i, cnt,
-    *, k, tile_n, transform, count_positive, matmul,
+    *, k, tile_n, transform, count_positive,
 ):
     j = pl.program_id(1)
     nn = pl.num_programs(1)
@@ -122,15 +122,12 @@ def _scan_topk_kernel(
         acc_i[:] = jnp.zeros_like(acc_i)
         cnt[:] = jnp.zeros_like(cnt)
 
-    if matmul:
-        # HIGHEST: full-f32 MXU passes for bit-parity with the unfused path
-        dots = jnp.dot(
-            q_ref[:], m_ref[:],
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-    else:
-        dots = m_ref[:]
+    # HIGHEST: full-f32 MXU passes for bit-parity with the unfused path
+    dots = jnp.dot(
+        q_ref[:], m_ref[:],
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     scores = _apply_transform(dots, transform, auxd_ref[0, :], auxq_ref[:])
     ids = j * tile_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     ok = live_ref[0, :] > 0
@@ -171,40 +168,27 @@ def _scan_topk_pallas(
     q, mat_t, live, aux_doc, aux_q,
     *, k, transform, count_positive, interpret, tiles,
 ):
-    matmul = q is not None
-    B = q.shape[0] if matmul else mat_t.shape[0]
-    D = q.shape[1] if matmul else 1
-    N = mat_t.shape[1]
+    B, D = q.shape
     tile_b, tile_n = tiles
-    if matmul:
-        qp = _pad_to(q, tile_b, 0, 0.0)
-        mp = _pad_to(mat_t, tile_n, 1, 0.0)
-    else:
-        qp = jnp.zeros((pl.cdiv(B, tile_b) * tile_b, 1), jnp.float32)
-        mp = _pad_to(_pad_to(mat_t, tile_b, 0, 0.0), tile_n, 1, 0.0)
+    qp = _pad_to(q, tile_b, 0, 0.0)
+    mp = _pad_to(mat_t, tile_n, 1, 0.0)
     livep = _pad_to(live.astype(jnp.float32)[None, :], tile_n, 1, 0.0)
     auxdp = _pad_to(aux_doc[None, :], tile_n, 1, 0.0)
     auxqp = _pad_to(aux_q[:, None], tile_b, 0, 0.0)
-    Bp = qp.shape[0] if matmul else mp.shape[0]
-    Np = mp.shape[1]
+    Bp, Np = qp.shape[0], mp.shape[1]
     nb, nn = Bp // tile_b, Np // tile_n
 
     kernel = functools.partial(
         _scan_topk_kernel,
         k=k, tile_n=tile_n, transform=transform,
-        count_positive=count_positive, matmul=matmul,
-    )
-    m_spec = (
-        pl.BlockSpec((D, tile_n), lambda i, j: (_I0, j))
-        if matmul
-        else pl.BlockSpec((tile_b, tile_n), lambda i, j: (i, j))
+        count_positive=count_positive,
     )
     out_v, out_i, out_t = pl.pallas_call(
         kernel,
         grid=(nb, nn),
         in_specs=[
-            pl.BlockSpec((tile_b, qp.shape[1]), lambda i, j: (i, _I0)),
-            m_spec,
+            pl.BlockSpec((tile_b, D), lambda i, j: (i, _I0)),
+            pl.BlockSpec((D, tile_n), lambda i, j: (_I0, j)),
             pl.BlockSpec((1, tile_n), lambda i, j: (_I0, j)),
             pl.BlockSpec((1, tile_n), lambda i, j: (_I0, j)),
             pl.BlockSpec((tile_b, 1), lambda i, j: (i, _I0)),
@@ -236,11 +220,7 @@ def scan_topk_xla(q, mat_t, live, aux_doc, aux_q, *, k, transform, count_positiv
     """XLA reference with identical semantics (and the non-TPU fast path).
     Jitted: callers outside a trace (e.g. the batched dense-only dispatch)
     must not fall back to eager per-op execution."""
-    dots = (
-        jnp.matmul(q, mat_t, precision=jax.lax.Precision.HIGHEST)
-        if q is not None
-        else mat_t
-    )
+    dots = jnp.matmul(q, mat_t, precision=jax.lax.Precision.HIGHEST)
     auxq = aux_q[:, None] if aux_q.ndim == 1 else aux_q
     scores = _apply_transform(dots, transform, aux_doc, auxq)
     scores = jnp.where(live[None, :] > 0, scores, -jnp.inf)
@@ -595,8 +575,8 @@ def use_pallas(score_bytes: int | None = None) -> bool:
 
 
 def scan_topk(
-    q: jax.Array | None,  # [B, D] f32 or None (streamed mode)
-    mat_t: jax.Array,  # [D, N] f32 (matmul mode) | [B, N] scores (streamed)
+    q: jax.Array,  # [B, D] f32
+    mat_t: jax.Array,  # [D, N] f32
     live: jax.Array,  # [N] bool/float mask
     k: int,
     *,
@@ -612,14 +592,13 @@ def scan_topk(
     semantics: all term weights > 0) else counts live lanes (kNN candidate
     counts).
     """
-    B = q.shape[0] if q is not None else mat_t.shape[0]
+    B, D = q.shape
     N = mat_t.shape[1]
     k = max(1, min(k, N))
     if aux_doc is None:
         aux_doc = jnp.zeros((N,), jnp.float32)
     if aux_q is None:
         aux_q = jnp.zeros((B,), jnp.float32)
-    D = q.shape[1] if q is not None else 1
     tiles = _pick_tiles(B, D, N, k) if k <= MAX_FUSED_K else None
     if interpret is None:
         if not use_pallas(score_bytes=4 * B * N) or tiles is None:

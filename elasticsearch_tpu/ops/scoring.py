@@ -170,27 +170,9 @@ def dense_term_scores(
     return scores, match
 
 
-def _fused_scan_engages(n: int, k: int) -> bool:
-    """The exact predicate top_k_with_total uses to pick the streamed
-    Pallas scan over sort-based lax.top_k — exposed so profiling can
-    attribute which selection tier a compiled plan actually ran."""
-    import os
-
-    import jax as _jax
-
-    mode = os.environ.get("ES_TPU_FUSED_TOPK", "auto")
-    from .kernels import MAX_FUSED_K
-
-    if mode == "0" or k > MAX_FUSED_K or n < 8:
-        return False
-    if mode == "force":
-        return True
-    return _jax.default_backend() == "tpu" and n >= (1 << 18)
-
-
-def topk_mode(n: int, k: int) -> str:
-    """-> "fused_scan" | "xla_topk": the selection tier for (n, k)."""
-    return "fused_scan" if _fused_scan_engages(n, k) else "xla_topk"
+# lanes a block of the two-level selection: one vreg row, so the [G, W] view
+# of the score row is a layout-free reshape
+SELECT_BLOCK = 128
 
 
 def top_k_with_total(
@@ -206,35 +188,46 @@ def top_k_with_total(
     (reference behavior: TopScoreDocCollector via
     search/query/QueryPhaseCollectorManager.java:416).
 
-    Behind ES_TPU_FUSED_TOPK (default on), large-corpus selection runs as
-    the streamed Pallas scan (ops/kernels.scan_topk streamed mode: one
-    bandwidth-bound pass holding the running top-k in VMEM) instead of
-    sort-based `lax.top_k` — identical (score desc, docid asc) order and
-    identical totals, so every per-query searcher (executor, the sharded
-    scatter/gather, C2's exhaustive fallback arm) rides the fused path.
-    'force' engages it on CPU through the interpreter (tests).
+    Selected in two levels, every op plain XLA (legal under vmap and inside
+    parallel/spmd.manual_shard_region alike): the masked row, padded with
+    -inf, is viewed as [G, W] blocks of W consecutive docids; level 1 takes
+    each block's maximum and the min(k, G) blocks first in (maximum desc,
+    block asc); level 2 gathers those blocks in ASCENDING block order, so
+    the flat candidate order is docid order and `lax.top_k`'s lowest-index
+    tie-break is still docid asc. One pass over the row and two selections
+    over G and k*W values replace a selection over all N.
 
-    PR 11 note: callers tracing sharded bodies no longer pin the XLA arm
-    (`force_xla` is gone) — pjit shard bodies run inside embedded
-    shard_map manual regions (parallel/spmd.manual_shard_region), where
-    the Pallas scan is legal because nothing asks GSPMD to partition it.
+    Why not `lax.top_k` over the whole row: the TPU compiler turns it into
+    its fast `TopK` call only where the operand is rank 2, which a rank-1
+    row is under one `vmap` and nowhere else. Inside a manual region (a
+    shard a chip) or unbatched (query/executor) it becomes a stable sort of
+    all N (score, index) pairs: at N = 294,912 on a v5e 342 us against
+    `TopK`'s 24, and ~20 s to compile, a program (scripts/topk_micro.py;
+    PERF.md section 6, PR 30). Here every selection is small, so either
+    lowering is cheap: 33 us at rank 2, 18 at rank 1.
+
+    Exact, not approximate: let document e of block g be in the true top-k
+    by (score desc, docid asc) with g not chosen. Then k chosen blocks g'
+    precede g: max(g') > max(g), or max(g') == max(g) and g' < g. Each holds
+    a document scoring max(g') >= score(e) and, on equality, of a smaller
+    docid (blocks are contiguous docid ranges, g' < g). So k documents beat
+    e: contradiction. (With G <= k every block is chosen.) The same floats
+    are compared, never recomputed, so values, ids (where the value is
+    finite) and total are bit-identical to `lax.top_k` over the masked row.
+    A -inf entry's id is some masked lane below N: the flat top-k fills from
+    the lowest candidate positions, and the padding lanes are the highest.
     """
-    import os
-
     n = live.shape[0]
     ok = match[:n] & live
-    if _fused_scan_engages(n, k):
-        force = os.environ.get("ES_TPU_FUSED_TOPK", "auto") == "force"
-        on_tpu = jax.default_backend() == "tpu"
-        from .kernels import scan_topk
-
-        v, i, t = scan_topk(
-            None, scores[:n][None, :], ok, k,
-            count_positive=False,
-            interpret=(not on_tpu) if force else False,
-        )
-        return v[0], i[0], t[0]
     total = jnp.sum(ok, dtype=jnp.int32)
     masked = jnp.where(ok, scores[:n], -jnp.inf)
-    top_scores, top_ids = jax.lax.top_k(masked, k)
+    w = SELECT_BLOCK
+    g = -(-n // w)
+    blocks = jnp.pad(masked, (0, g * w - n),
+                     constant_values=-jnp.inf).reshape(g, w)
+    _, chosen = jax.lax.top_k(blocks.max(axis=1), min(k, g))
+    chosen = jnp.sort(chosen)
+    top_scores, pos = jax.lax.top_k(blocks[chosen].reshape(-1), k)
+    w32 = jnp.int32(w)
+    top_ids = chosen[jax.lax.div(pos, w32)] * w32 + jax.lax.rem(pos, w32)
     return top_scores, top_ids, total

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.scoring import top_k_with_total, topk_mode
+from ..ops.scoring import top_k_with_total
 from ..query.dsl import parse_query
 from ..utils.jax_env import shard_map
 from ..utils.errors import IllegalArgumentError
@@ -419,10 +419,8 @@ class StackedSearcher:
         k_global = min(k, S * k_local)
 
         def shard_body(dev1, par1, agg_par1):
-            # PR 11: no force_xla pin — the body runs inside an embedded
-            # shard_map manual region, where the streamed Pallas scan is
-            # legal (GSPMD never sees the custom call), so the selection
-            # tier is the SAME one the single-device path picks
+            # the body runs inside an embedded shard_map manual region; the
+            # selection is the plain XLA the single-device path runs
             # the scopes name phases, not implementations: they are HLO
             # metadata that a capture's device operations carry
             with jax.named_scope("score"):
@@ -478,9 +476,6 @@ class StackedSearcher:
         # named for what it is: one compiled program per plan shape, all of
         # one family in a capture's `XLA Modules` line
         fn = jax.jit(search_solo)
-        # the selection tier this program is built with, decided once
-        # here: the Pallas streamed scan (fused_scan) or lax.top_k
-        fn.topk_tier = topk_mode(n, k_local)
         # host arrays a call of it is handed, and the leaves packed in them
         fn.packed = packed_counts(layout)
         self._cache[cache_key] = fn
@@ -1421,7 +1416,7 @@ class StackedSearcher:
         from ..monitoring.xla_introspect import check_dispatch
         from ..telemetry import metrics
 
-        metrics.counter_inc("es.search.topk." + fn.topk_tier)
+        metrics.counter_inc("es.search.topk.xla_topk")
         check_dispatch("sharded.spmd_topk", fn, (self.dev, buffers),
                        fields={"queries": 1, "k": k,
                                "num_docs": self.sp.S * self.sp.n_max})

@@ -202,8 +202,7 @@ class ShardSearcher:
         kernel (in-kernel split-bf16 matmul + per-tile top-t + canonical
         f32 rescore) whenever ES_TPU_FUSED / ES_TPU_FUSED_TOPK and the
         pack shape allow; per-query `search` keeps the compiled-plan
-        path, whose final selection also streams through the fused
-        scan (ops/scoring.top_k_with_total)."""
+        path, whose final selection is ops/scoring.top_k_with_total."""
         bs = getattr(self, "_batched", None)
         if bs is None:
             from ..ops.batched import BatchTermSearcher
@@ -417,12 +416,11 @@ class ShardSearcher:
         kind, state = self._plan_request(query, size, from_, mappings, aggs)
         if kind == "result":
             return state
-        from ..ops.scoring import topk_mode
         from ..telemetry import time_kernel
 
         k = state["k"]
         with time_kernel("compiled_plan", shard=0, queries=1,
-                         tier=topk_mode(self.pack.num_docs, k),
+                         tier="xla_topk",
                          num_docs=self.pack.num_docs, k=k):
             host = jax.device_get(state["outs"])
         return self._finalize_request(state, host)
@@ -468,7 +466,6 @@ class ShardSearcher:
                 slots[i] = (ck, scope)
         live = [s for s in states if s is not None]
         if live:
-            from ..ops.scoring import topk_mode
             from ..telemetry import host_transition, time_kernel
 
             # the wave contract (PR 11): every program dispatched above,
@@ -476,7 +473,7 @@ class ShardSearcher:
             host_transition("dispatch")
             k0 = max(s["k"] for s in live)
             with time_kernel("compiled_plan", shard=0, queries=len(live),
-                             tier=topk_mode(self.pack.num_docs, k0),
+                             tier="xla_topk",
                              num_docs=self.pack.num_docs, k=k0):
                 host = jax.device_get([s["outs"] for s in live])
             host_transition("fetch")

@@ -111,7 +111,7 @@ def device_sections(events: list[dict] | None, num_shards: int) -> list[dict]:
     # escalation outranks everything (it means the fast arm's result was
     # replaced); otherwise the last tier event of the main arm wins
     precedence = {"exact_escalation": 3, "fused": 2, "fast": 1, "exact": 1,
-                  "fused_scan": 1, "xla_topk": 0}
+                  "xla_topk": 0}
     best = -1
     dominant = None
     for e in (events or []):

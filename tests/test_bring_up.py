@@ -120,7 +120,7 @@ def test_solo_search_counts_the_tier_its_program_was_built_with():
         idx.refresh()
         before = count()
         idx.search({"match": {"b": "tier"}}, size=1)
-        assert count() > before  # CPU, 1 doc: never the Pallas scan
+        assert count() > before
     finally:
         e.close()
 

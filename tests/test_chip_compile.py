@@ -12,6 +12,7 @@ AOT entry written here cannot be read back without a chip).
 
 import functools
 import os
+import re
 import types
 
 import numpy as np
@@ -107,24 +108,35 @@ def test_fused_tile_candidates_compiles(one_chip, no_persistent_cache, bud):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_scan_topk_streamed_compiles_under_vmap(one_chip,
-                                                no_persistent_cache):
-    """ops/scoring.top_k_with_total's Pallas arm as the one-shard server
-    runs it: streamed scan_topk inside the vmapped shard body (S=1)."""
+def _assert_block_select_in(text, n):
+    """The compiled program selects in two levels and calls no Mosaic
+    kernel: one selection over the K chosen blocks, and no sort of a whole
+    row of n (what a rank-1 `lax.top_k` over the row compiles to)."""
+    from elasticsearch_tpu.ops.scoring import SELECT_BLOCK
+
+    assert "tpu_custom_call" not in text
+    assert f"{TOP_K * SELECT_BLOCK}]" in text, "no K x W candidate row"
+    whole_row = re.compile(rf"= \(f32\[(1,)*{n}\].* sort\(")
+    assert not [ln for ln in text.splitlines() if whole_row.search(ln)]
+
+
+def test_top_k_with_total_compiles_under_vmap(one_chip, no_persistent_cache):
+    """ops/scoring.top_k_with_total as the one-shard server runs it: inside
+    the vmapped shard body (S=1), at the 1M-doc width."""
     import jax
     import jax.numpy as jnp
 
-    from elasticsearch_tpu.ops.kernels import scan_topk
+    from elasticsearch_tpu.ops.scoring import top_k_with_total
 
-    def shard_body(scores, ok):
-        return scan_topk(None, scores[None, :], ok, TOP_K,
-                         count_positive=False, interpret=False)
+    def shard_body(scores, match, live):
+        return top_k_with_total(scores, match, live, TOP_K)
 
     compiled = jax.jit(jax.vmap(shard_body)).lower(
-        _sds((1, N_DOCS), jnp.float32, one_chip),
+        _sds((1, N_DOCS + 1), jnp.float32, one_chip),
+        _sds((1, N_DOCS + 1), jnp.bool_, one_chip),
         _sds((1, N_DOCS), jnp.bool_, one_chip),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    _assert_block_select_in(compiled.as_text(), N_DOCS)
 
 
 def test_impact_gather_pallas_compiles(one_chip, no_persistent_cache):
@@ -151,14 +163,14 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
         one_chip, no_persistent_cache):
     """PR 27: the solo program takes a `match`'s parameters as one
     int32[S, W] buffer; its slices (and 32-bit bitcasts) feed the dense
-    row index, the impact tier's gather and the streamed scan."""
+    row index, the impact tier's gather and the two-level selection."""
     import jax
     import jax.numpy as jnp
 
     from elasticsearch_tpu.index.pack import BLOCK
-    from elasticsearch_tpu.ops.kernels import scan_topk
     from elasticsearch_tpu.ops.scoring import (dense_term_scores,
-                                               impact_term_scores)
+                                               impact_term_scores,
+                                               top_k_with_total)
     from elasticsearch_tpu.parallel.param_pack import pack, unpack
 
     f32, i32 = np.float32, np.int32
@@ -172,9 +184,7 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
         ((dr, weight, _), (rows, _, _, wscale)), boost = params
         s1, m1 = dense_term_scores(dense_tfn[dr], weight, N_DOCS)
         s2, m2 = impact_term_scores(codes, docids, rows, wscale, N_DOCS)
-        scores, ok = boost * (s1 + s2), (m1 | m2)[:N_DOCS] & live
-        return scan_topk(None, scores[None, :N_DOCS], ok, TOP_K,
-                         count_positive=False, interpret=False)
+        return top_k_with_total(boost * (s1 + s2), m1 | m2, live, TOP_K)
 
     def solo(dense_tfn, codes, docids, live, buffers):
         return jax.vmap(shard_body)(dense_tfn, codes, docids, live,
@@ -188,7 +198,7 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
         tuple(_sds(b.shape, b.dtype, one_chip) for b in buffers),
     ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    _assert_block_select_in(text, N_DOCS)
     # no 64-bit bitcast is ever asked of the chip
     assert "s64" not in text and "f64" not in text
 
@@ -197,25 +207,25 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
         mesh4, no_persistent_cache):
     """PR 29: the program of `passage-4chip.solo.c8`, one `match` over four
     shards of 294,912 documents, one a chip: the packed `int32[4, W]`
-    parameters, the impact tier's gather and the streamed Pallas top-k
-    inside `manual_shard_region` (294,912 lanes a shard is over the 1 << 18
-    at which `_fused_scan_engages`), then the replication constraint that is
-    the all-gather, and the global top-k: `_compiled`'s shape, built from
-    the same pieces."""
+    parameters, the impact tier's gather and `top_k_with_total` (plain XLA,
+    so what compiles here is what the chip runs) inside
+    `manual_shard_region`, where the shard's row is rank 1 and a `lax.top_k`
+    over the whole of it would be a stable sort of 294,912 pairs; then the
+    replication constraint that gathers the shards' rows, and the global
+    top-k: `_compiled`'s shape, built from the same pieces."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from elasticsearch_tpu.index.pack import BLOCK
-    from elasticsearch_tpu.ops.kernels import scan_topk
     from elasticsearch_tpu.ops.scoring import (dense_term_scores,
-                                               impact_term_scores)
+                                               impact_term_scores,
+                                               top_k_with_total)
     from elasticsearch_tpu.parallel.param_pack import pack, unpack
     from elasticsearch_tpu.parallel.spmd import (constrain, constrain_shards,
                                                  manual_shard_region)
 
     S, n = 4, 294_912
-    assert n >= 1 << 18
     nb = N_BLOCKS // 3          # ~1/3 of the 1M-doc pack's blocks a shard
     f32, i32 = np.float32, np.int32
     dense = (np.zeros((S,), i32), np.ones((S,), f32), np.ones((S,), f32))
@@ -232,12 +242,7 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
                                         wscale, n)
             scores, match = boost * (s1 + s2), m1 | m2
         with jax.named_scope("topk"):
-            # top_k_with_total's Pallas arm, which asks the backend and so
-            # cannot be steered from here
-            v, i, t = scan_topk(None, scores[None, :n],
-                                match[:n] & dev1["live"], TOP_K,
-                                count_positive=False, interpret=False)
-            return v[0], i[0], t[0]
+            return top_k_with_total(scores, match, dev1["live"], TOP_K)
 
     region = manual_shard_region(shard_body, mesh4,
                                  in_specs=(P("shards"), P("shards")))
@@ -261,8 +266,12 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
         dev, tuple(jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buffers),
     ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
-    assert "all-gather" in text, "no all-gather in the program"
+    _assert_block_select_in(text, n)
+    # the shards' rows reach every chip: an all-gather, or (the compiler's
+    # choice here) each chip's K rows in a zeroed [S x K] buffer, summed
+    rows = rf"= [fs]32\[({S * TOP_K}|{S},{TOP_K})\].* all-(gather|reduce)\("
+    gathers = [ln for ln in text.splitlines() if re.search(rows, ln)]
+    assert len(gathers) >= 2, "scores and ids are not gathered"
     # each chip is handed its own row of the packed parameters
     assert "s32[1,71]" in text
 

@@ -29,6 +29,9 @@ def _cache_on(monkeypatch):
     cache itself and must see it enabled. The session _env_hermetic
     fixture restores the gate's env afterwards."""
     monkeypatch.delenv("ES_TPU_REQUEST_CACHE", raising=False)
+    # one cache object a process: tests/test_param_pack.py switches it off
+    # through REST, and may have run before on this worker
+    request_cache().set_enabled(True)
 
 
 # ---------------------------------------------------------------------------

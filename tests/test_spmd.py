@@ -430,20 +430,35 @@ def test_fused_arm_rides_one_program_with_ties(fsp, monkeypatch):
                 assert abs(a - b) <= 1e-5 * max(abs(b), 1.0), (q, pos)
 
 
-def test_pallas_scan_engages_inside_pjit_program(sp, monkeypatch):
-    """The force_xla pin is gone: with ES_TPU_FUSED_TOPK=force the
-    per-shard selection of the compiled `search` program routes through
-    the streamed Pallas scan INSIDE the pjit program's embedded
-    shard_map region — parity vs the sort-based XLA arm."""
+def test_two_level_selection_inside_pjit_program(monkeypatch):
+    """The per-shard selection of the compiled `search` program runs in two
+    levels INSIDE the pjit program's embedded shard_map region, on a pack
+    whose shards have more blocks than hits are asked for — parity vs the
+    same program built with plain `lax.top_k` over each shard's whole row."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.scoring import SELECT_BLOCK
+    from elasticsearch_tpu.parallel import sharded
+
+    def plain(scores, match, live, k):
+        n = live.shape[0]
+        ok = match[:n] & live
+        v, i = jax.lax.top_k(jnp.where(ok, scores[:n], -jnp.inf), k)
+        return v, i, jnp.sum(ok, dtype=jnp.int32)
+
     monkeypatch.setenv("ES_TPU_REQUEST_CACHE", "0")
+    big = build_stacked_pack(_corpus(n=4600), Mappings(_MAPPING),
+                             num_shards=4)
+    assert big.n_max > 6 * SELECT_BLOCK
     q = {"bool": {"should": [{"term": {"body": "w1"}},
                              {"term": {"body": "w2"}},
                              {"term": {"body": "rareterm"}}]}}
-    monkeypatch.setenv("ES_TPU_FUSED_TOPK", "force")
-    r_scan = _searcher(sp, "pjit", monkeypatch).search(query=q, size=6)
-    monkeypatch.setenv("ES_TPU_FUSED_TOPK", "0")
-    r_xla = _searcher(sp, "pjit", monkeypatch).search(query=q, size=6)
-    _same_result(r_scan, r_xla, "pallas-scan-in-pjit")
+    r_select = _searcher(big, "pjit", monkeypatch).search(query=q, size=6)
+    monkeypatch.setattr(sharded, "top_k_with_total", plain)
+    r_plain = _searcher(big, "pjit", monkeypatch).search(query=q, size=6)
+    assert len(r_select.doc_ids) == 6 and r_select.total > 6
+    _same_result(r_select, r_plain, "two-levels-in-pjit")
 
 
 # ---------------------------------------------------------------------------

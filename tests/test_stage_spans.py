@@ -417,6 +417,64 @@ def test_the_compiled_search_is_named_search_solo_and_carries_its_scopes(
         assert re.search(rf"jit\(search_solo\)/(\w+\()*{scope}\)*/", text), scope
 
 
+def _scoped_primitives(jaxpr, scope=""):
+    """-> [(primitive, the scopes around it)] of a jaxpr's leaf equations; a
+    nested jaxpr's own name stacks start anew under its equation's."""
+    out = []
+    for eqn in jaxpr.eqns:
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        inner = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                 if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        if not inner:
+            out.append((eqn.primitive.name, here))
+        for sub in inner:
+            out.extend(_scoped_primitives(sub, here))
+    return out
+
+
+def test_the_selection_runs_whole_under_the_scope_topk():
+    """`device.topk_ms` is the time of the operations that carry the scope
+    `topk`: every operation the selection adds has to carry it, and nothing
+    but plumbing may run outside `score` and `topk`."""
+    import jax
+
+    from elasticsearch_tpu.engine import Engine
+
+    engine = Engine(None)
+    try:
+        idx = engine.create_index(
+            "wide", {"properties": {"body": {"type": "text"}}})
+        for i in range(1100):
+            idx.index_doc(str(i), {"body": f"{WORDS[i % 7]} common"})
+        idx.refresh()
+        searcher = idx.searcher
+        st = searcher._agg_dispatch(query={"match": {"body": "alpha common"}},
+                                    size=1)
+        fn, buffers = searcher._packed_program(
+            st["node"], st["keys"], st["k"], None, (), st["params"],
+            st["agg_params"])
+        prims = _scoped_primitives(
+            jax.make_jaxpr(fn)(searcher.dev, buffers).jaxpr)
+    finally:
+        engine.close()
+    in_topk = [p for p, scope in prims if "topk" in scope]
+    # the blocks' maxima, the two selections and the shards' merge, the
+    # ascending sort, the gather of the chosen blocks
+    assert in_topk.count("top_k") == 3 and in_topk.count("sort") == 1
+    assert {"pad", "reduce_max", "gather", "reduce_sum"} <= set(in_topk)
+    selection = {"top_k", "sort", "pad", "reduce_max", "reduce_min", "gather",
+                 "select_n", "div", "rem"}
+    stray = [(p, scope) for p, scope in prims
+             if p in selection and "topk" not in scope
+             and "score" not in scope]
+    assert not stray, stray
+    unscoped = {p for p, scope in prims
+                if "topk" not in scope and "score" not in scope}
+    # the parameters' unpacking and the total's sum over the shards
+    assert unscoped <= {"slice", "reshape", "squeeze", "bitcast_convert_type",
+                        "convert_element_type", "reduce_sum"}, unscoped
+
+
 def test_persistent_cache_hits_are_counted_beside_compiles():
     import jax.monitoring
 
