@@ -1,4 +1,5 @@
-"""Headline benchmarks: the five BASELINE.json configs on real TPU hardware.
+"""Headline benchmarks: BASELINE.json configs 1, 3, 4 and 5 on real TPU hardware
+(config 2, the multi-term disjunction, is what benchmark/'s passage cells run).
 
 Corpus scale and honesty (VERDICT round 1, Next-round #2):
   - 1,000,000 synthetic msmarco-passage-like docs (Zipf term distribution,
@@ -21,9 +22,6 @@ host named by BASELINE.json, with the formula printed next to each number
 (see BENCH_NOTES.md for derivations and sources of the per-core rates):
   C1  match BM25 top-10:   32 cores x 75M WAND-effective postings/s/core
                            x 0.6 multicore scaling / mean(sum df per query)
-  C2  WAND disjunction:    speedup of the pruned path vs this framework's
-                           own exhaustive execution of the identical query
-                           (result-identical, so the ratio isolates pruning)
   C3  terms+date_histogram: 60M docs/s aggregate DocValues scan rate
                            (http_logs hourly_agg-class service times)
   C4  exact kNN cosine:    32 cores x 25 GFLOP/s/core effective over
@@ -537,192 +535,6 @@ def _impact_arm(searcher, lens, tok, rng, batches):
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
-
-
-def config2_wand(lens, tok, pack, m, rng):
-    """bool-should disjunctions: the PRODUCTION pruned path (block-max WAND
-    where the profitability gate engages, exhaustive fallback in the same
-    batched wave — search_pruned_batch) vs pure exhaustive on identical
-    queries, PLUS an engaged-pruning crossover sweep on a CSR-only build
-    of the same corpus. Round 4 timed the no-op of 12 gate-rejected
-    queries and printed it as a 67x win (VERDICT r4 weak #2); here a
-    non-engaging batch costs its exhaustive execution by construction,
-    engagement is reported per batch, and the sweep measures pruning
-    actually ENGAGED on hardware at increasing postings volumes so the
-    gate's crossover is a measurement, not a comment."""
-    from elasticsearch_tpu.parallel.sharded import StackedSearcher
-    from elasticsearch_tpu.parallel.stacked import StackedPack
-    from elasticsearch_tpu.query.dsl import parse_query
-
-    def _batch_pair(ss, qs, force=False):
-        """Warm + time exhaustive vs production-pruned on one query set.
-        Returns (t_ex, t_pr, engaged, mismatches, pruned_frac)."""
-        nodes = [parse_query(q, m) for q in qs]
-        ex_reqs = [dict(query=nd, size=TOP_K) for nd in nodes]
-        wd_reqs = [dict(node=nd, size=TOP_K, floor=0) for nd in nodes]
-        if force:
-            ss.wand_min_rows = 1
-        elif hasattr(ss, "wand_min_rows"):
-            del ss.wand_min_rows  # fall back to the production gate
-        ss.search_batch(ex_reqs)
-        ss.search_pruned_batch(wd_reqs)  # warm both compiled paths
-        t0 = time.perf_counter()
-        r_ex = ss.search_batch(ex_reqs)
-        t_ex = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        r_pr = ss.search_pruned_batch(wd_reqs)
-        t_pr = time.perf_counter() - t0
-        engaged = sum(r.wand_engaged for r in r_pr)
-        mism = sum(
-            1 for a, b_ in zip(r_pr, r_ex)
-            if list(a.doc_ids) != list(b_.doc_ids)
-        )
-        fracs = [
-            st["rows_pruned"] / max(st["rows_kept"] + st["rows_pruned"], 1)
-            for r in r_pr
-            for st in [getattr(r, "wand_stats", None)] if st
-        ]
-        frac = float(np.mean(fracs)) if fracs else 0.0
-        return t_ex, t_pr, engaged, mism, frac
-
-    # ---- part A: production path on the standard (dense-tier) pack ------
-    sp = StackedPack([pack], m)
-    ss = StackedSearcher(sp, mesh=None)
-    qs = [
-        {"bool": {"should": [
-            {"term": {"body": f"t{t}"}}
-            for t in rng.integers(900, 3500, size=4)
-        ]}}
-        for _ in range(12)
-    ]
-    t_ex, t_pr, engaged, mism, frac = _batch_pair(ss, qs)
-    out = {
-        "batch12_exhaustive_ms": round(t_ex * 1e3, 1),
-        "batch12_production_ms": round(t_pr * 1e3, 1),
-        "speedup": round(t_ex / t_pr, 2),
-        "engaged": f"{engaged}/{len(qs)}",
-        "postings_pruned_frac": round(frac, 3),
-        "topk_mismatches": mism,
-        "note": "production path = WAND where the gate engages, exhaustive "
-                "fallback inside the timed region otherwise",
-    }
-    del sp, ss
-    gc.collect()
-
-    # ---- part B: engaged crossover on a CSR-only build -------------------
-    # The dense tier makes top-Zipf terms unprunable-but-cheap (one MXU
-    # matmul); WAND's native regime is postings that have NO dense tier —
-    # the beyond-HBM configuration (full msmarco's dense tier would not
-    # fit one chip, BENCH_NOTES.md). Rebuild the SAME corpus CSR-only and
-    # sweep rare+common disjunctions of growing width: each point reports
-    # total CSR block rows (the gate's metric), whether the production
-    # gate engages, and forced-engagement speedup vs exhaustive.
-    log("[c2] building CSR-only pack for the engaged-pruning sweep...")
-    csr_pack, _ = build_pack(lens, tok, dense_min_df=1 << 62)
-    sp = StackedPack([csr_pack], m, dense_min_df=1 << 62)
-    ss = StackedSearcher(sp, mesh=None)
-    # rare terms: high-idf deciders (df ~ 40-200 on the Zipf tail;
-    # rank range scales with the vocab so the smoke corpus has them too)
-    rare_pool = [int(r) for r in rng.integers(VOCAB // 5, VOCAB * 3 // 5,
-                                              size=8)]
-    sweep = []
-    for width in (2, 8, 32):
-        qs = []
-        for b_i in range(6):
-            rares = rng.choice(rare_pool, 2, replace=False)
-            commons = rng.permutation(width * 2)[:width]
-            qs.append({"bool": {"should": [
-                {"term": {"body": f"t{t}"}} for t in rares
-            ] + [
-                {"term": {"body": f"t{t}"}} for t in commons
-            ]}})
-        rows = int(np.mean([
-            sum(
-                csr_pack.term_blocks("body", s["term"]["body"])[1]
-                for s in q["bool"]["should"]
-            )
-            for q in qs
-        ]))
-        t_ex, t_pr, engaged, mism, frac = _batch_pair(ss, qs, force=True)
-        # r08: the strongest opponent — the same queries through the
-        # eager impact tier (BM25S gather+sum over quantized codes; the
-        # code blocks were derived at searcher construction, the env flag
-        # only flips the plan routing, so warm+time is apples-to-apples)
-        saved_imp = os.environ.get("ES_TPU_IMPACT")
-        try:
-            os.environ["ES_TPU_IMPACT"] = "force"
-            nodes = [parse_query(q, m) for q in qs]
-            imp_reqs = [dict(query=nd, size=TOP_K) for nd in nodes]
-            ss.search_batch(imp_reqs)  # warm the term_imp compiled plans
-            t0 = time.perf_counter()
-            ss.search_batch(imp_reqs)
-            t_imp = time.perf_counter() - t0
-        finally:
-            if saved_imp is None:
-                os.environ.pop("ES_TPU_IMPACT", None)
-            else:
-                os.environ["ES_TPU_IMPACT"] = saved_imp
-        from elasticsearch_tpu.parallel.sharded import wand_gate_min_rows
-
-        gate_engages = rows >= wand_gate_min_rows()
-        sweep.append({
-            "width": width,
-            "mean_rows": rows,
-            "gate_engages": gate_engages,
-            "forced_engaged": f"{engaged}/{len(qs)}",
-            "exhaustive_ms": round(t_ex * 1e3, 1),
-            "pruned_ms": round(t_pr * 1e3, 1),
-            "impact_ms": round(t_imp * 1e3, 1),
-            "speedup_engaged": round(t_ex / t_pr, 2),
-            "speedup_impact_vs_exhaustive": round(t_ex / t_imp, 2),
-            "speedup_pruned_vs_impact": round(t_imp / t_pr, 2),
-            "pruned_frac": round(frac, 3),
-            "topk_mismatches": mism,
-        })
-        log(f"[c2] sweep width={width}: {sweep[-1]}")
-    out["csr_only_sweep"] = sweep
-    wins = [p for p in sweep if p["speedup_engaged"] > 1.5
-            and p["forced_engaged"] != "0/6"]
-    out["crossover"] = (
-        {"first_winning_width": wins[0]["width"],
-         "rows_at_crossover": wins[0]["mean_rows"]}
-        if wins else
-        "no sweep point beats exhaustive by >1.5x: the batched exhaustive "
-        "kernel dominates at 1M docs; the production gate (ES_TPU_WAND_MIN_"
-        "ROWS) stays high so WAND only engages beyond the measured range"
-    )
-    # ---- the verdict (ROADMAP item 2): WAND vs the impact tier ----------
-    # a "regime" must be one the PRODUCTION gate would actually route:
-    # forced sub-gate engagements on tiny corpora (smoke: 262 rows vs the
-    # 100k-row gate) are exactly the round-4 trap — a no-op-sized batch
-    # printed as a win (VERDICT r4 weak #2)
-    imp_wins = [p for p in sweep
-                if p["speedup_pruned_vs_impact"] > 1.5
-                and p["gate_engages"]
-                and p["forced_engaged"] != "0/6"]
-    sub_gate = [p for p in sweep
-                if p["speedup_pruned_vs_impact"] > 1.5
-                and not p["gate_engages"]]
-    out["wand_verdict"] = (
-        {"kept": True,
-         "regime": {"width": imp_wins[0]["width"],
-                    "rows": imp_wins[0]["mean_rows"],
-                    "speedup_vs_impact":
-                        imp_wins[0]["speedup_pruned_vs_impact"]},
-         "note": "a production-gated regime beats the impact tier by "
-                 ">1.5x — WAND stays production-routable"}
-        if imp_wins else
-        {"kept": False,
-         "sub_gate_forced_wins": [
-             {"width": p["width"], "rows": p["mean_rows"],
-              "speedup": p["speedup_pruned_vs_impact"]} for p in sub_gate],
-         "note": "no production-gated sweep point beats the impact tier "
-                 "by >1.5x (sixth losing round: r02-r05 vs exhaustive, "
-                 "r08 vs impact) — two-pass pruning demoted to the "
-                 "ES_TPU_WAND experimental flag; production prune_floor "
-                 "requests run the batched exhaustive/impact wave"}
-    )
-    return out
 
 
 def _c3_corpus(rng, n):
@@ -2507,7 +2319,7 @@ def main():
         _write_record(extras, partial=True)  # temp-file + rename per config
         print(_summary_line(extras, partial=True), flush=True)
 
-    if _want("c1") or _want("c2"):
+    if _want("c1"):
         log("[pack] building 1M-doc text pack...")
         t0 = time.perf_counter()
         # build_profile (PR 13): the C1 host-build baseline record — the
@@ -2524,16 +2336,10 @@ def main():
             f"stages {c1_build['stages_ms']}")
         from elasticsearch_tpu.query.executor import ShardSearcher
 
-        if _want("c1"):
-            searcher = ShardSearcher(pack, mappings=m)
-            _guard("match_bm25",
-                   lambda: config1_match(searcher, m, lens, tok, rng))
-            del searcher
-            gc.collect()
-        if _want("c2"):
-            _guard("wand_disjunction",
-                   lambda: config2_wand(lens, tok, pack, m, rng))
-        del pack
+        searcher = ShardSearcher(pack, mappings=m)
+        _guard("match_bm25",
+               lambda: config1_match(searcher, m, lens, tok, rng))
+        del searcher, pack
         gc.collect()
 
     if _want("c3"):

@@ -874,8 +874,6 @@ class EsIndex:
                 if seg.searcher.sp.live[s, d]:
                     seg.searcher.sp.shards[s].live[d] = False
                     seg.searcher.sp.live[s, d] = False
-                    seg.searcher.sp.dead_count = getattr(
-                        seg.searcher.sp, "dead_count", 0) + 1
                     flipped_segs.add(g)
             if e is not None and e.alive:
                 new_docs[did] = e.source
@@ -897,10 +895,6 @@ class EsIndex:
         seg_sp = build_stacked_pack_routed(
             routed, self.mappings, dense_min_df=1 << 62,
             **self._shard_build_args(base.mesh))
-        # total deadness across tiers: the WAND prune floor subtracts it
-        # from df before promising an exact count (sharded._wand_plan)
-        seg_sp.dead_count = sum(
-            getattr(s.sp, "dead_count", 0) for s in self.tier_searchers())
         self._account_packs(
             self._base_nbytes
             + sum(seg.nbytes for seg in self._tails) + seg_sp.nbytes(),
@@ -1015,7 +1009,6 @@ class EsIndex:
             sp = build_stacked_pack_routed(
                 routed, self.mappings, dense_min_df=1 << 62,
                 **self._shard_build_args(base.mesh))
-            sp.dead_count = getattr(base.sp, "dead_count", 0)
             self._account_packs(self._base_nbytes + sp.nbytes(), base.mesh)
             merged = _TailSegment(
                 searcher=None, shard_docs=routed,
@@ -1194,18 +1187,10 @@ class EsIndex:
     ):
         if collapse is not None and rescore is not None:
             raise IllegalArgumentError("cannot use [collapse] in conjunction with [rescore]")
-        # track_total_hits (reference: SearchSourceBuilder.trackTotalHitsUpTo,
-        # default threshold 10_000): true -> exact counting, which disables
-        # block-max pruning; false -> prune freely; int N -> prune only when
-        # the count provably reaches N (relation "gte" in the response)
-        if track_total_hits is None:
-            track_total_hits = 10_000
-        if track_total_hits is True:
-            prune_floor = None
-        elif track_total_hits is False:
-            prune_floor = 0
-        else:
-            prune_floor = int(track_total_hits)
+        # track_total_hits (reference: SearchSourceBuilder.trackTotalHitsUpTo):
+        # every count here is exact, so true, a threshold N and the default
+        # all answer {value: <count>, relation: "eq"}; false omits hits.total
+
         # ---- tiered fast path: base + tail searched separately, merged at
         # this coordinator (the per-segment search of the reference). Falls
         # through (auto-merging via the searcher property) for features the
@@ -1216,7 +1201,7 @@ class EsIndex:
                 and not script_fields):
             node = self._tier_node(query)
             if node is not None:
-                return self._search_tiered(node, size, from_, prune_floor,
+                return self._search_tiered(node, size, from_,
                                            track_total_hits,
                                            raw_query=query)
         m_eff = None
@@ -1323,7 +1308,7 @@ class EsIndex:
                 rts = [self._knn_exec(seg.searcher, _tier_node(), k)
                        for seg in tails]
                 out = self._tiered_merge(
-                    rb, rts, eff_size, from_, None, track_total_hits,
+                    rb, rts, eff_size, from_, track_total_hits,
                     [seg.shard_docs for seg in tails])
                 if track_total_hits is not False:
                     tv = out["hits"]["total"]
@@ -1418,24 +1403,22 @@ class EsIndex:
             res.max_score = float(order[0][2]) if order else None
         else:
             res = self.searcher.search(query, size=size, from_=from_, aggs=aggs,
-                                       mappings=m_eff,
-                                       prune_floor=None if knn is not None else prune_floor)
+                                       mappings=m_eff)
             if knn is not None and self._knn_mark_starved(
                     query, len(res.doc_ids) + from_, size + from_):
                 # filtered ANN retrieval could not reach k: re-run with
                 # the marked nodes recompiled onto the exact scan
                 res = self.searcher.search(query, size=size, from_=from_,
-                                           aggs=aggs, mappings=m_eff,
-                                           prune_floor=None)
+                                           aggs=aggs, mappings=m_eff)
         if knn is not None and knn_only:
             res.total = min(res.total, k_total)
         return self._format_generic_hits(
-            res, track_total_hits, prune_floor, aggs_request, had_pipeline,
+            res, track_total_hits, aggs_request, had_pipeline,
             script_fields=script_fields, collapse=collapse,
             collapse_keys=collapse_keys,
         )
 
-    def _format_generic_hits(self, res, track_total_hits, prune_floor,
+    def _format_generic_hits(self, res, track_total_hits,
                              aggs_request=None, had_pipeline=False,
                              script_fields=None, collapse=None,
                              collapse_keys=None) -> dict:
@@ -1464,14 +1447,8 @@ class EsIndex:
             if had_pipeline and res.aggregations is not None:
                 apply_pipeline_aggs(aggs_request, res.aggregations)
             self._resolve_top_hits(res.aggregations)
-            relation = getattr(res, "total_relation", "eq")
-            total_value = res.total
-            if relation == "gte" and prune_floor:
-                # the threshold itself is also a proven lower bound (pruning only
-                # engages when max term df >= floor); report the larger
-                total_value = max(total_value, prune_floor)
             hits_obj = {
-                "total": {"value": total_value, "relation": relation},
+                "total": {"value": res.total, "relation": "eq"},
                 "max_score": res.max_score,
                 "hits": hits,
             }
@@ -1567,8 +1544,8 @@ class EsIndex:
             return None
         return node if ok(node) else None
 
-    def _search_tiered(self, node, size, from_, prune_floor,
-                       track_total_hits, raw_query=None) -> dict:
+    def _search_tiered(self, node, size, from_, track_total_hits,
+                       raw_query=None) -> dict:
         # each tier parses/prepares its own copy of the query immediately
         # before its own execution, so per-searcher prepare state
         # (dense-tier routing) never crosses tiers. The RAW DSL dict is
@@ -1578,7 +1555,7 @@ class EsIndex:
         q = raw_query if isinstance(raw_query, dict) or raw_query is None \
             else node
         k = max(size + from_, 1)
-        rb = self._searcher.search(q, size=k, prune_floor=prune_floor)
+        rb = self._searcher.search(q, size=k)
         from ..telemetry import time_kernel
 
         # snapshot the segment list: a background fold may swap
@@ -1590,12 +1567,11 @@ class EsIndex:
                              num_docs=(seg.searcher.sp.S
                                        * seg.searcher.sp.n_max)):
                 rts.append(seg.searcher.search(q, size=k))
-        return self._tiered_merge(rb, rts, size, from_, prune_floor,
-                                  track_total_hits,
+        return self._tiered_merge(rb, rts, size, from_, track_total_hits,
                                   [seg.shard_docs for seg in tails])
 
-    def _tiered_merge(self, rb, rts, size, from_, prune_floor,
-                      track_total_hits, tail_shard_docs) -> dict:
+    def _tiered_merge(self, rb, rts, size, from_, track_total_hits,
+                      tail_shard_docs) -> dict:
         """Coordinator merge of the base + N tail-segment tier results —
         shared by the solo tiered path and the serving wave's tiered
         lane. `rts` is one result per tail segment, in segment order;
@@ -1621,15 +1597,11 @@ class EsIndex:
                 doc_id, src = docs[s][d]
                 hits.append({"_index": self.name, "_id": doc_id,
                              "_score": -negsc, "_source": src})
-            relations = [rb.total_relation] + [r.total_relation for r in rts]
-            relation = "gte" if "gte" in relations else "eq"
             value = rb.total + sum(r.total for r in rts)
-            if relation == "gte" and prune_floor:
-                value = max(value, prune_floor)
             max_score = max(
                 (x for x in (rb.max_score, *(r.max_score for r in rts))
                  if x is not None), default=None)
-            hits_obj = {"total": {"value": value, "relation": relation},
+            hits_obj = {"total": {"value": value, "relation": "eq"},
                         "max_score": max_score, "hits": hits}
             if track_total_hits is False:
                 del hits_obj["total"]
@@ -1728,13 +1700,9 @@ class EsIndex:
                         knn = resolve_query_vector_builders(knn, svc)
                     size = int(e.get("size", 10))
                     from_ = int(e.get("from_", 0))
-                    tth = e.get("track_total_hits")
-                    if tth is None:
-                        tth = 10_000
-                    pf = None if tth is True else (0 if tth is False
-                                                  else int(tth))
                     plans[i] = {"query": query, "knn": knn, "size": size,
-                                "from_": from_, "tth": tth, "pf": pf,
+                                "from_": from_,
+                                "tth": e.get("track_total_hits"),
                                 "aggs": e.get("aggs")}
                 except Exception as ex:  # noqa: BLE001
                     job["slots"][i] = ("error", ex)
@@ -1756,31 +1724,27 @@ class EsIndex:
             else:
                 tiered_nodes = None
             if tiered_nodes:
-                base_reqs, tail_reqs = [], []
+                reqs = []
                 for i in wave_ix:
                     p = plans[i]
                     q = (p["query"] if isinstance(p["query"], dict)
                          or p["query"] is None else tiered_nodes[i])
                     k = max(p["size"] + p["from_"], 1)
-                    base_reqs.append(dict(query=q, size=k, from_=0,
-                                          aggs=None, mappings=None,
-                                          prune_floor=p["pf"]))
-                    tail_reqs.append(dict(query=q, size=k, from_=0,
-                                          aggs=None, mappings=None,
-                                          prune_floor=None))
+                    reqs.append(dict(query=q, size=k, from_=0,
+                                     aggs=None, mappings=None))
                     job["fmt"][i] = p
                 segs = list(self._tails)
                 job["tiered"] = {
                     "ix": wave_ix,
                     "base": (self._searcher,
-                             self._searcher.search_many_begin(base_reqs)),
+                             self._searcher.search_many_begin(reqs)),
                     # one batched program per tail segment, all dispatched
                     # here and pulled by the wave's single combined fetch;
                     # shard_docs captured NOW — a background fold may swap
                     # the live segment list before this wave finishes
                     "tails": [
                         (seg.searcher, seg.searcher.search_many_begin(
-                            [dict(r) for r in tail_reqs]))
+                            [dict(r) for r in reqs]))
                         for seg in segs
                     ],
                     "tail_shard_docs": [seg.shard_docs for seg in segs],
@@ -1819,7 +1783,6 @@ class EsIndex:
                     aggs, had_pipeline = strip_pipeline_aggs(aggs_request)
                     aggs = aggs or None
                     query, size = p["query"], p["size"]
-                    pf = p["pf"]
                     knn_clamp = None
                     if p["knn"] is not None:
                         from ..query.dsl import parse_knn
@@ -1838,11 +1801,10 @@ class EsIndex:
                                           minimum_should_match=1))
                         size = min(size, max(k_total - p["from_"], 0))
                         knn_clamp = k_total
-                        pf = None
                     generic_ix.append(i)
                     generic_reqs.append(dict(
                         query=query, size=size, from_=p["from_"],
-                        aggs=aggs, mappings=None, prune_floor=pf))
+                        aggs=aggs, mappings=None))
                     job["fmt"][i] = {**p, "aggs_request": aggs_request,
                                      "had_pipeline": had_pipeline,
                                      "knn_clamp": knn_clamp,
@@ -1980,7 +1942,7 @@ class EsIndex:
                                     from_=p["from_"], aggs=p["eff_aggs"])
                             res.total = min(res.total, p["knn_clamp"])
                         job["slots"][i] = ("resp", self._format_generic_hits(
-                            res, p["tth"], p["pf"],
+                            res, p["tth"],
                             p.get("aggs_request"), p.get("had_pipeline"),
                         ))
                     except Exception as ex:  # noqa: BLE001
@@ -2042,7 +2004,7 @@ class EsIndex:
                     p = job["fmt"][i]
                     try:
                         job["slots"][i] = ("resp", self._tiered_merge(
-                            rb, rts, p["size"], p["from_"], p["pf"],
+                            rb, rts, p["size"], p["from_"],
                             p["tth"], t["tail_shard_docs"]))
                     except Exception as ex:  # noqa: BLE001
                         job["slots"][i] = ("error", ex)

@@ -697,8 +697,6 @@ KERNEL_COSTS: dict[str, object] = {
     # single combined fetch — both collective entries with ici_util
     "sharded.fused_allgather_topk": _fused_sharded_allgather,
     "serving.wave_program": _serving_wave,
-    "sharded.wand_pass1": None,      # pruned postings subset: rows unknown
-    "sharded.wand_pass2": None,      #   until finalize — wall time only
     # impact-scored sparse tier (BM25S, PR 8)
     "sparse.impact_gather": _impact_gather,
     "sparse.impact_sum": _impact_sum,

@@ -103,12 +103,6 @@ XLA_CHECKS: dict[str, dict] = {
     # PR 17: the tenant-gather body is batched.disjunction over
     # lane-indexed gathers; same sort/cumsum machinery, same cost shape
     "superpack.tenant_gather": {"status": "checked"},
-    "sharded.wand_pass1": {"status": "exempt",
-                           "reason": "experimental flag, wall-time-only "
-                                     "accounting (no cost entry)"},
-    "sharded.wand_pass2": {"status": "exempt",
-                           "reason": "experimental flag, wall-time-only "
-                                     "accounting (no cost entry)"},
     "sparse.tail_scan": {
         "status": "exempt",
         "reason": "tail-tier scan dispatched inside the engine's tiered "

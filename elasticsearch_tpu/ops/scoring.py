@@ -134,9 +134,7 @@ def score_posting_arrays(
     b: float = 0.75,
     has_norms: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Score explicit posting arrays (the tail of term_score_blocks; also
-    the execution form of WAND-pruned synthetic blocks, where surviving
-    postings were compacted host-side — query/wand.prune_postings)."""
+    """Score explicit posting arrays (the tail of term_score_blocks)."""
     if has_norms:
         denom = tfs + k1 * (1.0 - b + b * dls / avgdl)
     else:

@@ -34,18 +34,6 @@ from .param_pack import pack, packed_counts, unpack
 from .stacked import StackedPack
 
 
-def wand_gate_min_rows() -> int:
-    """Resolved WAND profitability gate: minimum total CSR block rows for
-    the two-pass pruned plan to engage. The single source of truth —
-    bench.py's crossover reporting reads THIS, so a retuned default can
-    never desynchronize the bench from production. Derivation: the
-    exhaustive batched kernel clears ~1-2G postings/s while the pruned
-    plan pays an extra device round trip + host posting prune, so pruning
-    pays only once a query's CSR postings are of order 10^7 (~10^5 block
-    rows) — see BENCH_NOTES.md C2."""
-    return int(os.environ.get("ES_TPU_WAND_MIN_ROWS", 100_000))
-
-
 import functools
 
 
@@ -183,9 +171,6 @@ class StackedResult:
     total: int
     max_score: float | None
     aggregations: dict | None = None
-    # "eq" for exhaustive runs; "gte" when block-max pruning made the
-    # total a lower bound (reference: hits.total.relation)
-    total_relation: str = "eq"
 
 
 def _copy_stacked_result(res: StackedResult) -> StackedResult:
@@ -194,15 +179,10 @@ def _copy_stacked_result(res: StackedResult) -> StackedResult:
     cached original must never be handed out by reference."""
     import copy as _copy
 
-    out = StackedResult(
+    return StackedResult(
         res.doc_shards.copy(), res.doc_ids.copy(), res.scores.copy(),
         res.total, res.max_score, _copy.deepcopy(res.aggregations),
-        res.total_relation,
     )
-    ws = getattr(res, "wand_stats", None)
-    if ws is not None:
-        out.wand_stats = dict(ws)
-    return out
 
 
 def _stacked_result_nbytes(res: StackedResult) -> int:
@@ -769,330 +749,6 @@ class StackedSearcher:
         )
         return s, ok
 
-    # -- block-max WAND ----------------------------------------------------
-
-    def search_wand(self, node, size: int, from_: int,
-                    floor: int = 0) -> StackedResult | None:
-        """Two-pass block-max pruned disjunction search; None when the query
-        shape doesn't qualify or pruning wouldn't reduce work. The returned
-        total is a LOWER bound (total_relation == "gte").
-
-        See query/wand.py for the plan and the soundness argument
-        (reference: Lucene block-max WAND via
-        search/query/QueryPhaseCollectorManager.java:416; SURVEY §7 hard
-        part #2 — skipping becomes block filtering)."""
-        out = self.search_wand_batch([dict(node=node, size=size,
-                                           from_=from_, floor=floor)])
-        return out[0]
-
-    def search_wand_batch(self, requests: list[dict]) -> list:
-        """Batched two-pass WAND: every request's pass-1 program launches
-        before any θ is fetched, host pruning runs for the whole batch,
-        then every pass-2 program launches before any result is fetched —
-        two device round trips TOTAL for the batch instead of two per
-        query. The plan overhead that round 3 measured as a net slowdown
-        at single-query scale (BENCH_NOTES.md C2) amortizes exactly like
-        the `_msearch` and agg batch paths. Entries that don't qualify
-        (shape, floor, nothing pruned) come back as None; callers run
-        those exhaustively (search_batch pipelines them the same way)."""
-        states = [
-            self._wand_plan(r["node"], r.get("size", 10),
-                            r.get("from_", 0), r.get("floor", 0))
-            for r in requests
-        ]
-        from ..telemetry import time_kernel
-
-        live = [s for s in states if s is not None]
-        if live:
-            with time_kernel("sharded.wand_pass1", tier="wand",
-                             requests=len(live)):
-                host1 = jax.device_get([s["outs1"] for s in live])
-            for s, h in zip(live, host1):
-                s["host1"] = h
-        wave2 = [s for s in live if self._wand_dispatch2(s)]
-        if wave2:
-            with time_kernel("sharded.wand_pass2", tier="wand",
-                             requests=len(wave2)):
-                host2 = jax.device_get([s["outs2"] for s in wave2])
-            for s, h in zip(wave2, host2):
-                s["host2"] = h
-        return [
-            self._wand_finalize(s) if s is not None and "host2" in s
-            else None
-            for s in states
-        ]
-
-    def search_pruned_batch(self, requests: list[dict]) -> list:
-        """Gate-then-fallback pruned search, batched: block-max WAND for
-        every request the profitability gate accepts, exhaustive execution
-        for the rest — one batched wave each, so a request never costs
-        more than its exhaustive execution plus the (amortized) gate
-        check. Semantically this is `search(prune_floor=...)`'s
-        gate+fallback decision applied to a whole batch; the engine's
-        serving path still runs that decision per query (engine.py
-        `search`), while bench.py times THIS batched form so a
-        non-engaging batch measures as ~the exhaustive batch, never as a
-        no-op (VERDICT r4 weak #2).
-
-        Each request dict: node (QueryNode), size, from_, floor.
-        Returns StackedResults; each carries `.wand_engaged`."""
-        pruned = self.search_wand_batch(requests)
-        fb_idx = [i for i, r in enumerate(pruned) if r is None]
-        if fb_idx:
-            fb = self.search_batch([
-                dict(query=requests[i]["node"],
-                     size=requests[i].get("size", 10),
-                     from_=requests[i].get("from_", 0))
-                for i in fb_idx
-            ])
-            for i, r in zip(fb_idx, fb):
-                pruned[i] = r
-        fb_set = set(fb_idx)
-        for i, r in enumerate(pruned):
-            r.wand_engaged = i not in fb_set
-        return pruned
-
-    def _wand_plan(self, node, size: int, from_: int,
-                   floor: int = 0) -> dict | None:
-        """Host planning + pass-1 launch (no fetch); None = not eligible."""
-        from ..index.pack import BM25_K1, BM25_B
-        from ..query import wand
-
-        if (self.ctx.k1, self.ctx.b) != (BM25_K1, BM25_B):
-            return None
-        terms = wand.should_terms(node)
-        if terms is None:
-            return None
-        if floor:
-            # exact counting promised up to `floor` hits: prune only when
-            # the true total provably reaches it. df counts postings at pack
-            # build; docs deleted in place since (tiered refresh) may be
-            # among them, so the proven bound is max df - dead docs.
-            dead = getattr(self.sp, "dead_count", 0)
-            if max(self.sp.eff_global_df.get((t.fld, t.term), 0)
-                   for t in terms) - dead < floor:
-                return None
-        S = self.sp.S
-        n = self.sp.n_max
-        if n == 0:
-            return None
-        k = min(max(size + from_, 1), max(n * S, 1))
-        views = [self.sp.shard_view(s) for s in range(S)]
-
-        # ---- host planning: per-term/per-shard sorted block upper bounds.
-        # All weight-free pieces (ubf order, window maxima) are cached on the
-        # pack per (shard, term), so a repeated query's host planning is a
-        # couple of dict hits + scalar scaling.
-        PASS1_ROWS = 4  # blocks/term/shard scored to seed θ (512 postings)
-        ubf_cache = getattr(self.sp, "_wand_ubf", None)
-        if ubf_cache is None:
-            ubf_cache = self.sp._wand_ubf = {}
-        infos = []  # per term: dict(weight, dense_row, rows[s], ubs[s])
-        csr_rows_total = 0
-        for t in terms:
-            params0, _key0 = t.prepare(views[0])  # sets t._dense; global weight
-            weight = float(params0[1])
-            avgdl = float(params0[2])
-            if t._dense:
-                infos.append({"dense": int(params0[0]), "weight": weight,
-                              "avgdl": avgdl})
-                continue
-            rows_s, ubs_s, wub_s = [], [], []
-            has_norms = t.fld in self.ctx.has_norms
-            for s in range(S):
-                p = self.sp.shards[s]
-                nw = wand.windows_for(p.num_docs)
-                ck = (s, t.fld, t.term, round(avgdl, 9), p.num_docs)
-                got = ubf_cache.get(ck)
-                if got is None:
-                    start, count, _df = p.term_blocks(t.fld, t.term)
-                    r, u = wand.term_row_ubf(
-                        p, start, count, avgdl, has_norms,
-                        self.ctx.k1, self.ctx.b,
-                    )
-                    wu = wand.window_ub_csr(p, r, u, p.num_docs, nw)
-                    got = ubf_cache[ck] = (r, u, wu)
-                r, u, wu = got
-                rows_s.append(r)
-                ubs_s.append(weight * u)
-                wub_s.append(weight * wu)
-                csr_rows_total += len(r)
-            infos.append({"dense": None, "weight": weight, "avgdl": avgdl,
-                          "rows": rows_s, "ubs": ubs_s, "win": wub_s})
-        n_csr = sum(1 for i in infos if i["dense"] is None)
-        min_rows = getattr(self, "wand_min_rows", None)
-        if min_rows is None:
-            # profitability gate (see wand_gate_min_rows): below it the
-            # plan is provably net negative at identical results
-            min_rows = wand_gate_min_rows()
-        if n_csr == 0 or csr_rows_total < min_rows:
-            return None  # too few blocks for pruning to pay for two launches
-
-        # per-shard, per-term window-localized upper bounds: win_ub[s][ti] is
-        # a [WINDOWS] array of the term's max block score per doc-id window
-        # (rare terms bound ~0 over most of doc space — the locality that
-        # makes block-max WAND prune; Lucene gets it from per-range maxes)
-        dense_win = getattr(self.sp, "_dense_win_tfn", None)
-        if dense_win is None:
-            dense_win = self.sp._dense_win_tfn = {}
-        win_ub = [[None] * len(infos) for _ in range(S)]
-        for ti, info in enumerate(infos):
-            for s in range(S):
-                if info["dense"] is not None:
-                    nd = self.sp.shards[s].num_docs
-                    nw = wand.windows_for(nd)
-                    dk = (s, info["dense"], round(info["avgdl"], 9), nd)
-                    got = dense_win.get(dk)
-                    if got is None:
-                        got = wand.window_tfn_dense(
-                            self.sp.dense_tfn_host(info["dense"], s,
-                                                   info["avgdl"]), nd, nw)
-                        dense_win[dk] = got
-                    win_ub[s][ti] = info["weight"] * got
-                else:
-                    win_ub[s][ti] = info["win"][s]
-
-        def synth(row_lists, inline_lists=None):
-            """params + struct keys for the disjunction with each CSR term's
-            block rows replaced by row_lists[t][s] (bucketed to a common
-            width across shards), or — when inline_lists[t] is set — by
-            synthetic posting arrays (docids, tfs, dls) per shard (the
-            doc-level pruned form; TermNode 5-tuple params)."""
-            per_shard_params, term_keys = [], []
-            widths = {}
-            for ti, info in enumerate(infos):
-                if info["dense"] is not None:
-                    continue
-                if inline_lists is not None and inline_lists[ti] is not None:
-                    widths[ti] = wand.bucket_width(max(
-                        inline_lists[ti][s][0].shape[0] for s in range(S)))
-                else:
-                    widths[ti] = wand.bucket_width(max(
-                        len(row_lists[ti][s]) for s in range(S)))
-            for s in range(S):
-                sp_params = []
-                for ti, (t, info) in enumerate(zip(terms, infos)):
-                    w = np.float32(info["weight"])
-                    ad = np.float32(info["avgdl"])
-                    if info["dense"] is not None:
-                        sp_params.append((np.int32(info["dense"]), w, ad))
-                        if s == 0:
-                            term_keys.append(("term_dense", t.fld))
-                    elif inline_lists is not None and inline_lists[ti] is not None:
-                        d_, t_, l_ = inline_lists[ti][s]
-                        wd = widths[ti]
-                        nd = self.sp.shards[s].num_docs
-                        pad = wd - d_.shape[0]
-                        if pad:
-                            d_ = np.concatenate(
-                                [d_, np.full((pad, d_.shape[1]), nd, np.int32)])
-                            t_ = np.concatenate(
-                                [t_, np.zeros((pad, t_.shape[1]), np.float32)])
-                            l_ = np.concatenate(
-                                [l_, np.ones((pad, l_.shape[1]), np.float32)])
-                        sp_params.append((d_, t_, l_, w, ad))
-                        if s == 0:
-                            term_keys.append(("term_inline", t.fld, wd))
-                    else:
-                        sp_params.append(
-                            (wand.pad_rows_to(row_lists[ti][s], widths[ti]),
-                             w, ad))
-                        if s == 0:
-                            term_keys.append(("term", t.fld, widths[ti]))
-                per_shard_params.append(
-                    ((), (), tuple(sp_params), ()))
-            key = ("bool", ((), (), tuple(term_keys), ()), node._msm())
-            params = _stack_shard_params(
-                [(p, np.float32(node.boost)) for p in per_shard_params])
-            return params, tuple(key for _ in range(S))
-
-        # ---- pass 1: seed θ from each term's best blocks (launch only)
-        p1_rows = [
-            [i["rows"][s][: min(PASS1_ROWS, len(i["rows"][s]))] for s in range(S)]
-            if i["dense"] is None else None
-            for i in infos
-        ]
-        params1, keys1 = synth(p1_rows)
-        fn1, buffers1 = self._packed_program(
-            node, ("wand1", keys1), k, None, (), params1, {})
-        return {
-            "node": node, "terms": terms, "infos": infos, "win_ub": win_ub,
-            "synth": synth, "k": k, "size": size, "from_": from_,
-            "outs1": self._launch(fn1, buffers1),
-        }
-
-    def _wand_dispatch2(self, st) -> bool:
-        """Host doc-level prune from θ + pass-2 launch; False when pruning
-        bought nothing (caller falls back to the exhaustive plan)."""
-        from ..query import wand
-
-        node, terms, infos = st["node"], st["terms"], st["infos"]
-        win_ub, k = st["win_ub"], st["k"]
-        S = self.sp.S
-        g_scores1, _gs1, _gd1, _tot1, _ = st["host1"]
-        valid1 = np.isfinite(g_scores1)
-        theta = float(g_scores1[k - 1]) if valid1.sum() >= k else -np.inf
-
-        # doc-level pruning — drop every posting whose exact self score +
-        # other-terms' window bound cannot reach θ, compact survivors into
-        # synthetic blocks (query/wand.prune_postings)
-        p2_inline = []
-        kept = dropped = 0
-        boost = float(node.boost)
-        has_norms_of = {t.fld: t.fld in self.ctx.has_norms for t in terms}
-        for ti, (t, info) in enumerate(zip(terms, infos)):
-            if info["dense"] is not None:
-                p2_inline.append(None)
-                continue
-            arrs_s = []
-            for s in range(S):
-                p = self.sp.shards[s]
-                nd = p.num_docs
-                nw = wand.windows_for(nd)
-                # Σ of the OTHER terms' window bounds at each window
-                other = np.sum(
-                    [win_ub[s][tj] for tj in range(len(infos)) if tj != ti],
-                    axis=0, dtype=np.float32)
-                d_, t_, l_, kp, tot = wand.prune_postings(
-                    p, nd, info["rows"][s], info["weight"] * boost,
-                    info["avgdl"], has_norms_of[t.fld],
-                    self.ctx.k1, self.ctx.b,
-                    other * boost, theta, nw)
-                arrs_s.append((d_, t_, l_))
-                kept += kp
-                dropped += tot - kp
-            p2_inline.append(arrs_s)
-        if dropped == 0:
-            return False  # pruning bought nothing; use the exhaustive plan
-        params2, keys2 = st["synth"](None, p2_inline)
-        fn2, buffers2 = self._packed_program(
-            node, ("wand2", keys2), k, None, (), params2, {})
-        st.update(theta=theta, kept=kept, dropped=dropped,
-                  outs2=self._launch(fn2, buffers2))
-        return True
-
-    def _wand_finalize(self, st) -> "StackedResult":
-        g_scores, g_shard, g_doc, total, _ = st["host2"]
-        size, from_ = st["size"], st["from_"]
-        valid = np.isfinite(g_scores)
-        max_score = float(g_scores[0]) if valid.any() else None
-        end = max(size + from_, 0)
-        out = StackedResult(
-            g_shard[valid][from_:end].astype(np.int32),
-            g_doc[valid][from_:end].astype(np.int32),
-            g_scores[valid][from_:end].astype(np.float32),
-            int(total),
-            max_score,
-            None,
-        )
-        out.total_relation = "gte"
-        # kept/dropped count POSTINGS since the round-3 doc-level pruning
-        # (block-level pruning cannot help mid-frequency disjunctions)
-        out.wand_stats = {"rows_kept": st["kept"],
-                          "rows_pruned": st["dropped"],
-                          "theta": st["theta"]}
-        return out
-
     def search(
         self,
         query: dict | QueryNode | None,
@@ -1100,13 +756,8 @@ class StackedSearcher:
         from_: int = 0,
         aggs: dict | None = None,
         mappings=None,
-        prune_floor: int | None = None,
     ) -> StackedResult:
-        """prune_floor: None = exact (no block-max pruning); 0 = prune freely
-        (track_total_hits=false); N > 0 = prune only when the total provably
-        reaches N (the track_total_hits threshold contract).
-
-        Plain-DSL requests are served from the shard request cache when
+        """Plain-DSL requests are served from the shard request cache when
         warm (whole-searcher scope: the merged result depends on every
         shard, so any shard's epoch bump invalidates it); QueryNode
         requests and per-request mapping overrides bypass the cache."""
@@ -1115,7 +766,7 @@ class StackedSearcher:
         rc = request_cache()
         ck = scope = None
         if rc.enabled and mappings is None and not isinstance(query, QueryNode):
-            ck = self._request_cache_key(query, size, from_, aggs, prune_floor)
+            ck = self._request_cache_key(query, size, from_, aggs)
             scope = self.cache_scope()
             hit = rc.get(scope[0], scope[1], ck)
             if hit is not None:
@@ -1133,8 +784,11 @@ class StackedSearcher:
         from ..telemetry import metrics as _metrics
 
         _t0 = _time.perf_counter()
-        res = self._search_uncached(query, size, from_, aggs, mappings,
-                                    prune_floor)
+        m = mappings if mappings is not None else self.sp.mappings
+        node, _ = self._parsed(query, m)
+        res = self.search_batch(
+            [dict(query=node, size=size, from_=from_, aggs=aggs, mappings=m)]
+        )[0]
         _elapsed_ms = (_time.perf_counter() - _t0) * 1000
         _metrics.histogram_record("es.shard.search.ms", _elapsed_ms)
         if ck is not None:
@@ -1142,34 +796,20 @@ class StackedSearcher:
                    _stacked_result_nbytes(res), recompute_ms=_elapsed_ms)
         return res
 
-    def _request_cache_key(self, query, size, from_, aggs, prune_floor):
+    def _request_cache_key(self, query, size, from_, aggs):
+        """A cached StackedResult's identity: what was asked of the device.
+        `track_total_hits` is not part of it: the count is always exact,
+        so every value maps to one identical result and the engine formats
+        `hits.total` from the request afterwards."""
         from ..cache import canonical_key
 
         return canonical_key({
             "op": "stacked_search", "query": query, "aggs": aggs,
             "size": int(size), "from": int(from_),
-            "prune_floor": prune_floor,
             # query-time analyzers (synonym-set reloads) change parsed
             # queries without any index write — part of the identity
             "ag": getattr(self.sp.mappings, "analysis_generation", 0),
         })
-
-    def _search_uncached(self, query, size, from_, aggs, mappings,
-                         prune_floor) -> StackedResult:
-        from ..query.wand import wand_enabled
-
-        m = mappings if mappings is not None else self.sp.mappings
-        node, _ = self._parsed(query, m)
-        if prune_floor is not None and not aggs and wand_enabled():
-            # experimental (ES_TPU_WAND=1): six measured rounds say the
-            # batched exhaustive/impact kernels dominate the two-pass
-            # pruned plan on this hardware — see query/wand.py
-            res = self.search_wand(node, size, from_, floor=prune_floor)
-            if res is not None:
-                return res
-        return self.search_batch(
-            [dict(query=node, size=size, from_=from_, aggs=aggs, mappings=m)]
-        )[0]
 
     # -- serving waves -----------------------------------------------------
 
@@ -1179,14 +819,14 @@ class StackedSearcher:
         completer thread can pull the device outputs (`search_many_fetch`,
         engine-state-free) while the engine thread plans the next wave.
 
-        Each request dict: query, size, from_, aggs, mappings,
-        prune_floor — the `search()` keyword surface. Per-request results
-        are byte-identical to solo `search()` calls: the cache lookup,
-        WAND gate and per-request compiled program are the same code, and
-        every request's program is independent of its wave-mates (the
-        wave only shares the dispatch+fetch round trip, exactly like
-        `search_batch`). A request that raises during planning carries
-        its exception in the state and re-raises at finish."""
+        Each request dict: query, size, from_, aggs, mappings — the
+        `search()` keyword surface. Per-request results are byte-identical
+        to solo `search()` calls: the cache lookup and per-request
+        compiled program are the same code, and every request's program
+        is independent of its wave-mates (the wave only shares the
+        dispatch+fetch round trip, exactly like `search_batch`). A request
+        that raises during planning carries its exception in the state
+        and re-raises at finish."""
         import time as _time
 
         from ..cache import request_cache
@@ -1205,13 +845,11 @@ class StackedSearcher:
             from_ = r.get("from_", 0)
             aggs = r.get("aggs")
             mappings = r.get("mappings")
-            prune_floor = r.get("prune_floor")
             try:
                 ck = scope = None
                 if (rc.enabled and mappings is None
                         and not isinstance(query, QueryNode)):
-                    ck = self._request_cache_key(query, size, from_, aggs,
-                                                 prune_floor)
+                    ck = self._request_cache_key(query, size, from_, aggs)
                     scope = self.cache_scope()
                     got = rc.get(scope[0], scope[1], ck)
                     if got is not None:
@@ -1221,22 +859,6 @@ class StackedSearcher:
                     misses += 1
                 m = mappings if mappings is not None else self.sp.mappings
                 node, _ = self._parsed(query, m)
-                if prune_floor is not None and not aggs:
-                    from ..query.wand import wand_enabled
-
-                    # experimental flag (ES_TPU_WAND): the two-pass WAND
-                    # plan lost every measured round to the batched
-                    # exhaustive/impact kernels (r05 sweep engaged
-                    # nowhere; r08 verdict vs the impact tier) — off by
-                    # default, the batched wave below is the production
-                    # path for prune_floor requests
-                    res = (self.search_wand(node, size, from_,
-                                            floor=prune_floor)
-                           if wand_enabled() else None)
-                    if res is not None:
-                        st["results"][i] = res
-                        st["cache_slots"][i] = (ck, scope)
-                        continue
                 st["states"][i] = self._agg_dispatch(
                     query=node, size=size, from_=from_, aggs=aggs,
                     mappings=m)
@@ -1307,13 +929,11 @@ class StackedSearcher:
                 out.append(st["errors"][i])
                 continue
             res = st["results"][i] if s is None else self._agg_finalize(s)
-            slot = st["cache_slots"][i]
-            if s is not None or (slot is not None and st["results"][i]
-                                 is not None):
-                # computed this wave (dispatched or WAND): store like solo
+            if s is not None:
+                # computed this wave: store like solo
                 _metrics.histogram_record("es.shard.search.ms", wave_ms)
-                if slot is not None and slot[0] is not None:
-                    ck, scope = slot
+                ck, scope = st["cache_slots"][i]
+                if ck is not None:
                     rc.put(scope[0], scope[1], ck,
                            _copy_stacked_result(res),
                            _stacked_result_nbytes(res))

@@ -452,22 +452,6 @@ class StackedPack:
         with ThreadPoolExecutor(default_shard_builders(self.S)) as ex:
             list(ex.map(fn, range(self.S)))
 
-    def dense_tfn_host(self, row: int, shard: int, avgdl: float,
-                       k1: float | None = None, b: float | None = None) -> np.ndarray:
-        """One dense row's tfn computed host-side with the CURRENT avgdl
-        (WAND planning bounds; the bulk tfn tier lives on device)."""
-        from ..index.pack import BM25_K1, BM25_B
-
-        k1 = BM25_K1 if k1 is None else k1
-        b = BM25_B if b is None else b
-        tf = self.dense_tf[shard, row]
-        fld = self.dense_fields[row]
-        if fld in self.norms:
-            K = k1 * (1.0 - b + b * self.norms[fld][shard] / max(avgdl, 1e-9))
-        else:
-            K = k1
-        return (tf / np.maximum(tf + K, 1e-9)).astype(np.float32)
-
     def impact_serving(self) -> bool:
         """True when the resident impact code blocks were derived from the
         CURRENT effective stats (StackedSearcher.refresh_impacts ran after

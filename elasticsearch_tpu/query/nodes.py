@@ -130,17 +130,6 @@ class TermNode(QueryNode):
 
             dr, weight, _avgdl = params
             return dense_term_scores(dev["dense_tfn"][dr], weight, ctx.num_docs)
-        if len(params) == 5:
-            # inline postings: WAND doc-level pruning compacts survivors
-            # host-side into synthetic blocks (query/wand.prune_postings)
-            from ..ops.scoring import score_posting_arrays
-
-            docids, tfs, dls, weight, avgdl = params
-            return score_posting_arrays(
-                docids, tfs, dls, weight, avgdl, ctx.num_docs,
-                ctx.k1, ctx.b,
-                has_norms=self.fld in ctx.has_norms,
-            )
         if len(params) == 4:
             rows, weight, avgdl, wscale = params
             from ..index.pack import BM25_B, BM25_K1

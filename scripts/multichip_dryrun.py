@@ -1,7 +1,7 @@
 """Multichip dry run + pjit parity gate (PR 10, CI satellite).
 
 Runs `__graft_entry__.dryrun_multichip` — the production sharded stack
-(bool/WAND/aggs/knn + batched msearch) on a device mesh with parity
+(bool/aggs/knn + batched msearch) on a device mesh with parity
 asserted against single-device AND the shard_map fallback — and exits
 nonzero on any divergence.
 

@@ -212,7 +212,7 @@ def _planner_cold():
 
 @pytest.fixture(autouse=True)
 def _env_hermetic():
-    """Behavior-steering env vars (fused/pallas/wand/wire toggles) must
+    """Behavior-steering env vars (fused/pallas/wire toggles) must
     never leak across tests: snapshot at test start, restore at test end.
     Module-scoped overrides (e.g. test_fused's ES_TPU_FUSED=force) are
     unaffected — they are set before the snapshot and dropped by their

@@ -449,7 +449,7 @@ def test_unmodeled_kernel_still_times():
         collect_profile_events, time_kernel)
 
     with collect_profile_events() as events:
-        with time_kernel("sharded.wand_pass1", requests=2):
+        with time_kernel("fused.msearch", requests=2):
             pass
     (e,) = events
     assert "mfu" not in e and e["ms"] >= 0  # wall time only, no fake MFU

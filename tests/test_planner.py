@@ -116,8 +116,8 @@ def test_observe_wall_warms_model_from_wave_attribution():
     assert pl.stats()["decision_modes"]["model"] == 1
     # non-positive walls and cost-model-less kernels are ignored
     pl.observe_wall("batched.disjunction", CANDS[2][2], 0.0)
-    pl.observe_wall("sharded.wand_pass1", {"queries": 1}, 1e-3)
-    assert "sharded.wand_pass1" not in pl.stats()["kernels"]
+    pl.observe_wall("fused.msearch", {"queries": 1}, 1e-3)
+    assert "fused.msearch" not in pl.stats()["kernels"]
 
 
 def test_predict_ms_none_while_cold():

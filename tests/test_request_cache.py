@@ -346,6 +346,36 @@ def test_engine_refresh_and_delete_invalidate():
     eng.delete_index("rc_idx")
 
 
+def test_track_total_hits_shares_one_cache_entry():
+    """`track_total_hits` formats the response and is no part of what was
+    asked of the device: true and false are one entry, and each response
+    is built by its own value."""
+    from elasticsearch_tpu.engine.engine import Engine
+
+    eng = Engine()
+    rc = eng.request_cache
+    idx = eng.create_index(
+        "rc_tth", mappings={"properties": {"body": {"type": "text"}}})
+    for i in range(24):
+        idx.index_doc(f"d{i}", {"body": f"alpha t{i % 5} beta"})
+    idx.refresh()
+    tiers = len(idx.tier_searchers())  # each tier keeps its own entry
+    q = {"match": {"body": "alpha t3"}}
+    st0 = rc.stats()
+    counted = idx.search(query=q, size=6, track_total_hits=True)
+    st1 = rc.stats()
+    uncounted = idx.search(query=q, size=6, track_total_hits=False)
+    st2 = rc.stats()
+    assert (st1["miss_count"] - st0["miss_count"],
+            st1["hit_count"] - st0["hit_count"]) == (tiers, 0)
+    assert (st2["miss_count"] - st1["miss_count"],
+            st2["hit_count"] - st1["hit_count"]) == (0, tiers)
+    assert counted["hits"]["total"] == {"value": 24, "relation": "eq"}
+    assert "total" not in uncounted["hits"]
+    assert counted["hits"]["hits"] == uncounted["hits"]["hits"]
+    eng.delete_index("rc_tth")
+
+
 def test_engine_dynamic_cache_settings():
     from elasticsearch_tpu.engine.engine import Engine
 
