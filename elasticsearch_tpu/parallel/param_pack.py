@@ -1,4 +1,4 @@
-"""One host-to-device transfer a dispatch.
+"""One host-to-device transfer a dispatch, one device-to-host transfer a fetch.
 
 A plan's parameters are a pytree of small host arrays, `[S, ...]` each after
 `_stack_shard_params`, and a jitted program ships every leaf it reads as a
@@ -13,6 +13,13 @@ other dtype (int64 under x64, bool) gets one buffer of its own dtype. A leaf
 that is already a `jax.Array` passes through beside the buffers. The layout
 is a hashable tuple and belongs to the identity of the program that unpacks
 by it.
+
+The way back is the mirror. A program's result is a tree of small device
+arrays, and `jax.device_get` waits for a copy of each. `pack_outputs`, at the
+end of the traced program, lays them side by side in one flat buffer per dtype
+class by the same rule; `unpack_host` cuts the fetched buffers back into the
+tree by NumPy views, bit for bit. This layout is known once the program has
+been traced, and stays with it.
 """
 
 from __future__ import annotations
@@ -20,6 +27,7 @@ from __future__ import annotations
 import math
 
 import jax
+import jax.numpy as jnp
 import jax.tree_util as jtu
 import numpy as np
 
@@ -89,4 +97,39 @@ def unpack(buffers, layout):
             if dtype != _WORD and _buffer_dtype(dtype) == _WORD:
                 x = jax.lax.bitcast_convert_type(x, dtype)
         leaves.append(x)
+    return jtu.tree_unflatten(treedef, leaves)
+
+
+def pack_outputs(tree):
+    """-> (buffers, layout), traceable. `buffers`: one flat device array per
+    dtype class, in order of first use, every leaf raveled into it (a float32
+    as its bits; a scalar is one element wide). `layout`: as `pack` gives it,
+    the offsets and shapes those of whole leaves. A tree without leaves gives
+    no buffer."""
+    leaves, treedef = jtu.tree_flatten(tree)
+    slots: dict = {}      # buffer dtype -> [index, flat pieces, next offset]
+    entries = []
+    for x in leaves:
+        x = jnp.asarray(x)
+        dtype = np.dtype(x.dtype)
+        bdt = _buffer_dtype(dtype)
+        slot = slots.setdefault(bdt, [len(slots), [], 0])
+        flat = x.reshape(-1)
+        slot[1].append(flat if dtype == bdt
+                       else jax.lax.bitcast_convert_type(flat, bdt))
+        entries.append((slot[0], slot[2], x.shape, dtype))
+        slot[2] += x.size
+    buffers = tuple(p[0] if len(p) == 1 else jnp.concatenate(p)
+                    for _, p, _ in slots.values())
+    return buffers, (treedef, tuple(entries))
+
+
+def unpack_host(buffers, layout):
+    """The tree `pack_outputs` was given, from its buffers once they are host
+    arrays: a slice, a view and a reshape a leaf, no copy."""
+    treedef, entries = layout
+    leaves = []
+    for buf, off, shape, dtype in entries:
+        x = buffers[buf][off:off + math.prod(shape)]
+        leaves.append((x if x.dtype == dtype else x.view(dtype)).reshape(shape))
     return jtu.tree_unflatten(treedef, leaves)

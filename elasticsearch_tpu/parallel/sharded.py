@@ -30,7 +30,8 @@ from ..query.dsl import parse_query
 from ..utils.jax_env import shard_map
 from ..utils.errors import IllegalArgumentError
 from ..query.nodes import ExecContext, QueryNode
-from .param_pack import pack, packed_counts, unpack
+from .param_pack import (pack, pack_outputs, packed_counts, unpack,
+                         unpack_host)
 from .stacked import StackedPack
 
 
@@ -383,7 +384,8 @@ class StackedSearcher:
     def _compiled(self, node, key, k, agg_nodes, agg_key, layout):
         """The program of one plan shape. It takes the request's parameters
         packed (`param_pack.pack`) and unpacks them by `layout`, which is
-        therefore part of its identity."""
+        therefore part of its identity. It hands its result tree back packed
+        too (`param_pack.pack_outputs`): `_fetched` rebuilds the tree."""
         from ..monitoring.device import note_executable_cache
 
         cache_key = (key, k, agg_key, self._exec, layout)
@@ -451,13 +453,22 @@ class StackedSearcher:
                 g_scores, g_idx = jax.lax.top_k(flat, k_global)
                 g_shard = (g_idx // k_local).astype(jnp.int32)
                 g_doc = flat_i[g_idx]
-            return g_scores, g_shard, g_doc, tot.sum(), agg_out
+            # one device array a dtype class for the fetch to wait for,
+            # replicated on a mesh so that the host pulls from one device.
+            # The shards' counts are summed in their own width (x64 would
+            # widen the sum, and S * n_max documents are far below 2**31),
+            # so a plain search's whole result is one buffer of words
+            outs, fn.out_layout = pack_outputs(
+                (g_scores, g_shard, g_doc, tot.sum(dtype=jnp.int32), agg_out))
+            return tuple(constrain(b, self.mesh, P()) for b in outs)
 
         # named for what it is: one compiled program per plan shape, all of
         # one family in a capture's `XLA Modules` line
         fn = jax.jit(search_solo)
         # host arrays a call of it is handed, and the leaves packed in them
         fn.packed = packed_counts(layout)
+        # how its outputs are packed: known once it has been traced
+        fn.out_layout = None
         self._cache[cache_key] = fn
         return fn
 
@@ -908,7 +919,7 @@ class StackedSearcher:
         wave2 = []
         for i, s in enumerate(st["states"]):
             if s is not None:
-                s["host"] = next(host)
+                s["host"] = self._fetched(next(host), s["out_layout"])
                 if self._agg_pass2_dispatch(s):
                     wave2.append(s)
         if wave2:
@@ -916,7 +927,7 @@ class StackedSearcher:
             # recorded so the wave's host-transition meta stays honest
             host2 = jax.device_get([s["outs2"] for s in wave2])
             for s, h2 in zip(wave2, host2):
-                s["host2"] = h2
+                s["host2"] = self._fetched(h2, s["out_layout2"])
             st["extra_dispatches"] = st.get("extra_dispatches", 0) + 1
             st["extra_fetches"] = st.get("extra_fetches", 0) + 1
         from ..telemetry import metrics as _metrics
@@ -972,13 +983,13 @@ class StackedSearcher:
                 host = jax.device_get([s["outs"] for s in states])
         wave2 = []
         for s, ho in zip(states, host):
-            s["host"] = ho
+            s["host"] = self._fetched(ho, s["out_layout"])
             if self._agg_pass2_dispatch(s):
                 wave2.append(s)
         if wave2:
             host2 = jax.device_get([s["outs2"] for s in wave2])
             for s, h2 in zip(wave2, host2):
-                s["host2"] = h2
+                s["host2"] = self._fetched(h2, s["out_layout2"])
         return [self._agg_finalize(s) for s in states]
 
     def _parsed(self, query, m, aggs=None):
@@ -1044,12 +1055,12 @@ class StackedSearcher:
         # lower and compile
         with TRACER.span("engine.dispatch",
                          **({} if hit else {"compiled": True})):
-            outs = self._launch(fn, buffers)
+            outs, out_layout = self._launch(fn, buffers)
         return {
             "node": node, "keys": tuple(keys), "k": k, "size": size,
             "from_": from_, "agg_nodes": agg_nodes, "agg_key": agg_key,
             "params": params, "agg_params": agg_params,
-            "outs": outs,
+            "outs": outs, "out_layout": out_layout,
         }
 
     def _packed_program(self, node, key, k, agg_nodes, agg_key, params,
@@ -1061,13 +1072,26 @@ class StackedSearcher:
                 buffers)
 
     def _launch(self, fn, buffers):
-        """Call a `_compiled` program on packed parameters, counted."""
+        """Call a `_compiled` program on packed parameters, counted.
+        -> (its packed outputs, still on the device; the layout that
+        `_fetched` unpacks them by)."""
         from ..telemetry import metrics
 
         n_buffers, n_leaves = fn.packed
         metrics.counter_inc("es.search.dispatch.buffers", n_buffers)
         metrics.counter_inc("es.search.dispatch.leaves", n_leaves)
-        return fn(self.dev, buffers)
+        outs = fn(self.dev, buffers)
+        return outs, fn.out_layout
+
+    def _fetched(self, buffers, layout):
+        """The `(g_scores, g_shard, g_doc, total, agg_out)` that a `_compiled`
+        program computed, from its fetched buffers; counted as `_launch`
+        counts the way in."""
+        from ..telemetry import metrics
+
+        metrics.counter_inc("es.search.fetch.buffers", len(buffers))
+        metrics.counter_inc("es.search.fetch.leaves", len(layout[1]))
+        return unpack_host(buffers, layout)
 
     def _agg_pass2_dispatch(self, s) -> bool:
         """Launch pass 2 (two-pass terms candidates) if the request needs
@@ -1100,7 +1124,7 @@ class StackedSearcher:
             (s["agg_key"], "tp2",
              tuple(sorted((n, a._C) for n, a in tp.items()))),
             s["params"], agg_params)
-        s["outs2"] = self._launch(fn2, buffers)
+        s["outs2"], s["out_layout2"] = self._launch(fn2, buffers)
         return True
 
     def _agg_finalize(self, s) -> StackedResult:

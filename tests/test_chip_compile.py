@@ -108,6 +108,24 @@ def test_fused_tile_candidates_compiles(one_chip, no_persistent_cache, bud):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_one_replicated_buffer_of_words(compiled, words):
+    """PR 32: the program hands its whole result back as ONE `int32[words]`
+    (on a mesh every chip holds it), and the chip is asked for no 64-bit
+    bitcast on the way."""
+    import jax
+
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, np.dtype(o.dtype)) for o in outs] == [
+        ((words,), np.dtype(np.int32))]
+    assert all(s.is_fully_replicated for s in
+               jax.tree_util.tree_leaves(compiled.output_shardings))
+    text = compiled.as_text()
+    entry = [ln for ln in text.splitlines() if ln.startswith("ENTRY ")]
+    assert len(entry) == 1 and re.search(
+        rf"-> \(?s32\[{words}\]", entry[0]), entry
+    assert "s64" not in text and "f64" not in text
+
+
 def _assert_block_select_in(text, n):
     """The compiled program selects in two levels and calls no Mosaic
     kernel: one selection over the K chosen blocks, and no sort of a whole
@@ -171,7 +189,8 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
     from elasticsearch_tpu.ops.scoring import (dense_term_scores,
                                                impact_term_scores,
                                                top_k_with_total)
-    from elasticsearch_tpu.parallel.param_pack import pack, unpack
+    from elasticsearch_tpu.parallel.param_pack import (pack, pack_outputs,
+                                                       unpack)
 
     f32, i32 = np.float32, np.int32
     dense = (np.zeros((1,), i32), np.ones((1,), f32), np.ones((1,), f32))
@@ -187,8 +206,13 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
         return top_k_with_total(boost * (s1 + s2), m1 | m2, live, TOP_K)
 
     def solo(dense_tfn, codes, docids, live, buffers):
-        return jax.vmap(shard_body)(dense_tfn, codes, docids, live,
-                                    unpack(buffers, layout))
+        ts, ti, tot = jax.vmap(shard_body)(dense_tfn, codes, docids, live,
+                                           unpack(buffers, layout))
+        # PR 32: the merge's rows and the total leave as one buffer
+        g_scores, g_idx = jax.lax.top_k(ts.reshape(-1), TOP_K)
+        return pack_outputs((g_scores, (g_idx // TOP_K).astype(jnp.int32),
+                             ti.reshape(-1)[g_idx],
+                             tot.sum(dtype=jnp.int32), {}))[0]
 
     compiled = jax.jit(solo).lower(
         _sds((1, V_DENSE, N_DOCS), jnp.float32, one_chip),
@@ -197,10 +221,9 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
         _sds((1, N_DOCS), jnp.bool_, one_chip),
         tuple(_sds(b.shape, b.dtype, one_chip) for b in buffers),
     ).compile()
-    text = compiled.as_text()
-    _assert_block_select_in(text, N_DOCS)
-    # no 64-bit bitcast is ever asked of the chip
-    assert "s64" not in text and "f64" not in text
+    _assert_block_select_in(compiled.as_text(), N_DOCS)
+    # no 64-bit bitcast is ever asked of the chip, on the way in or out
+    _assert_one_replicated_buffer_of_words(compiled, 3 * TOP_K + 1)
 
 
 def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
@@ -212,7 +235,8 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
     `manual_shard_region`, where the shard's row is rank 1 and a `lax.top_k`
     over the whole of it would be a stable sort of 294,912 pairs; then the
     replication constraint that gathers the shards' rows, and the global
-    top-k: `_compiled`'s shape, built from the same pieces."""
+    top-k, packed into one replicated buffer (PR 32): `_compiled`'s shape,
+    built from the same pieces."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -221,7 +245,8 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
     from elasticsearch_tpu.ops.scoring import (dense_term_scores,
                                                impact_term_scores,
                                                top_k_with_total)
-    from elasticsearch_tpu.parallel.param_pack import pack, unpack
+    from elasticsearch_tpu.parallel.param_pack import (pack, pack_outputs,
+                                                       unpack)
     from elasticsearch_tpu.parallel.spmd import (constrain, constrain_shards,
                                                  manual_shard_region)
 
@@ -254,7 +279,9 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
             flat = constrain(ts.reshape(-1), mesh4, P())
             flat_i = constrain(ti.reshape(-1), mesh4, P())
             g_scores, g_idx = jax.lax.top_k(flat, TOP_K)
-        return g_scores, g_idx // TOP_K, flat_i[g_idx], tot.sum()
+        outs, _ = pack_outputs((g_scores, (g_idx // TOP_K).astype(jnp.int32),
+                                flat_i[g_idx], tot.sum(dtype=jnp.int32), {}))
+        return tuple(constrain(b, mesh4, P()) for b in outs)
 
     sharded = NamedSharding(mesh4, P("shards"))
     dev = {"dense_tfn": _sds((S, V_DENSE, n), jnp.float32, sharded),
@@ -269,11 +296,16 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
     _assert_block_select_in(text, n)
     # the shards' rows reach every chip: an all-gather, or (the compiler's
     # choice here) each chip's K rows in a zeroed [S x K] buffer, summed
-    rows = rf"= [fs]32\[({S * TOP_K}|{S},{TOP_K})\].* all-(gather|reduce)\("
-    gathers = [ln for ln in text.splitlines() if re.search(rows, ln)]
-    assert len(gathers) >= 2, "scores and ids are not gathered"
+    # (the ids' rows may share one all-reduce with the total, a tuple)
+    gathered = " ".join(ln.split(" all-")[0] for ln in text.splitlines()
+                        if re.search(r" all-(gather|reduce)\(", ln))
+    for rows in ("f32", "s32"):
+        assert re.search(rf"{rows}\[({S * TOP_K}|{S},{TOP_K})\]", gathered), \
+            f"the shards' {rows} rows are not gathered"
     # each chip is handed its own row of the packed parameters
     assert "s32[1,71]" in text
+    # and every chip holds the one buffer the host fetches from one of them
+    _assert_one_replicated_buffer_of_words(compiled, 3 * TOP_K + 1)
 
 
 def test_sharded_fused_region_compiles_on_four_chips(mesh4,

@@ -310,8 +310,9 @@ def test_a_search_hands_its_program_one_host_array_a_dtype_class(
 
 
 def test_nodes_stats_ships_one_buffer_a_search_of_the_rest_api():
-    """What the benchmark's `engine.dispatch_buffers` reads: the counter's
-    rise over the searches `rest.search` counted, from `_nodes/stats`."""
+    """What the benchmark's `engine.dispatch_buffers` and
+    `engine.fetch_buffers` read: the counters' rise over the searches
+    `rest.search` counted, from `_nodes/stats`."""
     import asyncio
     import json
 
@@ -362,6 +363,9 @@ def test_nodes_stats_ships_one_buffer_a_search_of_the_rest_api():
     assert added("es.span.rest.search.count") == 3
     assert added("es.search.dispatch.buffers") == 3
     assert added("es.search.dispatch.leaves") > 3 * 4
+    # and back (PR 32): one fetched buffer a search, four leaves cut from it
+    assert added("es.search.fetch.buffers") == 3
+    assert added("es.search.fetch.leaves") == 3 * 4
 
 
 # -- (d) the layout is part of the program's identity ------------------------

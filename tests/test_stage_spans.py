@@ -470,9 +470,11 @@ def test_the_selection_runs_whole_under_the_scope_topk():
     assert not stray, stray
     unscoped = {p for p, scope in prims
                 if "topk" not in scope and "score" not in scope}
-    # the parameters' unpacking and the total's sum over the shards
+    # the parameters' unpacking, the total's sum over the shards and the
+    # outputs' packing
     assert unscoped <= {"slice", "reshape", "squeeze", "bitcast_convert_type",
-                        "convert_element_type", "reduce_sum"}, unscoped
+                        "convert_element_type", "reduce_sum",
+                        "concatenate"}, unscoped
 
 
 def test_persistent_cache_hits_are_counted_beside_compiles():
