@@ -1,9 +1,10 @@
 """The arithmetic of the end-to-end metrics: percentiles and rates over the
-requests of one window."""
+requests of one window, and the spread of one metric over a set of runs."""
 
 from __future__ import annotations
 
 import math
+import statistics
 from dataclasses import dataclass
 
 
@@ -54,4 +55,24 @@ def window_summary(requests: list[Request], seconds: float) -> dict:
         out["p95_ms"] = percentile(lat, 95)
         out["mean_ms"] = sum(lat) / len(lat)
     out["qps"] = out["answered_in_window"] / seconds
+    return out
+
+
+def spread(values, trimmed: bool = False) -> float:
+    """How far the runs of one set lie apart in one metric: the distance
+    between the first and the third quartile, as
+    `statistics.quantiles(values, n=4)` gives them, as a share of the median.
+    `trimmed` leaves out the run farthest from the median where that narrows
+    the spread: the check's measure of whether a bound is too tight (the mean
+    of two sets' trimmed spreads may be at most half of it); untrimmed, over
+    all runs, its measure of whether one is too loose (README.md, Bounds)."""
+    v = [float(x) for x in values]
+    if len(v) < 2:
+        raise ValueError("a spread needs two runs or more")
+    q1, _, q3 = statistics.quantiles(v, n=4)
+    med = statistics.median(v)
+    out = (q3 - q1) / med
+    if trimmed and len(v) > 3:  # three runs or more stay
+        far = max(range(len(v)), key=lambda i: abs(v[i] - med))
+        out = min(out, spread(v[:far] + v[far + 1:]))
     return out

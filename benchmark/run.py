@@ -257,11 +257,12 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     work = os.path.join(program_root, ".bench_work", workload)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(os.path.join(work, "trace"))
-    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or os.path.join(program_root, ".jax_cache"))
+    # the compile cache is the run's own and goes with `work`: a run that
+    # finds what another compiled sets up sooner, by up to a fifth (PERF.md
+    # section 2)
     server = server_factory(program_root, os.path.join(work, "data"),
-                            os.path.join(work, "server.log"), cache_dir,
-                            server_env)
+                            os.path.join(work, "server.log"),
+                            os.path.join(work, "jax_cache"), server_env)
     try:
         c = server.start()
         say(f"server: up on port {server.port}")

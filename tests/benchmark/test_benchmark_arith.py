@@ -16,6 +16,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import control  # noqa: E402
+import loop  # noqa: E402
 from benchlib import compare as cmp  # noqa: E402
 from benchlib import corpus as gen  # noqa: E402
 from benchlib import stats, trace  # noqa: E402
@@ -77,6 +78,47 @@ def test_window_summary_counts_the_stall_and_the_failure():
 def test_a_window_without_answers_has_a_rate_of_zero_and_no_latency():
     s = stats.window_summary([Request(0, 0.0, 0.1, 0)], 1.0)
     assert s["failed"] == 1 and s["qps"] == 0.0 and "p50_ms" not in s
+
+
+# -- the spread of a set of runs (README.md, Bounds) --------------------------
+
+@pytest.mark.parametrize("values, plain, trimmed", [
+    # quartiles as statistics.quantiles(n=4) gives them: 2.25 and 6.75 of
+    # 1..8 (NumPy's 2.75 and 6.25 lie closer together); without the 1, 3 and 7
+    ([1, 2, 3, 4, 5, 6, 7, 8], 4.5 / 4.5, 4.0 / 5.0),
+    # six runs, one far off: 99.375 and 105.75 around 100.25; the 120 left
+    # out, 99.25 and 100.75 around 100
+    ([100.0, 100.5, 101.0, 99.5, 99.0, 120.0], 6.375 / 100.25, 0.015),
+    ([10.0, 10.0, 10.0, 10.0, 10.0, 10.0], 0.0, 0.0),
+    # no run is left out of three: two are no set
+    ([3.0, 1.0, 2.0], 2.0 / 2.0, 2.0 / 2.0),
+])
+def test_spread_is_the_quartiles_distance_over_the_median(values, plain, trimmed):
+    assert stats.spread(values) == pytest.approx(plain, rel=1e-3)
+    assert stats.spread(values, trimmed=True) == pytest.approx(trimmed, rel=1e-3)
+    assert stats.spread(values, trimmed=True) <= stats.spread(values)
+
+
+def test_spread_of_one_run_is_an_error():
+    with pytest.raises(ValueError):
+        stats.spread([1.0])
+
+
+def test_loops_summary_takes_plain_runs_by_cell_and_metric():
+    def rec(run, p50, qps=None):
+        m = {"search_p50_ms": {"value": p50, "unit": "ms"}}
+        if qps:
+            m["search_qps"] = {"value": qps, "unit": "req/s"}
+        return {"run": run, "rc": 0, "wall_s": 1.0, "result": {"metrics": m}}
+
+    lines = loop.summary([
+        rec("a.c8:1:0", 20.0, 400.0), rec("a.c8:2:0", 21.0, 380.0),
+        rec("a.c8:3:1", 99.0),                      # traced: no end-to-end line
+        rec("a.c8:4:0", 19.0, 420.0), rec("b.c1:5:0", 3.0),
+        {"run": "a.c8:6:0", "rc": 1, "wall_s": 1.0, "result": {}}])
+    assert lines == [
+        "a.c8 search_p50_ms: n=3 median=20 spread=0.1000 trimmed=0.1000",
+        "a.c8 search_qps: n=3 median=400 spread=0.1000 trimmed=0.1000"]
 
 
 # -- the generator -----------------------------------------------------------
