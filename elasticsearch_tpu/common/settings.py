@@ -340,6 +340,12 @@ def default_cluster_settings() -> list[Setting]:
                 validator=_validate_duration),
         Setting("serving.queue.max_depth", 1000, Setting.positive_int,
                 dynamic=True),
+        # the smallest batch tier of the wave programs' ladder
+        # (ops/batched.wave_q_tier: powers of two from here): a wave, or a
+        # fused wave's escalation, of fewer queries is padded with empty
+        # ones up to it. Raising it trades padded rows for fewer programs.
+        Setting("serving.wave.min_tier", 1, Setting.at_least_one,
+                dynamic=True),
         # per-tenant weighted fair scheduling: "tenantA:4,tenantB:1"
         # (X-Opaque-Id is the tenant identity; unlisted tenants weigh 1)
         Setting("serving.tenant.weights", "", str, dynamic=True),

@@ -1650,6 +1650,9 @@ class EsIndex:
                "lanes": [], "term_lanes": [], "tiered": None,
                "t0": time.monotonic(),
                "meta": {"wave_size": n, "term_packed": 0, "term_waves": [],
+                        # nanoseconds of the members' query parsing, which
+                        # the service hands back to them as `engine.parse`
+                        "parse_ns": 0,
                         # host-transition accounting (PR 11): one
                         # dispatch phase + one combined fetch per wave
                         # is the contract; extras (escalations, agg
@@ -1763,11 +1766,14 @@ class EsIndex:
                 if (not p["aggs"] and p["knn"] is None
                         and isinstance(p["query"], dict)
                         and searcher is not None and searcher.sp.n_max > 0):
+                    t_parse = time.perf_counter_ns()
                     try:
                         spec = term_disjunction_of(
                             parse_query(p["query"], self.mappings))
                     except Exception:  # noqa: BLE001 - generic lane raises it
                         spec = None
+                    job["meta"]["parse_ns"] += (time.perf_counter_ns()
+                                                - t_parse)
                 if spec is not None:
                     fld, terms = spec
                     k = max(p["size"] + p["from_"], 1)
@@ -1897,10 +1903,16 @@ class EsIndex:
                       k=max([m["fields"].get("k", 10) for m in merged]
                             or [10]),
                       num_docs=(sp.S * sp.n_max if sp is not None else 0))
+        pending = ([s["pending"] for s in pend_states]
+                   + [m["pending"] for m in merged])
+        # counted as the solo path's fetch is: device arrays pulled
+        from ..telemetry import metrics
+
+        n_arrays = len(jax.tree_util.tree_leaves(pending))
+        metrics.counter_inc("es.search.fetch.buffers", n_arrays)
+        metrics.counter_inc("es.search.fetch.leaves", n_arrays)
         with time_kernel("serving.wave_program", **fields):
-            host = jax.device_get(
-                [s["pending"] for s in pend_states]
-                + [m["pending"] for m in merged])
+            host = jax.device_get(pending)
         hi = iter(host)
         for s in pend_states:
             s["host"] = next(hi)
@@ -2264,6 +2276,15 @@ class Engine:
                 key, lambda v, a=attr: getattr(self.serving, a)(v))
         if self.settings.get("serving.enabled"):
             self.serving.set_enabled(True)
+
+        def _wave_min_tier(v):
+            from ..ops.batched import BatchTermSearcher
+
+            BatchTermSearcher.WAVE_MIN_TIER = \
+                1 << (max(int(v), 1) - 1).bit_length()
+
+        self.settings.add_consumer("serving.wave.min_tier", _wave_min_tier)
+        _wave_min_tier(self.settings.get("serving.wave.min_tier"))
         # adaptive execution planner (PR 18, planner/): push the dynamic
         # knobs into the process-wide planner singleton — the dispatch
         # sites consult it on every arm choice, so a settings update

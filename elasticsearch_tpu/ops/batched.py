@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..index.pack import BLOCK
+from .scoring import top_k_of_row
 
 
 @dataclass
@@ -84,72 +85,105 @@ def batch_term_disjunction(
     live = dev["live"]
     n = num_docs
 
-    # ---- dense tier on the MXU ------------------------------------------
-    dense = dev.get("dense_tfn")
-    if dense is not None and W.shape[1] > 0:
-        # HIGHEST: full-f32 MXU passes — default TPU matmul rounds through
-        # bf16, which costs ~1e-4 relative score error vs the scalar path
-        scores_d = jnp.matmul(W, dense, precision=jax.lax.Precision.HIGHEST)
-    else:
-        scores_d = jnp.zeros((W.shape[0], n), jnp.float32)
-    scores_d = jnp.where(live[None, :], scores_d, 0.0)
-
-    # ---- sparse tail: explicit candidates, no scatter -------------------
-    docids = dev["post_docids"][sparse_rows]  # [Q, Ts, B, 128]
-    if impact_w is not None:
-        codes = dev["impact_codes"][sparse_rows].astype(jnp.float32)
-        part = impact_w[:, :, None, None] * codes  # pad lanes -> 0
-    else:
-        tfs = dev["post_tfs"][sparse_rows]
-        if has_norms:
-            dls = dev["post_dls"][sparse_rows]
-            denom = tfs + k1 * (1.0 - b + b * dls / avgdl)
+    # the scopes name phases, as in `search_solo`: HLO metadata that a
+    # capture's device operations carry (`device.score_ms`, `device.topk_ms`)
+    with jax.named_scope("score"):
+        # ---- dense tier on the MXU ------------------------------------------
+        dense = dev.get("dense_tfn")
+        if dense is not None and W.shape[1] > 0:
+            # HIGHEST: full-f32 MXU passes — default TPU matmul rounds through
+            # bf16, which costs ~1e-4 relative score error vs the scalar path.
+            # Never one row: a backend may take a matrix-vector route for it
+            # that adds in another order than the matrix-matrix route of
+            # every larger batch (the CPU's does: 1 ulp), and a query's row
+            # must not depend on the batch it rides in.
+            Wm = W if W.shape[0] > 1 else jnp.pad(W, ((0, 1), (0, 0)))
+            scores_d = jnp.matmul(
+                Wm, dense, precision=jax.lax.Precision.HIGHEST)[:W.shape[0]]
         else:
-            denom = tfs + k1
-        part = sparse_weights[:, :, None, None] * tfs / denom  # pad -> 0
-    Q = docids.shape[0]
-    C = Ts * B * BLOCK
-    cd = docids.reshape(Q, C)
-    cs = part.reshape(Q, C)
-    # padding lanes carry docid == num_docs and score 0; sort pushes them
-    # last. Multi-operand sort, not argsort + take_along_axis: the take is
-    # a per-element gather (~30ns/element on TPU), measured 5x slower.
-    sd, sv = jax.lax.sort((cd, cs), dimension=1, num_keys=1)
-    # run sums: csum - (csum just before this run's start), run start base
-    # propagated forward by cummax (csum - sv is non-decreasing: sv >= 0).
-    # f64 prefix sums: a f32 cumsum carries O(prefix/value * 2^-24) noise
-    # (~1e-4 relative at C=8k), enough to randomly split docs whose true
-    # scores tie — this path is the accuracy reference, so it pays for
-    # (slow, emulated) f64 to keep per-doc sums exact to f32 ulps.
-    sv64 = sv.astype(jnp.float64)
-    csum = jnp.cumsum(sv64, axis=1)
-    col = jnp.arange(C)
-    starts = jnp.where(col[None, :] == 0, True, sd != jnp.roll(sd, 1, axis=1))
-    base = jnp.where(starts, csum - sv64, -jnp.inf)
-    run_base = jax.lax.cummax(base, axis=1)
-    run_sum = (csum - run_base).astype(jnp.float32)
-    is_end = jnp.where(col[None, :] == C - 1, True, sd != jnp.roll(sd, -1, axis=1))
-    live_c = live[jnp.minimum(sd, n - 1)] & (sd < n)
-    valid_end = is_end & live_c
-    # full candidate score = sparse run sum + dense score at that doc
-    dg = jnp.take_along_axis(scores_d, jnp.minimum(sd, n - 1), axis=1)
-    cand = jnp.where(valid_end, run_sum + dg, -jnp.inf)
+            scores_d = jnp.zeros((W.shape[0], n), jnp.float32)
+        scores_d = jnp.where(live[None, :], scores_d, 0.0)
+
+        # ---- sparse tail: explicit candidates, no scatter -------------------
+        docids = dev["post_docids"][sparse_rows]  # [Q, Ts, B, 128]
+        if impact_w is not None:
+            codes = dev["impact_codes"][sparse_rows].astype(jnp.float32)
+            part = impact_w[:, :, None, None] * codes  # pad lanes -> 0
+        else:
+            tfs = dev["post_tfs"][sparse_rows]
+            if has_norms:
+                dls = dev["post_dls"][sparse_rows]
+                denom = tfs + k1 * (1.0 - b + b * dls / avgdl)
+            else:
+                denom = tfs + k1
+            part = sparse_weights[:, :, None, None] * tfs / denom  # pad -> 0
+        Q = docids.shape[0]
+        C = Ts * B * BLOCK
+        cd = docids.reshape(Q, C)
+        cs = part.reshape(Q, C)
+        # padding lanes carry docid == num_docs and score 0; sort pushes them
+        # last. Multi-operand sort, not argsort + take_along_axis: the take is
+        # a per-element gather (~30ns/element on TPU), measured 5x slower.
+        # Lanes of one docid must stay in term order (the run sums below add
+        # them in it), which a stable sort keeps; where (docid, term) fits
+        # one int32 it is the key itself, every key is its own and the sort
+        # need not be stable: 2.9 s of compile against 9.6 at C = 4,096.
+        if (n + 1) * Ts < 2**31:
+            term = jnp.arange(C, dtype=jnp.int32) // (B * BLOCK)
+            skey, sv = jax.lax.sort((cd * Ts + term[None, :], cs), dimension=1,
+                                    num_keys=1, is_stable=False)
+            sd = skey // Ts
+        else:
+            sd, sv = jax.lax.sort((cd, cs), dimension=1, num_keys=1)
+        # run sums: a (term, doc) pair holds at most one posting, so a docid's
+        # run is at most Ts lanes long and its sum is the lane plus the Ts - 1
+        # before it that carry the same docid: Ts - 1 shifted adds in f32. The
+        # sum reads the run's own values alone, in one order, so docs whose
+        # postings tie score bit-identically (what a f32 prefix sum over the
+        # whole row loses: O(prefix/value * 2^-24) noise), a longer padded Ts
+        # adds exact zeros, and no f64 is emulated: the f64 cumsum this
+        # replaces was 147 s of the program's 178 s on the TPU compiler at
+        # (Ts 4, B 8, Q 1) (PERF.md section 6, PR 35).
+        col = jnp.arange(C)
+        run_sum = sv
+        for j in range(1, min(Ts, C)):
+            same = jnp.pad(sd[:, :-j], ((0, 0), (j, 0)), constant_values=-1) == sd
+            run_sum = run_sum + jnp.where(
+                same, jnp.pad(sv[:, :-j], ((0, 0), (j, 0))), 0.0)
+        is_end = jnp.where(col[None, :] == C - 1, True, sd != jnp.roll(sd, -1, axis=1))
+        live_c = live[jnp.minimum(sd, n - 1)] & (sd < n)
+        valid_end = is_end & live_c
+        # full candidate score = sparse run sum + dense score at that doc
+        dg = jnp.take_along_axis(scores_d, jnp.minimum(sd, n - 1), axis=1)
+        cand = jnp.where(valid_end, run_sum + dg, -jnp.inf)
 
     # ---- merge ----------------------------------------------------------
-    masked_d = jnp.where(live[None, :] & (scores_d > 0), scores_d, -jnp.inf)
-    dv, di = jax.lax.top_k(masked_d, k)  # [Q, k]
-    dup = (di[:, :, None] == sd[:, None, :]) & valid_end[:, None, :]
-    dv = jnp.where(dup.any(-1), -jnp.inf, dv)
-    all_v = jnp.concatenate([cand, dv], axis=1)
-    all_i = jnp.concatenate([sd, di], axis=1)
-    # exact (score desc, docid asc) order across both lists: non-negative IEEE
-    # f32 bit patterns sort like values as int32 (and -inf sorts below all),
-    # so pack [score_bits | ~docid] into one int64 rank key
-    score_bits = jax.lax.bitcast_convert_type(all_v, jnp.int32).astype(jnp.int64)
-    rank = (score_bits << 32) + (jnp.int64(0xFFFFFFFF) - all_i.astype(jnp.int64))
-    _, fidx = jax.lax.top_k(rank, k)
-    fv = jnp.take_along_axis(all_v, fidx, axis=1)
-    fids = jnp.take_along_axis(all_i, fidx, axis=1)
+    with jax.named_scope("topk"):
+        masked_d = jnp.where(live[None, :] & (scores_d > 0), scores_d, -jnp.inf)
+        # row by row in two levels (ops/scoring.top_k_of_row): as the body
+        # of a shard axis this batch is rank 3, and the TPU compiler sorts
+        # a rank-3 `lax.top_k` whole: 24 s of compile at N = 294,912
+        dv, di = jax.vmap(lambda row: top_k_of_row(row, k))(masked_d)  # [Q, k]
+        dup = (di[:, :, None] == sd[:, None, :]) & valid_end[:, None, :]
+        dv = jnp.where(dup.any(-1), -jnp.inf, dv)
+        # the candidates lie in docid order (the sort above), so an f32
+        # top-k over them, which breaks ties by the lowest lane, already is
+        # (score desc, docid asc); only its k winners meet the dense tier's k
+        # under the int64 rank key. An int64 top_k over all C lanes is
+        # sorted whole too: 20 s of compile at C = 4,096.
+        kc = min(k, C)
+        cv, cpos = jax.vmap(lambda row: top_k_of_row(row, kc))(cand)
+        all_v = jnp.concatenate([cv, dv], axis=1)
+        all_i = jnp.concatenate(
+            [jnp.take_along_axis(sd, cpos, axis=1), di], axis=1)
+        # exact (score desc, docid asc) order across both lists: non-negative
+        # IEEE f32 bit patterns sort like values as int32 (and -inf sorts
+        # below all), so pack [score_bits | ~docid] into one int64 rank key
+        score_bits = jax.lax.bitcast_convert_type(all_v, jnp.int32).astype(jnp.int64)
+        rank = (score_bits << 32) + (jnp.int64(0xFFFFFFFF) - all_i.astype(jnp.int64))
+        _, fidx = jax.lax.top_k(rank, k)
+        fv = jnp.take_along_axis(all_v, fidx, axis=1)
+        fids = jnp.take_along_axis(all_i, fidx, axis=1)
 
     totals = (masked_d > 0).sum(axis=1) + (valid_end & (dg <= 0) & (run_sum > 0)).sum(axis=1)
     return fv, fids, totals.astype(jnp.int32)
@@ -806,17 +840,15 @@ class BatchTermSearcher:
                 if nb > 0:
                     ts += 1
                     maxb = max(maxb, nb)
-            # buckets: Ts pow2, B in 4x steps from 8. The sparse sort/scan
-            # cost per query is proportional to Ts*B, so queries must not
-            # pay a heavier query's padding; executable dispatches are
-            # effectively free once compiled, so more groups only cost
-            # one-time compiles (persisted in the XLA cache).
-            bb = 8
-            while bb < maxb:
-                bb *= 4
+            # buckets: Ts pow2, B in 4x steps from 8 (the waves' ladder).
+            # The sparse sort/scan cost per query is proportional to Ts*B,
+            # so queries must not pay a heavier query's padding;
+            # executable dispatches are effectively free once compiled, so
+            # more groups only cost one-time compiles (persisted in the
+            # XLA cache).
             shapes.append(
                 ((1 << max(ts - 1, 0).bit_length()) if ts else 0,
-                 bb if maxb else 0)
+                 self.wave_b_tier(maxb) if maxb else 0)
             )
         groups: dict[tuple, list[int]] = {}
         for qi, sh in enumerate(shapes):
@@ -844,15 +876,64 @@ class BatchTermSearcher:
             fs = self._fused = FusedTermSearcher(self)
         return fs
 
-    @staticmethod
-    def wave_q_tier(q: int) -> int:
+    # the smallest batch tier (`serving.wave.min_tier`): a wave, or an
+    # escalation, of fewer queries is padded up to it. Process-wide like
+    # the program caches it bounds; the engine's settings consumer sets it.
+    WAVE_MIN_TIER = 1
+
+    @classmethod
+    def wave_q_tier(cls, q: int) -> int:
         """The compiled batch tier a q-query wave pads to: the next power
         of two (the same {1, 2, 4, ...} executable family `_chunk_q` and
-        `plan_bucketed` already key their compiled-plan caches on). The
-        serving front end pads coalesced waves to this tier so steady-
-        state traffic reuses a small family of compiled programs, and
-        reports q / wave_q_tier(q) as the wave's device occupancy."""
-        return 1 << max(q - 1, 0).bit_length() if q > 1 else 1
+        `plan_bucketed` already key their compiled-plan caches on), at
+        least WAVE_MIN_TIER. The serving front end pads coalesced waves to
+        this tier so steady-state traffic reuses a small family of
+        compiled programs, and reports q / wave_q_tier(q) as the wave's
+        device occupancy."""
+        return max(cls.WAVE_MIN_TIER,
+                   1 << max(q - 1, 0).bit_length() if q > 1 else 1)
+
+    @staticmethod
+    def _steps_of_four(n: int, floor: int) -> int:
+        tier = floor
+        while tier < n:
+            tier *= 4
+        return tier
+
+    @classmethod
+    def wave_ts_tier(cls, ts: int) -> int:
+        """The padded count of sparse terms the widest query of a stacked
+        batch is planned with: 4, then in steps of four (4, 16, 64). Coarse
+        on purpose: which queries share a wave follows arrival order, so
+        every tier is one more program that some wave may be the first to
+        need, and a padded term costs a batch Q x B x 128 sorted lanes, small
+        beside the dense tier it reads whole."""
+        return cls._steps_of_four(ts, 4)
+
+    @classmethod
+    def wave_b_tier(cls, nb: int) -> int:
+        """The padded count of posting blocks a sparse term (the longest of
+        a batch) is planned with: 8, then in steps of four. A term of
+        `dense_min_df` documents or more lies in the dense tier, so the
+        ladder ends at the pack's own longest sparse term."""
+        return cls._steps_of_four(nb, 8)
+
+    @classmethod
+    def wave_r_tier(cls, rows: int) -> int:
+        """The padded count of posting-block rows a fused chunk is planned
+        with: 64, then in steps of four (64, 256, 1,024). Coarser than
+        `plan_fused`'s own powers of two for the reason `wave_ts_tier`
+        gives: with those, two runs of ten under 64 callers met a program
+        for the first time inside the measured window, a 10 s compile
+        (PERF.md section 6, PR 35)."""
+        return cls._steps_of_four(rows, 64)
+
+    @classmethod
+    def wave_td_tier(cls, td: int) -> int:
+        """The padded count of dense terms a fused chunk's widest query is
+        planned with: 16, then in steps of four: one tier for every query of
+        up to 16 dense terms."""
+        return cls._steps_of_four(td, 16)
 
     def msearch_coalesced(self, fld, groups, k: int = 10, **kw):
         """Coalesced msearch for the serving front end: pack several

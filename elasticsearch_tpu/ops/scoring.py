@@ -219,6 +219,18 @@ def top_k_with_total(
     ok = match[:n] & live
     total = jnp.sum(ok, dtype=jnp.int32)
     masked = jnp.where(ok, scores[:n], -jnp.inf)
+    top_scores, top_ids = top_k_of_row(masked, k)
+    return top_scores, top_ids, total
+
+
+def top_k_of_row(masked: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The two-level selection of `top_k_with_total` over one masked row
+    [n] (-inf where a lane is out): (values [k], lanes [k]), bit-identical
+    to `lax.top_k(masked, k)` where the value is finite, ties to the lowest
+    lane. `vmap` it over a batch's rows: every selection inside is small,
+    so it compiles and runs fast at any rank (a `lax.top_k` over the long
+    axis of a rank-3 operand, a batch under a shard axis, is sorted whole)."""
+    n = masked.shape[0]
     w = SELECT_BLOCK
     g = -(-n // w)
     blocks = jnp.pad(masked, (0, g * w - n),
@@ -228,4 +240,4 @@ def top_k_with_total(
     top_scores, pos = jax.lax.top_k(blocks[chosen].reshape(-1), k)
     w32 = jnp.int32(w)
     top_ids = chosen[jax.lax.div(pos, w32)] * w32 + jax.lax.rem(pos, w32)
-    return top_scores, top_ids, total
+    return top_scores, top_ids

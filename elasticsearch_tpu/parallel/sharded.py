@@ -18,6 +18,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import json
+import logging
 import os
 
 import jax
@@ -34,6 +35,7 @@ from .param_pack import (pack, pack_outputs, packed_counts, unpack,
                          unpack_host)
 from .stacked import StackedPack
 
+log = logging.getLogger(__name__)
 
 import functools
 
@@ -236,6 +238,12 @@ class StackedSearcher:
             "dense-tier packs bake default k1/b; rebuild with dense disabled"
         )
         self._cache: dict = {}
+        # a searcher that never compiles a plan shape has met none: the
+        # counter reads 0 from the start, not nothing (a node whose every
+        # search rides a wave)
+        from ..telemetry import metrics
+
+        metrics.counter_inc("es.jit.cache.search_solo.misses", 0)
         self._dense_tfn_fn = None
         # shard request cache identity: per-shard epochs so one shard's
         # in-place mutation invalidates only its own entries (plus the
@@ -1397,17 +1405,65 @@ def msearch_wave_begin(ss: "StackedSearcher", fld: str, queries: list,
     vmap model (a single-device merge is still one program with a k-row
     fetch); only the shard_map oracle resolves synchronously here — it
     is a test fixture, not a serving model."""
-    from ..ops.batched import BatchTermSearcher
-
     Q = len(queries)
-    tier = BatchTermSearcher.wave_q_tier(Q)
-    padded = list(queries) + [[] for _ in range(tier - Q)]
+    padded, tier = _pad_to_wave_tier(queries)
     st = {"Q": Q, "tier": tier}
     if getattr(ss, "_exec", "vmap") == "shardmap":
         st["result"] = msearch_sharded(ss, fld, padded, k)
         return st
     st.update(_merged_cached_begin(ss, fld, padded, k))
     return st
+
+
+# the fused arm's escalation is padded to this many queries at least: the
+# exact arm reads the whole dense tier whatever its batch, so the rows are
+# free, and the count of flagged queries (1, 2, 3, ... by arrival order)
+# then names one program and not three or four
+ESCALATION_MIN_TIER = 8
+
+
+def _pad_to_wave_tier(queries: list, floor: int = 1) -> tuple[list, int]:
+    """-> (the queries padded with empty ones to their batch tier, the
+    tier): the one place a wave's batch width is decided, for the wave
+    itself and for the fused arm's escalation alike. An empty query plans
+    to zero weights and scores nothing, and every row of a batch is
+    computed independently, so a real query's row does not depend on its
+    companions or on the padding."""
+    from ..ops.batched import BatchTermSearcher
+
+    tier = max(BatchTermSearcher.wave_q_tier(len(queries)), floor)
+    return list(queries) + [[] for _ in range(tier - len(queries))], tier
+
+
+def _wave_program(cache: dict, site: str, cache_key: tuple, build):
+    """The compiled program of one key of a wave-program cache, built by
+    `build()` where the key is new; every look-up counted
+    (`es.jit.cache.wave_program.hits` / `.misses` and `es.jit.cache.<site>.*`:
+    the misses are the size of the family the traffic has reached)."""
+    from ..monitoring.device import note_executable_cache
+
+    fn = cache.get(cache_key)
+    note_executable_cache("wave_program", fn is not None)
+    note_executable_cache(site, fn is not None)
+    if fn is None:
+        log.info("wave program: new key %s %s", site, cache_key)
+        fn = cache[cache_key] = jax.jit(build())
+    return fn
+
+
+def _wave_launch(fn, *args):
+    """Call a wave program: the stage `engine.wave_launch` of the wave this
+    thread is carrying, and `es.search.dispatch.buffers` / `.leaves` for the
+    host arrays handed over, each one host-to-device transfer (a pack that
+    is resident on the device is neither)."""
+    from ..telemetry import metrics, wave_stage
+
+    host = sum(isinstance(a, (np.ndarray, np.generic))
+               for a in jax.tree_util.tree_leaves(args))
+    metrics.counter_inc("es.search.dispatch.buffers", host)
+    metrics.counter_inc("es.search.dispatch.leaves", host)
+    with wave_stage("engine.wave_launch"):
+        return fn(*args)
 
 
 def msearch_wave_fetch(st: dict) -> None:
@@ -1684,8 +1740,14 @@ def _msearch_stack_plans(ss: "StackedSearcher", fld: str, queries: list,
     plans = [BatchTermSearcher(a).plan(fld, queries, k) for a in adapters]
     if impact and any(p.impact_w is None for p in plans):
         return None
-    ts_max = max(p.sparse_rows.shape[1] for p in plans)
-    b_max = max(p.sparse_rows.shape[2] for p in plans)
+    # the one place the stacked arms' (Ts, B) is decided: on the ladders,
+    # never the exact largest of the batch, so that which queries share a
+    # batch cannot mint a program (row 0 is the all-padding block and a
+    # padded term weighs 0: a padded lane scores nothing)
+    ts_max = BatchTermSearcher.wave_ts_tier(
+        max(p.sparse_rows.shape[1] for p in plans))
+    b_max = BatchTermSearcher.wave_b_tier(
+        max(p.sparse_rows.shape[2] for p in plans))
     attrs = ("sparse_weights", "impact_w") if impact else ("sparse_weights",)
     for s in range(S):
         sr = plans[s].sparse_rows
@@ -1751,9 +1813,7 @@ def _msearch_impact_partials(ss: "StackedSearcher", fld: str,
            ("post_docids", "impact_codes", "live")}
     if "dense_tfn" in ss.dev:
         sub["dense_tfn"] = ss.dev["dense_tfn"]
-    cache_key = ("msearch_impact", fld, Ts, B, kk, Q)
-    fn = ss._cache.get(cache_key)
-    if fn is None:
+    def build():
         if ss.mesh is not None:
             def msearch_impact(dev, W_, rows_, ws_, iws_):
                 specs = jax.tree_util.tree_map(lambda _: P("shards"), dev)
@@ -1771,7 +1831,10 @@ def _msearch_impact_partials(ss: "StackedSearcher", fld: str,
                     )
                 v, i, t = jax.vmap(body)(dev, W_, rows_, ws_, iws_)
                 return v[:, 0], i[:, 0], t[:, 0]
-        fn = ss._cache[cache_key] = jax.jit(msearch_impact)
+        return msearch_impact
+
+    fn = _wave_program(ss._cache, "msearch_impact",
+                       ("msearch_impact", fld, Ts, B, kk, Q), build)
     from ..telemetry import profile_event, time_kernel
 
     code_bytes = int(np.dtype(ss.dev["impact_codes"].dtype).itemsize)
@@ -1858,8 +1921,13 @@ def _msearch_merged_begin(ss: "StackedSearcher", fld: str, queries: list,
         cands.append(("exact", "sharded.allgather_topk",
                       {"tier": "exact", "shards": S, "queries": Q,
                        "k": k, "num_docs": S * n_max}))
+        # the static order, whatever the planner's EMAs hold: each arm of
+        # this route is a family of compiled programs, and the first
+        # escalation of a fused wave (which hands `sharded.allgather_topk`
+        # its first efficiency reading) used to flip every later wave to the
+        # impact arm and its 29 programs (PERF.md section 6, PR 35)
         arm = execution_planner().choose_arm(
-            "sharded.msearch_merged", cands)
+            "sharded.msearch_merged", cands, model=False)
         if arm == "fused":
             return fs.msearch_merged_begin(fld, queries, k)
     elif _impact_sharded_usable(ss):
@@ -1918,8 +1986,8 @@ def _msearch_merged_arm_begin(ss: "StackedSearcher", fld: str,
     if "dense_tfn" in ss.dev:
         sub["dense_tfn"] = ss.dev["dense_tfn"]
     cache_key = ("msearch_merged", impact, fld, Ts, B, kk, Q)
-    fn = ss._cache.get(cache_key)
-    if fn is None:
+
+    def build():
         from .spmd import (
             constrain, constrain_shards, merge_topk_rows, replica_axis,
         )
@@ -1942,11 +2010,26 @@ def _msearch_merged_arm_begin(ss: "StackedSearcher", fld: str,
                 W_, rows_, ws_, iws_ = (
                     constrain(x, mesh, P("shards", ra))
                     for x in (W_, rows_, ws_, iws_))
-            outs = jax.vmap(shard_one)(dev, W_, rows_, ws_, iws_)
+            args = (dev, W_, rows_, ws_, iws_)
+            if mesh is None:
+                # one device: the shards one after another, so that every
+                # selection inside stays rank 2. Under `vmap` they are
+                # rank 3, which the TPU compiler sorts whole, and past
+                # 2,048 lanes that costs ~10 s of compile each (PERF.md
+                # section 6, PR 35); a mesh needs the `vmap` to shard
+                per_shard = [
+                    shard_one(*jax.tree_util.tree_map(lambda x: x[s_], args))
+                    for s_ in range(S)]
+                outs = tuple(jnp.stack(o) for o in zip(*per_shard))
+            else:
+                outs = jax.vmap(shard_one)(*args)
             v, i, t = constrain_shards(outs, mesh)
-            return merge_topk_rows(v, i, t, mesh=mesh)
+            with jax.named_scope("topk"):
+                return merge_topk_rows(v, i, t, mesh=mesh)
 
-        fn = ss._cache[cache_key] = jax.jit(msearch_merged_exact)
+        return msearch_merged_exact
+
+    fn = _wave_program(ss._cache, "msearch_merged", cache_key, build)
     iws = pl.get("iws")
     if iws is None:
         iws = np.zeros_like(pl["ws"])
@@ -1965,13 +2048,12 @@ def _msearch_merged_arm_begin(ss: "StackedSearcher", fld: str,
     if impact:
         fields["code_bytes"] = int(
             np.dtype(ss.dev["impact_codes"].dtype).itemsize)
-    prog_args = (sub, jnp.asarray(pl["W"]), jnp.asarray(pl["rows"]),
-                 jnp.asarray(pl["ws"]), jnp.asarray(iws))
+    prog_args = (sub, pl["W"], pl["rows"], pl["ws"], iws)
     from ..monitoring.xla_introspect import check_dispatch
 
     # PR 12: the one-program scan+merge vs its own compiled cost analysis
     check_dispatch("sharded.allgather_topk", fn, prog_args, fields=fields)
-    outs = fn(*prog_args)
+    outs = _wave_launch(fn, *prog_args)
     return {"pending": outs, "host": None,
             "kernel": "sharded.allgather_topk", "fields": fields,
             "finish": _merged_rows_finish}
@@ -2045,9 +2127,7 @@ def _msearch_exact_partials(ss: "StackedSearcher", fld: str,
            ("post_docids", "post_tfs", "post_dls", "live")}
     if "dense_tfn" in ss.dev:
         sub["dense_tfn"] = ss.dev["dense_tfn"]
-    cache_key = ("msearch_sharded", fld, Ts, B, kk, Q)
-    fn = ss._cache.get(cache_key)
-    if fn is None:
+    def build():
         if ss.mesh is not None:
             def msearch_exact(dev, W_, rows_, ws_):
                 specs = jax.tree_util.tree_map(lambda _: P("shards"), dev)
@@ -2065,7 +2145,10 @@ def _msearch_exact_partials(ss: "StackedSearcher", fld: str,
                     )
                 v, i, t = jax.vmap(body)(dev, W_, rows_, ws_)
                 return v[:, 0], i[:, 0], t[:, 0]
-        fn = ss._cache[cache_key] = jax.jit(msearch_exact)
+        return msearch_exact
+
+    fn = _wave_program(ss._cache, "msearch_sharded",
+                       ("msearch_sharded", fld, Ts, B, kk, Q), build)
     if _return_program:
         # measurement hook (scripts/c5_mesh_probe.py): the compiled
         # program + its device inputs, so collective-merge overhead can be
@@ -2212,27 +2295,27 @@ class _FusedShardedMsearch:
             self._fa_live_of = dev["live"]
         return self._fa
 
-    def _geom(self, nreal):
+    def _geom(self, R):
         """Shared kernel geometry of one fused batch: (bud, tile_n,
-        qsub, t) — window budget from the REAL posting count, pow2-
-        quantized (see FusedTermSearcher._compiled_scan)."""
+        qsub, t) — window budget from the plan's block rows R, which
+        `plan_fused` pads to a power of two from 64, so that (R, Td)
+        alone name a program (see FusedTermSearcher._compiled_scan)."""
         from ..index.pack import BLOCK
         from ..ops import fused as F
 
         tile_n, qsub = self._tile_n, self._qsub
         njc = self.n_pad // tile_n
         t = self._t_env if self._t_env > 0 else F.tile_t_for(njc)
-        nreal_q = 1 << max(nreal - 1, 1).bit_length()
-        mean_win = max(1, nreal_q * BLOCK // ((F.QC // qsub) * njc))
+        mean_win = max(1, R * BLOCK // ((F.QC // qsub) * njc))
         bude = min(
             64 * 1024, max(2048, 1 << (2 * mean_win - 1).bit_length())
         )
         return bude // 128, tile_n, qsub, t
 
-    def _compiled(self, fld, C, R, Td, k, nreal, interpret):
+    def _compiled(self, fld, C, R, Td, k, interpret):
         from ..ops import fused as F
 
-        bud, tile_n, qsub, t = self._geom(nreal)
+        bud, tile_n, qsub, t = self._geom(R)
         key = (fld, C, R, Td, k, interpret, bud, tile_n, qsub, t,
                self._inkernel, self.ss.mesh is None)
         fn = self._cache.get(key)
@@ -2268,7 +2351,7 @@ class _FusedShardedMsearch:
         fn = self._cache[key] = jax.jit(fused_pipeline)
         return fn
 
-    def _compiled_merged(self, fld, C, R, Td, k, nreal, interpret):
+    def _compiled_merged(self, fld, C, R, Td, k, interpret):
         """ONE compiled SPMD program (PR 11, ROADMAP item 1): the
         per-shard fused Pallas pipeline runs inside an embedded
         shard_map manual region — custom calls cannot be GSPMD-
@@ -2278,17 +2361,17 @@ class _FusedShardedMsearch:
         is OR'd across shards in-program too, so the host fetches
         merged k-rows + one bool per query: no more fused-tier fork off
         the one-program route, no S·k-row fetch, no host merge."""
-        from ..ops import fused as F
-
-        bud, tile_n, qsub, t = self._geom(nreal)
+        bud, tile_n, qsub, t = self._geom(R)
         key = ("merged", fld, C, R, Td, k, interpret, bud, tile_n, qsub,
                t, self._inkernel, self.ss.mesh is None)
-        fn = self._cache.get(key)
-        from ..monitoring.device import note_executable_cache
+        return _wave_program(
+            self._cache, "sharded_fused", key,
+            lambda: self._build_merged(fld, k, bud, tile_n, qsub, t,
+                                       interpret))
 
-        note_executable_cache("sharded_fused", fn is not None)
-        if fn is not None:
-            return fn
+    def _build_merged(self, fld, k, bud, tile_n, qsub, t, interpret):
+        from ..ops import fused as F
+
         kw = dict(
             k=k, n=self.n_max, n_pad=self.n_pad,
             has_norms=fld in self.ss.ctx.has_norms,
@@ -2299,7 +2382,8 @@ class _FusedShardedMsearch:
 
         def shard_scan(fa1, avgdl, rows, row_q, row_w, dr, dw):
             def body(carry, xs):
-                return carry, F._fused_pipeline(fa1, avgdl, *xs, **kw)
+                with jax.named_scope("score"):
+                    return carry, F._fused_pipeline(fa1, avgdl, *xs, **kw)
 
             _, outs = jax.lax.scan(body, 0, (rows, row_q, row_w, dr, dw))
             return outs
@@ -2318,12 +2402,12 @@ class _FusedShardedMsearch:
             v2, i2, t2 = constrain_shards(
                 (v.reshape(S_, C_ * qc, kk), i.reshape(S_, C_ * qc, kk),
                  tot.reshape(S_, C_ * qc)), mesh)
-            mv, msh, mi, mt = merge_topk_rows(v2, i2, t2, mesh=mesh)
+            with jax.named_scope("topk"):
+                mv, msh, mi, mt = merge_topk_rows(v2, i2, t2, mesh=mesh)
             flags = jnp.any(fl.reshape(S_, C_ * qc), axis=0)
             return mv, msh, mi, mt, flags
 
-        fn = self._cache[key] = jax.jit(fused_pipeline_merged)
-        return fn
+        return fused_pipeline_merged
 
     def msearch(self, fld, queries, k):
         """Shard_map oracle route: per-shard partials + host merge —
@@ -2345,9 +2429,9 @@ class _FusedShardedMsearch:
         idxs, pb = self._plan_batch(fld, queries, k)
         interpret = jax.default_backend() != "tpu"
         fn = self._compiled_merged(fld, pb["C"], pb["R"], pb["Td"], k,
-                                   pb["nreal"], interpret)
-        outs = fn(self._arrays(), pb["avgdl"], pb["rows"], pb["row_q"],
-                  pb["row_w"], pb["dr"], pb["dw"])
+                                   interpret)
+        outs = _wave_launch(fn, self._arrays(), pb["avgdl"], pb["rows"],
+                            pb["row_q"], pb["row_w"], pb["dr"], pb["dw"])
         Q = len(queries)
         profile_event("tier", tier="fused", queries=Q)
         fields = dict(tier="fused", shards=self.S, queries=Q, k=k,
@@ -2388,12 +2472,17 @@ class _FusedShardedMsearch:
             still = np.nonzero(flagged)[0]
             profile_event("tier", tier="exact_escalation",
                           queries=int(still.shape[0]))
+            # padded to its batch tier like the wave it came from: the
+            # flagged count follows arrival order, and as a program's Q
+            # it would mint one for every count met
+            padded, _tier = _pad_to_wave_tier(
+                [queries[i_] for i_ in still], floor=ESCALATION_MIN_TIER)
             st_ex = _msearch_merged_arm_begin(
-                self.ss, fld, [queries[i_] for i_ in still], k,
-                impact=False)
+                self.ss, fld, padded, k, impact=False)
             host_transition("dispatch")
             _msearch_merged_fetch(st_ex)
-            ev, esh, ei, et = _merged_rows_finish(st_ex)
+            ev, esh, ei, et = (a[:still.shape[0]]
+                               for a in _merged_rows_finish(st_ex))
             ke = min(ev.shape[1], kk)
             scores[still, :] = -np.inf
             scores[still, :ke] = ev[:, :ke]
@@ -2423,10 +2512,17 @@ class _FusedShardedMsearch:
              for qidx in idxs]
             for v in views
         ]  # [S][C]
+        from ..ops.batched import BatchTermSearcher
+
         C = len(idxs)
-        R = max(p.rows.shape[0] for ps in plans for p in ps)
-        Td = max(p.dense_rows.shape[1] for ps in plans for p in ps)
-        nreal = max(p.nreal for ps in plans for p in ps)
+        # on the waves' ladders, coarser than the plans' own powers of two:
+        # (R, Td) name the program, and which queries share a chunk follows
+        # arrival order (a padded row is the all-padding block 0 with weight
+        # 0, a padded dense term weighs 0)
+        R = BatchTermSearcher.wave_r_tier(
+            max(p.rows.shape[0] for ps in plans for p in ps))
+        Td = BatchTermSearcher.wave_td_tier(
+            max(p.dense_rows.shape[1] for ps in plans for p in ps))
 
         def _padr(a, width):
             return np.pad(
@@ -2447,7 +2543,7 @@ class _FusedShardedMsearch:
                 [np.pad(p.dense_w, ((0, 0), (0, Td - p.dense_w.shape[1])))
                  for p in ps] for ps in plans]),
             "avgdl": np.float32(views[0].avgdl(fld)),
-            "C": C, "R": R, "Td": Td, "nreal": nreal,
+            "C": C, "R": R, "Td": Td,
         }
 
     def msearch_partials(self, fld, queries, k):
@@ -2461,8 +2557,7 @@ class _FusedShardedMsearch:
         Q = len(queries)
         idxs, pb = self._plan_batch(fld, queries, k)
         interpret = jax.default_backend() != "tpu"
-        fn = self._compiled(fld, pb["C"], pb["R"], pb["Td"], k,
-                            pb["nreal"], interpret)
+        fn = self._compiled(fld, pb["C"], pb["R"], pb["Td"], k, interpret)
         from ..telemetry import profile_event, time_kernel
 
         profile_event("tier", tier="fused", queries=Q)

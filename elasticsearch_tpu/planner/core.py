@@ -283,13 +283,16 @@ class ExecutionPlanner:
 
     # -- arm choice ---------------------------------------------------------
 
-    def choose_arm(self, site: str, candidates) -> str:
+    def choose_arm(self, site: str, candidates, model: bool = True) -> str:
         """Pick one arm for a dispatch. `candidates` is a list of
         (arm, kernel, fields) in TODAY'S static priority order; the
         last entry must be the always-correct exact arm. Returns the
         arm name. Cold (any surviving candidate unpredictable) ->
         static fallback = first survivor, so an empty-EMA planner is
-        byte-identical to the pre-planner routing."""
+        byte-identical to the pre-planner routing. `model=False` keeps
+        the static order whatever the EMAs hold (repricing still
+        applies): for a site whose arms are families of compiled
+        programs, where a flip of the model's mind costs a family."""
         t0 = time.perf_counter()
         alive = [c for c in candidates if not self.repriced(c[0])]
         mode = "static"
@@ -300,7 +303,7 @@ class ExecutionPlanner:
             mode = "repriced"
         chosen = alive[0]
         predicted: dict[str, float] = {}
-        if self.enabled and len(alive) > 1:
+        if model and self.enabled and len(alive) > 1:
             preds = []
             with self._lock:
                 for arm, kernel, fields in alive:

@@ -1975,9 +1975,12 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                     if t_raw is None:
                         t_raw = engine.settings.get(
                             "search.default_search_timeout")
-                    res = await sv.submit_async(
+                    served = sv.submit(
                         entry, tenant=tenant,
                         timeout_s=parse_duration_seconds(t_raw, None))
+                    res = await asyncio.wrap_future(served)
+                    # the wave's stages, as this search's own
+                    sv.member_spans(served)
                 else:
                     res = await call(
                         engine_search, engine.search_multi, expression,

@@ -45,6 +45,9 @@ class PendingSearch:
     tenant: str
     future: Future = field(default_factory=Future)
     enqueue_t: float = field(default_factory=time.monotonic)
+    # the same two instants on the spans' clock (`time.perf_counter_ns`)
+    enqueue_ns: int = field(default_factory=time.perf_counter_ns)
+    claim_ns: int = 0              # set when a wave claims the entry
     deadline: float | None = None  # monotonic; None = no timeout
     task: object | None = None     # tasks.Task while queued/running
     est_bytes: int = 4096          # in_flight_requests breaker charge

@@ -171,6 +171,9 @@ class ServingService:
         # size a wave to the arrivals one drain period can deliver
         self._arrival_rate_ema: float | None = None
         self._last_arrival: float | None = None
+        self._last_gap: float | None = None   # between the last two arrivals
+        self._arrived = 0                     # members since the last close
+        self._last_close = time.monotonic()
         # flight recorder (PR 12): bounded ring of per-wave records —
         # segment timings (admission→claim→dispatch→device→complete),
         # tenant/lane mix, per-kernel utilization deltas, cache traffic,
@@ -186,6 +189,18 @@ class ServingService:
         # (a rejected request that keeps its reservation is a slow leak)
         self._reserved_bytes = 0
         _LIVE_SERVICES.add(self)
+        # what a wave adds to (`_count_wave`) reads 0 from the start, not
+        # nothing: a node whose front end is off has served no wave
+        from ..telemetry import WAVE_STAGES, metrics
+
+        metrics.counters_add(
+            [((f"es.span.{s}.ns", f"es.span.{s}.count"), (0, 0))
+             for s in WAVE_STAGES] + [
+                (("es.serving.wave.count", "es.serving.wave.members"), (0, 0)),
+                (("es.serving.wave.padded_rows", "es.serving.wave.wait_ns"),
+                 (0, 0)),
+                (("es.jit.cache.wave_program.misses",
+                  "es.jit.cache.wave_program.hits"), (0, 0))])
 
     # ---- settings consumers ---------------------------------------------
 
@@ -360,11 +375,9 @@ class ServingService:
             with self._cv:
                 self._tenants.push(ps)
                 self.counters["admitted"] += 1
+                self._arrived += 1
                 if self._last_arrival is not None:
-                    inst = 1.0 / max(now - self._last_arrival, 1e-6)
-                    self._arrival_rate_ema = (
-                        inst if self._arrival_rate_ema is None
-                        else 0.8 * self._arrival_rate_ema + 0.2 * inst)
+                    self._last_gap = now - self._last_arrival
                 self._last_arrival = now
                 metrics.gauge_set("es.serving.queue_depth",
                                   self._tenants.depth)
@@ -378,11 +391,6 @@ class ServingService:
             raise
         self._ensure_threads()
         return ps.future
-
-    async def submit_async(self, entry: dict, **kw):
-        import asyncio
-
-        return await asyncio.wrap_future(self.submit(entry, **kw))
 
     def submit_merge(self, fn, *, index: str = "", est_bytes: int = 1024):
         """Admit one background DEVICE index merge as the low-weight
@@ -470,7 +478,7 @@ class ServingService:
         from ..planner import execution_planner
 
         deadline = None
-        eff_wave = self.max_wave
+        eff_wave, eff_wait = self.max_wave, self.max_wait_s
         while not self._stop:
             with self._cv:
                 depth = self._tenants.depth
@@ -478,25 +486,53 @@ class ServingService:
                     deadline = None
                     self._cv.wait(0.05)
                     continue
-                # PR 18: the planner sizes the wave to depth + expected
-                # arrivals during one measured drain period, and shrinks
-                # the coalesce window to the time those arrivals need
-                # (cold EMAs -> the configured values, unchanged)
-                eff_wave, eff_wait = execution_planner().advise_wave_close(
-                    self.max_wave, self.max_wait_s, depth,
-                    self._wave_ms_ema, self._arrival_rate_ema)
+                if deadline is None:
+                    # PR 18: the planner sizes the wave to depth + expected
+                    # arrivals during one measured drain period, and shrinks
+                    # the coalesce window to the time those arrivals need
+                    # (cold EMAs -> the configured values, unchanged). Asked
+                    # once a wave, at its first member: a target that moved
+                    # up with the depth at every look was never reached,
+                    # and every busy wave sat out its whole window.
+                    eff_wave, eff_wait = execution_planner().advise_wave_close(
+                        self.max_wave, self.max_wait_s, depth,
+                        self._wave_ms_ema, self._arrival_rate_ema)
+                    deadline = time.monotonic() + eff_wait
                 if depth >= eff_wave:
                     break
-                if self._inflight_count == 0:
-                    break  # pipeline idle: dispatch promptly
-                if deadline is None:
-                    deadline = time.monotonic() + eff_wait
+                if self._inflight_count == 0 and self._stream_sparse():
+                    break  # a lone request on an idle pipeline never waits
                 if time.monotonic() >= deadline:
                     break
                 self._cv.wait(max(min(eff_wait, 0.005), 0.0005))
         if self._stop:
             return []
-        return self._tenants.pop_wave(eff_wave)
+        wave = self._tenants.pop_wave(eff_wave)
+        self._note_wave_closed()
+        return wave
+
+    def _stream_sparse(self) -> bool:
+        """Whether arrivals lie further apart than the coalescing window, so
+        that holding a wave open would gather nobody (caller holds _cv).
+        Read off the last gap alone, not off an average: under one load
+        the answer is the same whatever came before."""
+        gap = self._last_gap
+        return gap is None or gap > self.max_wait_s
+
+    def _note_wave_closed(self) -> None:
+        """The arrival rate the wave-close advisory reads: members admitted
+        since the last close over the time since, smoothed over waves (the
+        reciprocal of single gaps, which it replaces, reads a burst of
+        two as a million a second)."""
+        now = time.monotonic()
+        with self._cv:
+            n, self._arrived = self._arrived, 0
+            dt, self._last_close = now - self._last_close, now
+        if n and dt > 0:
+            inst = n / dt
+            self._arrival_rate_ema = (
+                inst if self._arrival_rate_ema is None
+                else 0.8 * self._arrival_rate_ema + 0.2 * inst)
 
     def _scheduler_loop(self):
         from ..telemetry import metrics
@@ -506,7 +542,7 @@ class ServingService:
                 wave = self._close_wave()
                 if self._stop:
                     break
-                now = time.monotonic()
+                now, now_ns = time.monotonic(), time.perf_counter_ns()
                 ready = []
                 dropped = {"expired": 0, "cancelled": 0}
                 meter = self._meter()
@@ -529,6 +565,7 @@ class ServingService:
                         "es.serving.coalesce_wait_ms", wait_ms)
                     if meter is not None:
                         meter.note_queue_wait(ps.tenant, wait_ms)
+                    ps.claim_ns = now_ns
                     ready.append(ps)
                 metrics.gauge_set(
                     "es.serving.queue_depth", self._tenants.depth)
@@ -590,7 +627,8 @@ class ServingService:
 
             try:
                 faults.check("serving.wave", n=state["n"])
-                with collect_profile_events() as events:
+                with collect_profile_events() as events, \
+                        state["stages"].stage("engine.wave_fetch"):
                     for idx, _members, job in state["jobs"]:
                         # engine-state-free device pull: overlaps the
                         # engine thread's planning of the next wave
@@ -665,6 +703,19 @@ class ServingService:
                 tc["kernels"].get(c["kernel"], 0.0) + (c["weight"] or 1.0))
 
     def _wave_begin(self, ready: list[PendingSearch]) -> dict:
+        """Plan and launch one wave (engine thread): the stage
+        `engine.wave_plan`, less the launches inside it, each of which is
+        an `engine.wave_launch` of its own (parallel/sharded._wave_launch)."""
+        from ..telemetry import WaveStages
+
+        stages = WaveStages()
+        with stages.stage("engine.wave_plan"):
+            state = self._wave_begin_staged(ready)
+        state["stages"] = stages
+        state["members"] = ready
+        return state
+
+    def _wave_begin_staged(self, ready: list[PendingSearch]) -> dict:
         from ..telemetry import collect_profile_events
 
         tenants: dict[str, int] = {}
@@ -766,6 +817,74 @@ class ServingService:
         return state
 
     def _wave_finish(self, state: dict):
+        """Build and hand out the wave's answers (engine thread): the stage
+        `engine.wave_finish`; then the wave's stages and counts reach the
+        counters at once, and each member learns its share of them."""
+        stages = state["stages"]
+        with stages.stage("engine.wave_finish"):
+            rows = self._wave_finish_staged(state)
+        self._count_wave(state, stages, rows)
+        for ps, res in state.pop("answers", ()):
+            if isinstance(res, Exception):
+                self._finish_entry(ps, error=res)
+            else:
+                self._finish_entry(ps, result=res)
+
+    def _count_wave(self, state: dict, stages, rows: int) -> None:
+        """What one wave adds to `_nodes/stats` -> metrics.counters, under
+        one acquisition of the lock: its four stages (`es.span.engine.
+        wave_*.ns` / `.count`), 1 to `es.serving.wave.count`, its members,
+        the rows its programs computed for them (a term lane's batch tier,
+        one for any other member) and the nanoseconds the members waited
+        between admission and the claim. A member's own `rest.search` gets
+        an equal share of each stage through `member_spans`."""
+        from ..telemetry import metrics
+
+        members = state["members"]
+        n = len(members)
+        wait_ns = sum(ps.claim_ns - ps.enqueue_ns for ps in members)
+        metrics.counters_add(stages.counter_pairs() + [
+            (("es.serving.wave.count", "es.serving.wave.members"), (1, n)),
+            (("es.serving.wave.padded_rows", "es.serving.wave.wait_ns"),
+             (rows, wait_ns)),
+        ])
+        done_ns = time.perf_counter_ns()
+        parse_ns = sum(job.get("meta", {}).get("parse_ns", 0)
+                       for _idx, _members, job in state["jobs"])
+        for ps in members:
+            ps.future.wave = (ps.enqueue_ns, ps.claim_ns, done_ns, n, stages,
+                              parse_ns)
+
+    @staticmethod
+    def member_spans(future) -> None:
+        """Record, in the caller's context (the member's `rest.search`),
+        what a served search spent where: `engine.queue` from admission to
+        the claim, `engine.search` from the claim to the answer, and inside
+        it a 1/n share of each of the wave's stages under the name the solo
+        path gives that work (`engine.plan`, `.dispatch`, `.fetch`,
+        `.collect`): engine- and completer-thread time a search, as in the
+        cells without the front end. The rest of `engine.search` is the
+        wait for the wave: the other members' shares, the device, the
+        hand-offs between the threads."""
+        from ..telemetry import TRACER
+
+        wave = getattr(future, "wave", None)
+        if wave is None:
+            return
+        enq, claim, done, n, stages, parse_ns = wave
+        TRACER.record("engine.queue", enq, claim)
+        shares, at = [], claim
+        for solo, ns in (
+                ("engine.parse", parse_ns),
+                ("engine.plan", stages.ns("engine.wave_plan") - parse_ns),
+                ("engine.dispatch", stages.ns("engine.wave_launch")),
+                ("engine.fetch", stages.ns("engine.wave_fetch")),
+                ("engine.collect", stages.ns("engine.wave_finish"))):
+            shares.append((solo, at, at + max(ns, 0) // n))
+            at = shares[-1][2]
+        TRACER.record("engine.search", claim, done, children=shares)
+
+    def _wave_finish_staged(self, state: dict) -> int:
         from ..telemetry import collect_profile_events, metrics
 
         err = state.get("fetch_error")
@@ -791,17 +910,16 @@ class ServingService:
                  "fallback_solo": state.get("fallback_solo", 0)}
         occ = []
         indices = []
+        rows = 0
         with collect_profile_events() as fin_events:
             for idx, members, job in state["jobs"]:
                 if err is not None:
                     results = self._rescue_solo(members)
                 else:
                     results = idx.search_wave_finish(job)
-                for ps, res in zip(members, results):
-                    if isinstance(res, Exception):
-                        self._finish_entry(ps, error=res)
-                    else:
-                        self._finish_entry(ps, result=res)
+                # handed out by `_wave_finish` once the wave is counted, so
+                # that no member wakes before its share of the stages is known
+                state.setdefault("answers", []).extend(zip(members, results))
                 # a superpack job serves MANY indices: report the member
                 # names (ordered, unique), not the job owner's synthetic
                 # "_superpack" — flight records must name real tenants
@@ -823,7 +941,9 @@ class ServingService:
                         "term_packed", 0)
                     self._disp_sum += tr.get("dispatch", 0)
                     self._fetch_sum += tr.get("fetch", 0)
+                rows += len(members) - meta.get("term_packed", 0)
                 for q, tier in meta.get("term_waves", ()):
+                    rows += tier
                     metrics.histogram_record(
                         "es.serving.wave_occupancy", q / max(tier, 1))
                     occ.append(q / max(tier, 1))
@@ -850,6 +970,7 @@ class ServingService:
             self._apply_fairshare()
         except Exception:  # noqa: BLE001 - advisory, never fails a wave
             pass
+        return rows
 
     def _rescue_solo(self, members) -> list:
         """Re-run a poisoned wave's members one by one on the classic
@@ -1179,6 +1300,8 @@ class ServingService:
             self._occ_sum = self._occ_n = 0
             self._size_sum = 0
             self._disp_sum = self._fetch_sum = 0
-            self._wave_ms_ema = None
+            self._wave_ms_ema = self._arrival_rate_ema = None
+            self._last_arrival = self._last_gap = None
+            self._arrived = 0
             self._flight.clear()
             self._wave_seq = 0

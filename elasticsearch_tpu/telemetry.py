@@ -21,7 +21,7 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 
 log = logging.getLogger("elasticsearch_tpu")
@@ -144,14 +144,25 @@ def context_from_headers(headers: dict | None) -> TraceContext | None:
 # fetch. Inside a `rest.search` these names are summed into
 # `es.span.<name>.ns` / `.count` (what `_nodes/stats` -> metrics.counters
 # ships and the benchmark's per-layer readers divide into a mean a search).
-# Any other span, and a stage entered from elsewhere (`_msearch`, the serving
-# wave, a library call), is recorded and summed nowhere: the counters stay
-# one search's, and a span named by a request's data can never mint one.
+# Any other span, and a stage entered from elsewhere (`_msearch`, a library
+# call), is recorded and summed nowhere: the counters stay one search's, and
+# a span named by a request's data can never mint one. A search that the
+# serving front end answered records them from its wave's stages
+# (`ServingService.member_spans`).
 STAGES = (
     "rest.search", "engine.queue", "engine.search", "engine.parse",
     "engine.plan", "engine.dispatch", "engine.fetch", "engine.collect",
     "rest.respond",
 )
+# The stages of a serving wave (serving/service.py), which several members
+# share and two threads carry: host planning on the engine thread up to each
+# program's launch, the launches, the one combined fetch on the completer
+# thread, the finish on the engine thread. `WaveStages` sums them and the
+# service adds a wave's to `es.span.<stage>.ns` / `.count` at once when the
+# wave ends, as a search's are when its `rest.search` ends. They never
+# nest (a launch pauses the planning around it), so all four are leaves.
+WAVE_STAGES = ("engine.wave_plan", "engine.wave_launch", "engine.wave_fetch",
+               "engine.wave_finish")
 # The leaves among them also open a `jax.profiler.TraceAnnotation` while a
 # capture runs, which puts them on its host plane, on the capture's clock,
 # beside PJRT's own events. Parents stay off it: an idle gap of the device
@@ -159,10 +170,10 @@ STAGES = (
 # would cover every gap and name none.
 ANNOTATED_STAGES = frozenset({
     "engine.parse", "engine.plan", "engine.dispatch", "engine.fetch",
-    "engine.collect", "rest.respond",
+    "engine.collect", "rest.respond", *WAVE_STAGES,
 })
 _STAGE_COUNTERS = {name: (f"es.span.{name}.ns", f"es.span.{name}.count")
-                   for name in STAGES}
+                   for name in STAGES + WAVE_STAGES}
 _annotation = None  # jax.profiler.TraceAnnotation while a capture runs
 
 
@@ -290,13 +301,21 @@ class Tracer:
         return Span(self, name, attributes)
 
     def record(self, name: str, start_ns: int, end_ns: int,
-               **attributes) -> Span:
+               children=(), **attributes) -> Span:
         """A finished span from two `time.perf_counter_ns()` readings, as a
-        child of the current span: counters and span tree, no annotation."""
+        child of the current span: counters and span tree, no annotation.
+        `children` are (name, start_ns, end_ns) of finished spans under it."""
         s = Span(self, name, attributes)
         s._t0 = start_ns
         if s._wall is not None:
             s._wall -= (time.perf_counter_ns() - start_ns) * 1e-9
+        if children:
+            token = self._current.set(s)
+            try:
+                for child in children:
+                    self.record(*child)
+            finally:
+                self._current.reset(token)
         self._finish(s, end_ns)
         return s
 
@@ -347,6 +366,67 @@ class Tracer:
 
 
 TRACER = Tracer()
+
+
+class WaveStages:
+    """The stage spans of one serving wave, summed: `stage(name)` is a span
+    of WAVE_STAGES around work of the calling thread. Entered inside another
+    stage of the same wave on the same thread (a program's launch inside the
+    planning) it pauses that one, so the stages lie end to end and their sum
+    is wall time of the threads that carried the wave. `sums` is
+    {stage: [ns, spans]}; `counter_pairs()` is what `metrics.counters_add`
+    takes once the wave has ended."""
+
+    __slots__ = ("sums", "_open")
+
+    def __init__(self):
+        self.sums: dict[str, list] = {}
+        self._open: list = []     # (name, span) entered and not left, in order
+
+    def _enter(self, name: str) -> None:
+        span = TRACER.span(name)
+        span.__enter__()
+        self._open.append((name, span))
+
+    def _leave(self) -> None:
+        name, span = self._open.pop()
+        span.__exit__(None, None, None)
+        rec = self.sums.setdefault(name, [0, 0])
+        rec[0] += span._t1 - span._t0
+        rec[1] += 1
+
+    @contextmanager
+    def stage(self, name: str):
+        outer = self._open[-1][0] if self._open else None
+        if outer is not None:
+            self._leave()
+        token = _wave_stages.set(self)
+        self._enter(name)
+        try:
+            yield
+        finally:
+            self._leave()
+            _wave_stages.reset(token)
+            if outer is not None:
+                self._enter(outer)
+
+    def ns(self, name: str) -> int:
+        return self.sums.get(name, (0, 0))[0]
+
+    def counter_pairs(self):
+        return [(_STAGE_COUNTERS[name], rec)
+                for name, rec in self.sums.items()]
+
+
+_wave_stages: contextvars.ContextVar[WaveStages | None] = \
+    contextvars.ContextVar("wave_stages", default=None)
+
+
+def wave_stage(name: str):
+    """`stage(name)` of the wave this thread is carrying a stage of, and
+    nothing outside one (a solo `_msearch` launches the same programs)."""
+    ws = _wave_stages.get()
+    return ws.stage(name) if ws is not None else nullcontext()
 
 
 def stitch_trace(spans: list[dict]) -> dict:

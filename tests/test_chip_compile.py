@@ -341,8 +341,8 @@ def test_sharded_fused_region_compiles_on_four_chips(mesh4,
         "tier16_stack": _sds((S, fs._vp2, fs.n_pad), jnp.bfloat16, sharded),
         "live": _sds((S, 1, fs.n_pad), jnp.float32, sharded),
     }
-    C, R, Td, nreal = 1, 4096, 4, 3000
-    fn = fs._compiled_merged("body", C, R, Td, TOP_K, nreal, False)
+    C, R, Td = 1, 4096, 4
+    fn = fs._compiled_merged("body", C, R, Td, TOP_K, False)
     compiled = fn.lower(
         fa, _sds((), jnp.float32, replicated),
         _sds((S, C, R), jnp.int32, sharded),
@@ -353,6 +353,47 @@ def test_sharded_fused_region_compiles_on_four_chips(mesh4,
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
+
+
+def test_the_exact_wave_program_compiles_without_f64_or_a_whole_row_sort(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The merged exact arm (the fused arm's escalation) at the serving
+    cell's width, 294,912 passages on one chip, at the batch tier the
+    escalation pads to: no f64 (the prefix sum that was 147 s of a 178 s
+    compile, PR 35), and no sort over a whole row of documents (a
+    `lax.top_k` of a rank-3 operand): the only sort left is the candidates'."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.index.pack import BLOCK
+    from elasticsearch_tpu.parallel import sharded
+
+    n, v_dense, nb = 294_912, 1152, 500_000
+    ts, b, q = 4, 8, sharded.ESCALATION_MIN_TIER
+    monkeypatch.setattr(sharded, "_msearch_stack_plans", lambda *a, **k: {
+        "W": np.zeros((1, q, v_dense), np.float32),
+        "rows": np.zeros((1, q, ts, b), np.int32),
+        "ws": np.zeros((1, q, ts), np.float32),
+        "avgdl": 56.0, "has_norms": True, "kk": TOP_K})
+    ss = types.SimpleNamespace(
+        sp=types.SimpleNamespace(S=1, n_max=n), mesh=None, _cache={},
+        dev=dict.fromkeys(("post_docids", "post_tfs", "post_dls", "live",
+                           "dense_tfn")))
+    fn, _args, kk = sharded._msearch_merged_arm_begin(
+        ss, "body", [[]] * q, TOP_K, impact=False, _return_program=True)
+    dev = {"post_docids": _sds((1, nb, BLOCK), jnp.int32, one_chip),
+           "post_tfs": _sds((1, nb, BLOCK), jnp.float32, one_chip),
+           "post_dls": _sds((1, nb, BLOCK), jnp.float32, one_chip),
+           "live": _sds((1, n), jnp.bool_, one_chip),
+           "dense_tfn": _sds((1, v_dense, n), jnp.float32, one_chip)}
+    text = fn.lower(
+        dev, _sds((1, q, v_dense), jnp.float32, one_chip),
+        _sds((1, q, ts, b), jnp.int32, one_chip),
+        _sds((1, q, ts), jnp.float32, one_chip),
+        _sds((1, q, ts), jnp.float32, one_chip)).compile().as_text()
+    assert not re.search(r"\bf64\[", text)
+    sorted_widths = {int(w) for w in re.findall(
+        r"= \(?[a-z0-9]+\[\d+,(\d+)\][^=]* sort\(", text)}
+    assert sorted_widths and max(sorted_widths) <= ts * b * BLOCK, sorted_widths
 
 
 def test_device_build_kernels_compile_at_one_bulk_burst(

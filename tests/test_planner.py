@@ -89,6 +89,23 @@ def test_env_kill_switch(monkeypatch):
     assert pl.choose_arm("batched.msearch", CANDS) == "fused"
 
 
+def test_a_site_of_program_families_keeps_the_static_order_when_warm():
+    """PR 35: `model=False` (the merged msearch route, whose arms are
+    families of compiled programs): warm EMAs that would send the model to
+    the impact arm leave the first candidate chosen; repricing still
+    filters."""
+    pl = execution_planner()
+    _warm(pl, {"fused.pallas_scan": 0.001, "sparse.impact_sum": 0.9,
+               "batched.disjunction": 0.2})
+    assert pl.choose_arm("batched.msearch", CANDS) == "impact"
+    for _ in range(5):
+        assert pl.choose_arm("batched.msearch", CANDS, model=False) == "fused"
+    assert pl.stats()["decision_modes"]["static"] == 5
+    with pl.reprice(["fused"], reason="oom"):
+        assert pl.choose_arm("batched.msearch", CANDS,
+                             model=False) == "impact"
+
+
 def test_warm_model_picks_argmin_deterministically():
     pl = execution_planner()
     # fused priced terribly, impact excellent, exact mediocre

@@ -546,3 +546,99 @@ def test_512_way_concurrency_parity(served):
     # the whole point: far fewer device waves than requests
     assert st["waves"] < 512 / 4, f"no coalescing: {st['waves']} waves"
     assert st["term_packed"] > 0
+
+
+# ---- PR 35: what a wave adds to the counters and the span tree ------------
+
+
+def _wave_counters():
+    from elasticsearch_tpu.telemetry import metrics
+
+    c = metrics.snapshot()["counters"]
+    return {k: v for k, v in c.items()
+            if k.startswith(("es.serving.wave.", "es.span."))}
+
+
+def test_a_wave_adds_its_members_rows_wait_and_stages(served):
+    """A wave of n term-lane members adds 1 wave, n members and tier(n)
+    rows; its four stages lie end to end inside its wall; and a member's
+    own `rest.search` records the wave's stages under the solo path's names,
+    an n-th of each."""
+    from elasticsearch_tpu.ops.batched import BatchTermSearcher
+    from elasticsearch_tpu.telemetry import TRACER, WAVE_STAGES
+
+    engine, idx, svc = served
+    assert idx.searcher is not None   # merges the tiers: one base, a term lane
+    bodies = [{"query": {"match": {"title": w}}, "size": 5}
+              for w in ("alpha", "beta gamma", "delta", "common", "epsilon")]
+    n = len(bodies)
+    entries = [svc.classify("idx", b, {}) for b in bodies]
+    before = _wave_counters()
+    # shipped from the service's start, at 0: every cell's readers find them
+    assert before["es.serving.wave.count"] >= 0
+    assert "es.span.engine.wave_fetch.ns" in before
+    # admitted under the scheduler's own lock, so that it finds all five at
+    # its first look and closes them into one wave
+    with svc._cv:
+        futs = [svc.submit(e) for e in entries]
+    wait(futs, timeout=120)
+    assert all(f.exception() is None for f in futs)
+    after = _wave_counters()
+    added = {k: after[k] - before.get(k, 0) for k in after}
+    assert added["es.serving.wave.count"] == 1
+    assert added["es.serving.wave.members"] == n
+    assert added["es.serving.wave.padded_rows"] \
+        == BatchTermSearcher.wave_q_tier(n) == 8
+    assert added["es.serving.wave.wait_ns"] > 0
+    rec = svc.flight_recorder()["waves"][-1]
+    assert rec["size"] == n
+    stage_ns = [added[f"es.span.{s}.ns"] for s in WAVE_STAGES]
+    assert all(ns > 0 for ns in stage_ns)
+    assert [added[f"es.span.{s}.count"] for s in WAVE_STAGES][2:] == [1, 1]
+    # plan is split by its launch: one span before it and one behind it
+    assert added["es.span.engine.wave_launch.count"] >= 1
+    assert added["es.span.engine.wave_plan.count"] \
+        == added["es.span.engine.wave_launch.count"] + 1
+    seg = rec["segments_ms"]
+    assert sum(stage_ns) / 1e6 <= seg["plan"] + seg["device"] + seg["finish"]
+    # a member's own stages, recorded in its request's context
+    with TRACER.span("rest.search"):
+        svc.member_spans(futs[0])
+    member = _wave_counters()
+    moved = {k: member[k] - after.get(k, 0) for k in member}
+    for stage in ("engine.queue", "engine.search", "engine.parse",
+                  "engine.plan", "engine.dispatch", "engine.fetch",
+                  "engine.collect", "rest.search"):
+        assert moved[f"es.span.{stage}.count"] == 1, stage
+    shares = sum(moved[f"es.span.engine.{s}.ns"]
+                 for s in ("parse", "plan", "dispatch", "fetch", "collect"))
+    assert 0 < shares <= sum(stage_ns) // n + 5
+    assert shares <= moved["es.span.engine.search.ns"]
+    assert moved["es.span.engine.queue.ns"] * n \
+        <= added["es.serving.wave.wait_ns"] * n   # its own wait, not a share
+    # a future that no wave answered records nothing
+    from concurrent.futures import Future
+
+    svc.member_spans(Future())
+
+
+def test_a_dense_stream_holds_the_window_and_a_lone_request_does_not(served):
+    """The idle-pipeline shortcut is for a sparse stream: a request whose
+    predecessor came within the coalescing window waits for company, a lone
+    one is dispatched at once, whatever came before."""
+    engine, _idx, svc = served
+    svc.set_max_wait("50ms")
+    entry = lambda: svc.classify(  # noqa: E731
+        "idx", {"query": {"match": {"title": "alpha"}}, "size": 5}, {})
+    t0 = time.monotonic()
+    svc.submit(entry()).result(timeout=60)          # the first ever: alone
+    assert svc.stats()["waves"] == 1
+    with svc._cv:                                    # two, a moment apart
+        futs = [svc.submit(entry()), svc.submit(entry())]
+    wait(futs, timeout=60)
+    assert svc.stats()["waves"] == 2, "the pair rode one wave"
+    time.sleep(0.12)                                 # the stream thins out
+    t1 = time.monotonic()
+    svc.submit(entry()).result(timeout=60)
+    assert svc.stats()["waves"] == 3
+    assert time.monotonic() - t1 < 0.05 + (t1 - t0), "a lone request waited"

@@ -577,3 +577,241 @@ def test_spmd_mode_resolution(monkeypatch):
     assert spmd_mode() == "shardmap"
     monkeypatch.setenv("ES_TPU_SPMD", "pjit")
     assert spmd_mode() == "pjit"
+
+
+# ---------------------------------------------------------------------------
+# PR 35: one bounded family of wave programs; a row does not depend on its wave
+# ---------------------------------------------------------------------------
+
+def _bench():
+    """The benchmark's generator, reference and comparison (no JAX)."""
+    import os
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    path = os.path.join(os.path.dirname(here), "benchmark")
+    if path not in sys.path:
+        sys.path.insert(0, path)
+    from benchlib import compare, corpus, reference, stats
+
+    return compare, corpus, reference, stats
+
+
+_WAVE_DOCS, _WAVE_POOL, _WAVE_SEED = 2048, 128, 3500000023
+_WAVE_CORPUS = {"generator": "zipf_text", "vocab": 3000, "zipf_s": 1.0,
+                "doc_len_mean": 56, "doc_len_sd": 25, "doc_len_min": 4}
+# the source's query shapes (benchmark/configs/msmarco-passage-1shard.json)
+_WAVE_QUERY = {"from": "documents", "terms_share": {
+    "1": 1, "2": 4, "3": 11, "4": 16, "5": 17, "6": 15, "7": 12, "8": 9,
+    "9": 6, "10": 4, "11": 2, "12": 3}}
+_WAVE_LIMITS = {"total_wrong": 0, "rank_gap": 2e-4, "score_gap": 2e-4,
+                "order_wrong": 0, "repeat_diff": 0}
+
+
+def _wave_pack(dense_min_df):
+    _cmp, gen, reference, _stats = _bench()
+    c = gen.build_corpus(_WAVE_SEED, _WAVE_DOCS, _WAVE_CORPUS)
+    pool = gen.build_pool(_WAVE_SEED, c, _WAVE_QUERY, _WAVE_POOL)
+    docs = [(str(d), {"body": " ".join(
+        f"t{t}" for t in c.tok[c.starts[d]:c.starts[d] + c.lens[d]])})
+        for d in range(c.n_docs)]
+    pack = build_stacked_pack(
+        docs, Mappings({"properties": {"body": {"type": "text"}}}),
+        num_shards=1, dense_min_df=dense_min_df)
+    return pack, pool, reference.Reference(c.lens, c.tok, 1)
+
+
+@pytest.fixture(scope="module")
+def wave_corpus():
+    """A seeded small corpus of the benchmark's own generator on one shard,
+    with a dense tier (df >= 64), its pool of the source's query shapes and
+    the plain reference over the same arrays."""
+    return _wave_pack(64)
+
+
+@pytest.fixture(scope="module")
+def wave_corpus_sparse():
+    """The same corpus with every term in the postings (no dense tier)."""
+    return _wave_pack(10 ** 9)
+
+
+def _wave_terms(pool, members):
+    return [[(f"t{t}", 1.0) for t in pool[m]] for m in members]
+
+
+_ARMS = {"exact": {}, "impact": {"ES_TPU_IMPACT": "1"},
+         "fused": {"ES_TPU_FUSED": "force"}}
+
+
+def _arm_searcher(pack, arm, monkeypatch):
+    monkeypatch.setenv("ES_TPU_REQUEST_CACHE", "0")
+    for key, value in _ARMS[arm].items():
+        monkeypatch.setenv(key, value)
+    ss = _searcher(pack, "pjit", monkeypatch, mesh=False)
+    if arm == "impact":
+        ss.refresh_impacts()
+    return ss
+
+
+@pytest.mark.parametrize("arm", list(_ARMS))
+def test_a_row_does_not_depend_on_its_wave_and_is_the_references(
+        wave_corpus, wave_corpus_sparse, arm, monkeypatch):
+    """The same query alone, in a wave of 7 and in a wave of 64 with other
+    companions: bit-identical rows; and held against the NumPy float64 BM25
+    of `benchmark/benchlib/reference.py` by the benchmark's own comparison
+    under the cells' limits (totals exact, order right, scores and ranks
+    within 2e-4, no answer to a query differing from its first). The fused
+    arm on the pack with a dense tier (its batch is one 512-row chunk
+    whatever the wave); the exact and the impact arm on the pack without:
+    their dense tier is one f32 matmul of the wave's width, and the CPU
+    backend's matmul adds in an order that follows the width and the
+    thread count (1 ulp between widths; PERF.md section 7)."""
+    from elasticsearch_tpu.telemetry import collect_profile_events
+
+    compare, _gen, _reference, stats = _bench()
+    pack, pool, ref = wave_corpus if arm == "fused" else wave_corpus_sparse
+    ss = _arm_searcher(pack, arm, monkeypatch)
+    rng = np.random.default_rng(_WAVE_SEED)
+    probes = [int(q) for q in rng.choice(_WAVE_POOL, size=6, replace=False)]
+    # one of the longest queries is always probed, as the benchmark's sample
+    probes.append(max(range(_WAVE_POOL), key=lambda q: len(pool[q])))
+    answers = []
+    with collect_profile_events() as events:
+        for q in probes:
+            others = [m for m in range(_WAVE_POOL) if m != q]
+            waves = ([q],
+                     [int(m) for m in rng.choice(others, 3, False)] + [q]
+                     + [int(m) for m in rng.choice(others, 3, False)],
+                     [int(m) for m in rng.choice(others, 63, False)] + [q])
+            rows = []
+            for members in waves:
+                (v, s, d, t), tier = msearch_wave(
+                    ss, "body", _wave_terms(pool, members), k=10)
+                assert tier == 1 << (len(members) - 1).bit_length()
+                at = members.index(q)
+                rows.append((v[at], d[at], int(t[at])))
+                n = int(np.isfinite(v[at]).sum())
+                answers.append(stats.Request(
+                    q, 0.0, 0.0, 200, ids=[int(x) for x in d[at][:n]],
+                    scores=[float(x) for x in v[at][:n]],
+                    total={"value": int(t[at]), "relation": "eq"}))
+            for v, d, t in rows[1:]:
+                fin = np.isfinite(rows[0][0])
+                np.testing.assert_array_equal(v, rows[0][0])
+                assert (d[fin] == rows[0][1][fin]).all() and t == rows[0][2]
+    tiers = {e.get("tier") for e in events if e.get("kind") == "tier"}
+    assert arm in tiers, tiers
+    if arm == "fused":
+        # 21 waves of 1, 7 and 64 members: two programs, on the ladders
+        # (block rows 64 / 256 / 1,024, dense terms 16)
+        keys = {(k[3], k[4]) for k in _wave_program_keys(ss)
+                if k[0] == "merged"}
+        assert keys == {(64, 16), (256, 16)}, keys
+    verdict = compare.compare(ref, pool, answers, probes, 10, _WAVE_LIMITS)
+    assert verdict["correct"], verdict["numbers"]
+    assert verdict["compared"] == 3 * len(probes)
+
+
+def _wave_program_keys(ss):
+    from elasticsearch_tpu.parallel.sharded import _fused_sharded_for
+
+    keys = {k for k in ss._cache if k[0] == "msearch_merged"}
+    fs = getattr(ss, "_fused_msearch", None)
+    if fs is not None:
+        keys |= {k for k in fs._cache if k[0] == "merged"}
+    return keys
+
+
+def test_the_family_of_wave_programs_is_bounded(wave_corpus, monkeypatch):
+    """Several hundred waves of random membership, drawn from a seed, through
+    `msearch_wave_begin` on the exact arm: the distinct program keys are those
+    the ladders enumerate for this pool (Q tier x Ts tier x B tier of the
+    waves drawn), they stop growing, and every look-up was counted."""
+    from elasticsearch_tpu.ops.batched import BatchTermSearcher as B
+    from elasticsearch_tpu.parallel.sharded import (
+        msearch_wave_begin, msearch_wave_fetch, msearch_wave_finish)
+    from elasticsearch_tpu.telemetry import metrics
+
+    pack, pool, _ref = wave_corpus
+    ss = _arm_searcher(pack, "exact", monkeypatch)
+    view = pack.shard_view(0)
+
+    def shape(q):   # (sparse terms, longest sparse term's blocks) of a query
+        nbs = [view.term_blocks("body", f"t{t}")[1] for t in pool[q]
+               if view.dense_row_of("body", f"t{t}") is None
+               and view.term_blocks("body", f"t{t}")[2] > 0]
+        return len(nbs), max(nbs, default=0)
+
+    shapes = [shape(q) for q in range(_WAVE_POOL)]
+    rng = np.random.default_rng(_WAVE_SEED + 1)
+    before = dict(metrics.snapshot()["counters"])
+    expected, seen_after = set(), []
+    for n_wave in range(300):
+        members = rng.choice(_WAVE_POOL, size=int(rng.integers(1, 17)),
+                             replace=False)
+        st = msearch_wave_begin(ss, "body", _wave_terms(pool, members), k=10)
+        msearch_wave_fetch(st)
+        msearch_wave_finish(st)
+        expected.add((
+            B.wave_ts_tier(max(max(shapes[m][0] for m in members), 1)),
+            B.wave_b_tier(max(max(shapes[m][1] for m in members), 1)),
+            B.wave_q_tier(len(members))))
+        seen_after.append(len(_wave_program_keys(ss)))
+    keys = _wave_program_keys(ss)
+    assert {(k[3], k[4], k[6]) for k in keys} == expected
+    # bounded by the ladders, whatever the membership: 5 Q tiers here, the
+    # Ts tiers of 1-12 sparse terms, the B tiers of this pack's sparse terms
+    assert len(keys) <= 5 * 2 * 2
+    assert seen_after[-1] == seen_after[149], "the family still grows"
+    after = metrics.snapshot()["counters"]
+    added = {k: after.get(k, 0) - before.get(k, 0) for k in after}
+    assert added["es.jit.cache.wave_program.misses"] == len(keys)
+    assert added["es.jit.cache.wave_program.hits"] == 300 - len(keys)
+    assert added["es.jit.cache.msearch_merged.misses"] == len(keys)
+
+
+def test_an_escalation_reuses_the_tier_programs(wave_corpus, monkeypatch):
+    """The fused arm's escalation pads its flagged queries to the batch tier
+    (at least ESCALATION_MIN_TIER): 1, 3 and 5 flagged queries run ONE exact
+    program, and each escalated row is the row the exact arm gives the query
+    alone."""
+    from elasticsearch_tpu.parallel import sharded
+
+    pack, pool, _ref = wave_corpus
+    exact = _arm_searcher(pack, "exact", monkeypatch)
+    ss = _arm_searcher(pack, "fused", monkeypatch)
+    fs = sharded._fused_sharded_for(ss)
+    assert fs is not None and fs.usable(10)
+    members = list(range(8))
+    real_finish = sharded._merged_rows_finish
+    solo = {}
+    for n_flagged in (1, 3, 5):
+        st = fs.msearch_merged_begin("body", _wave_terms(pool, members), 10)
+        sharded._msearch_merged_fetch(st)
+        host = list(st["host"])
+        flags = np.zeros_like(np.asarray(host[4]))
+        flags[:n_flagged] = True          # as if the fused pass had flagged them
+        st["host"] = tuple(host[:4]) + (flags,)
+        v, s, d, t = st["finish"](st)
+        assert st["extra_dispatches"] == 1
+        for at in range(n_flagged):
+            q = members[at]
+            if q not in solo:
+                # alone at the escalation's width: on the CPU a dense
+                # product's last bit follows its row count
+                alone = _wave_terms(pool, [q]) + [[]] * (
+                    sharded.ESCALATION_MIN_TIER - 1)
+                # (the arm by name: ES_TPU_FUSED is read at every call)
+                st1 = sharded._msearch_merged_arm_begin(
+                    exact, "body", alone, 10, impact=False)
+                sharded._msearch_merged_fetch(st1)
+                v1, s1, d1, t1 = sharded._merged_rows_finish(st1)
+                solo[q] = (v1[0], d1[0], int(t1[0]))
+            fin = np.isfinite(solo[q][0])
+            np.testing.assert_array_equal(v[at], solo[q][0])
+            assert (d[at][fin] == solo[q][1][fin]).all()
+            assert int(t[at]) == solo[q][2]
+    assert sharded._merged_rows_finish is real_finish
+    exact_keys = {k for k in ss._cache if k[0] == "msearch_merged"}
+    assert {k[6] for k in exact_keys} == {sharded.ESCALATION_MIN_TIER}
+    assert len(exact_keys) <= 2      # one Q tier; the Ts tiers of 8 queries

@@ -20,7 +20,8 @@ import time
 import pytest
 
 from elasticsearch_tpu import telemetry
-from elasticsearch_tpu.telemetry import ANNOTATED_STAGES, STAGES, TRACER
+from elasticsearch_tpu.telemetry import (ANNOTATED_STAGES, STAGES, TRACER,
+                                         WAVE_STAGES)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORDS = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta"]
@@ -103,11 +104,12 @@ def test_stage_names_are_fit_for_the_readers():
         from benchlib import spans, trace
     finally:
         sys.path.pop(0)
-    assert ANNOTATED_STAGES < set(STAGES)
-    for name in STAGES:
+    every = STAGES + WAVE_STAGES
+    assert ANNOTATED_STAGES < set(every)
+    for name in every:
         assert not [w for w in trace.WAITING if w in name], name
         assert spans.STAGE.match(name), name
-    assert len(set(STAGES)) == len(STAGES)
+    assert len(set(every)) == len(every)
 
 
 def test_one_search_yields_one_tree_of_stages_and_moves_their_counters():
