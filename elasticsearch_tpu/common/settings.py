@@ -346,6 +346,12 @@ def default_cluster_settings() -> list[Setting]:
         # ones up to it. Raising it trades padded rows for fewer programs.
         Setting("serving.wave.min_tier", 1, Setting.at_least_one,
                 dynamic=True),
+        # the smallest rows tier of the solo path's `match` programs
+        # (query/nodes.match_tiers: powers of two from here): a match's
+        # sparse posting-block rows are padded up to it. Raising it trades
+        # padded rows for fewer programs, as serving.wave.min_tier does
+        Setting("search.solo.min_rows_tier", 8, Setting.at_least_one,
+                dynamic=True),
         # per-tenant weighted fair scheduling: "tenantA:4,tenantB:1"
         # (X-Opaque-Id is the tenant identity; unlisted tenants weigh 1)
         Setting("serving.tenant.weights", "", str, dynamic=True),

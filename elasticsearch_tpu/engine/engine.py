@@ -2285,6 +2285,16 @@ class Engine:
 
         self.settings.add_consumer("serving.wave.min_tier", _wave_min_tier)
         _wave_min_tier(self.settings.get("serving.wave.min_tier"))
+
+        def _solo_min_rows_tier(v):
+            from ..ops.batched import BatchTermSearcher
+            from ..query import nodes
+
+            nodes.MATCH_MIN_ROWS = BatchTermSearcher.pow2_tier(v)
+
+        self.settings.add_consumer("search.solo.min_rows_tier",
+                                   _solo_min_rows_tier)
+        _solo_min_rows_tier(self.settings.get("search.solo.min_rows_tier"))
         # adaptive execution planner (PR 18, planner/): push the dynamic
         # knobs into the process-wide planner singleton — the dispatch
         # sites consult it on every arm choice, so a settings update

@@ -324,6 +324,11 @@ class ShardPack:
         e = int(self.term_block_start[tid + 1])
         return s, e - s, int(self.term_df[tid])
 
+    def impact_served(self) -> bool:
+        """Whether sparse terms can score from the impact tier's codes."""
+        return (self.impact_codes is not None and self.impact_meta is not None
+                and self.impact_ubf is not None)
+
     def impact_wscale(self, fld: str, term: str) -> float | None:
         """ubf(t)/QMAX — the per-term dequantization scale of the impact
         tier; the query-time term weight is boost · idf · this. None when

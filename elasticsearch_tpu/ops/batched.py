@@ -890,8 +890,14 @@ class BatchTermSearcher:
         this tier so steady-state traffic reuses a small family of
         compiled programs, and reports q / wave_q_tier(q) as the wave's
         device occupancy."""
-        return max(cls.WAVE_MIN_TIER,
-                   1 << max(q - 1, 0).bit_length() if q > 1 else 1)
+        return cls.pow2_tier(q, cls.WAVE_MIN_TIER)
+
+    @staticmethod
+    def pow2_tier(n: int, floor: int = 1) -> int:
+        """The least power of two >= n, at least `floor`: the ladder every
+        batch tier of the wave programs and the solo path's `match` family
+        (query/nodes.match_tiers) are padded to."""
+        return max(floor, 1 << max(int(n) - 1, 0).bit_length())
 
     @staticmethod
     def _steps_of_four(n: int, floor: int) -> int:

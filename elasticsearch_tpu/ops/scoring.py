@@ -66,33 +66,6 @@ def impact_enabled() -> bool:
     return _jax.default_backend() == "tpu"
 
 
-def impact_term_scores(
-    impact_codes: jax.Array,  # [num_blocks, BLOCK] u16|i8 codes
-    post_docids: jax.Array,  # [num_blocks, BLOCK] int32 (pad: num_docs)
-    rows: jax.Array,  # [B] int32 block rows for this term (0-padded)
-    wscale: jax.Array,  # scalar f32: boost * idf * ubf / qmax
-    num_docs: int,
-) -> tuple[jax.Array, jax.Array]:
-    """Impact-tier scoring of one term: a pure gather+sum. No tf, no doc
-    length, no avgdl, no division — the code IS the (quantized) BM25
-    contribution, dequantized by one per-term scalar multiply.
-
-    Returns (scores[N+1] f32, match[N+1] bool) with identical padding /
-    dead-slot semantics to term_score_blocks (codes of padding lanes are
-    0, and tf > 0 postings always carry code >= 1)."""
-    codes = impact_codes[rows]  # [B, 128]
-    docids = post_docids[rows]
-    block_scores = wscale * codes.astype(jnp.float32)
-    flat_ids = docids.reshape(-1)
-    scores = jnp.zeros(num_docs + DEAD_SLOT_PAD, jnp.float32).at[flat_ids].add(
-        block_scores.reshape(-1), mode="drop"
-    )
-    match = jnp.zeros(num_docs + DEAD_SLOT_PAD, bool).at[flat_ids].set(
-        (codes > 0).reshape(-1), mode="drop"
-    )
-    return scores, match
-
-
 def term_score_blocks(
     post_docids: jax.Array,  # [num_blocks, BLOCK] int32
     post_tfs: jax.Array,  # [num_blocks, BLOCK] float32
@@ -151,21 +124,63 @@ def score_posting_arrays(
     return scores, match
 
 
-def dense_term_scores(
-    tfn_row: jax.Array,  # [N] f32 precomputed tf/(tf + K) for this term
-    weight: jax.Array,  # scalar f32: boost * idf
+def match_scores(
+    dev: dict,  # one shard's pack arrays (post_docids, ..., dense_tfn)
+    params: tuple,  # query/nodes.match_params: the padded lists and scalars
     num_docs: int,
+    k1: float,
+    b: float,
+    has_norms: bool,
+    impact: bool,
 ) -> tuple[jax.Array, jax.Array]:
-    """Score one dense-tier term (df above the dense threshold).
+    """Score a `match` (a term, or a bool of terms on one field) from its
+    two padded lists; every program of the solo path's family is this one
+    computation at another (dense tier, rows tier).
 
-    High-df terms are stored as dense tfn rows ([V_dense, N] in the pack);
-    scoring is a pure elementwise scale — no gather, no scatter. tfn > 0
-    iff tf > 0, so the row doubles as the match bitmap.
-    """
+    Sparse terms: their posting-block rows lie in ONE flat list with a
+    weight a row (padding: the reserved row 0, whose lanes carry docid N and
+    tf 0), gathered once and scatter-added once. A document is in a term's
+    blocks once, so the scatter adds at most one value a term into a slot,
+    each the f32 product the per-term path computed (`impact`: wscale x
+    code, a pure gather and sum; else BM25 over tf and the dl that rides in
+    the block). Dense terms: their precomputed tfn rows, weighted and added
+    one after another in list order (padding: weight 0, no match). A
+    document's match count is the number of terms that hold it; it matches
+    where the count reaches the plan's threshold (1 for a disjunction, the
+    term count for a conjunction, else minimum_should_match).
+
+    Returns (scores[N+1] f32, match[N+1] bool), dead slot N as
+    term_score_blocks has it."""
+    rows, rw, rs, dr, dw, dok, thr, boost, avgdl = params
     n1 = num_docs + DEAD_SLOT_PAD
-    scores = jnp.zeros(n1, jnp.float32).at[:num_docs].set(weight * tfn_row)
-    match = jnp.zeros(n1, bool).at[:num_docs].set(tfn_row > 0)
-    return scores, match
+    docids = dev["post_docids"][rows].reshape(-1)  # [R * 128]
+    if impact:
+        codes = dev["impact_codes"][rows]
+        vals = rs[:, None] * codes.astype(jnp.float32)
+        hit = codes > 0
+    else:
+        tfs = dev["post_tfs"][rows]
+        if has_norms:
+            denom = tfs + k1 * (1.0 - b + b * dev["post_dls"][rows] / avgdl)
+        else:
+            denom = tfs + k1
+        # tf==0 padding -> 0/k1' = 0
+        vals = rw[:, None] * tfs / denom
+        hit = tfs > 0
+    scores = jnp.zeros(n1, jnp.float32).at[docids].add(
+        vals.reshape(-1), mode="drop")
+    count = jnp.zeros(n1, jnp.int32).at[docids].add(
+        hit.reshape(-1).astype(jnp.int32), mode="drop")
+    if dr.shape[0]:
+        s, c = scores[:num_docs], count[:num_docs]
+        for i in range(dr.shape[0]):
+            tfn = dev["dense_tfn"][dr[i]]  # [N]; tfn > 0 iff tf > 0
+            s = s + dw[i] * tfn
+            c = c + ((tfn > 0) & (dok[i] != 0)).astype(jnp.int32)
+        scores = scores.at[:num_docs].set(s)
+        count = count.at[:num_docs].set(c)
+    match = count >= thr
+    return jnp.where(match, boost * scores, 0.0), match
 
 
 # lanes a block of the two-level selection: one vreg row, so the [G, W] view

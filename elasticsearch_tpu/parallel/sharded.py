@@ -30,7 +30,7 @@ from ..ops.scoring import top_k_with_total
 from ..query.dsl import parse_query
 from ..utils.jax_env import shard_map
 from ..utils.errors import IllegalArgumentError
-from ..query.nodes import ExecContext, QueryNode
+from ..query.nodes import ExecContext, QueryNode, match_rows, plan_key
 from .param_pack import (pack, pack_outputs, packed_counts, unpack,
                          unpack_host)
 from .stacked import StackedPack
@@ -239,11 +239,12 @@ class StackedSearcher:
         )
         self._cache: dict = {}
         # a searcher that never compiles a plan shape has met none: the
-        # counter reads 0 from the start, not nothing (a node whose every
+        # counters read 0 from the start, not nothing (a node whose every
         # search rides a wave)
-        from ..telemetry import metrics
+        from ..telemetry import SOLO_ROWS, metrics
 
         metrics.counter_inc("es.jit.cache.search_solo.misses", 0)
+        metrics.counters_add([(SOLO_ROWS, (0, 0))])
         self._dense_tfn_fn = None
         # shard request cache identity: per-shard epochs so one shard's
         # in-place mutation invalidates only its own entries (plus the
@@ -692,7 +693,7 @@ class StackedSearcher:
             keys.append(k_)
         params = _stack_shard_params(per_shard)
         k = max(size + from_, 1)
-        got = self._compiled_collapse(node, tuple(keys), fld, k)
+        got = self._compiled_collapse(node, plan_key(keys), fld, k)
         fn, V = got
         top_s, top_shard, top_doc, top_g, total = jax.device_get(fn(self.dev, params))
         col = self.sp.global_docvalues.get(fld)
@@ -737,7 +738,7 @@ class StackedSearcher:
             per_shard.append(p)
             keys.append(k_)
         params = _stack_shard_params(per_shard)
-        cache_key = ("scores_at", tuple(keys), len(doc_ids), self._exec)
+        cache_key = ("scores_at", plan_key(keys), len(doc_ids), self._exec)
         fn = self._cache.get(cache_key)
         if fn is None:
             ctx = self.ctx
@@ -1019,7 +1020,7 @@ class StackedSearcher:
     def _agg_dispatch(self, query=None, size=10, from_=0, aggs=None,
                       mappings=None):
         """Plan + launch one request's pass-1 program (no device fetch)."""
-        from ..telemetry import TRACER
+        from ..telemetry import SOLO_ROWS, TRACER, metrics
 
         m = mappings if mappings is not None else self.sp.mappings
         node, agg_nodes = self._parsed(query, m, aggs)
@@ -1047,13 +1048,20 @@ class StackedSearcher:
                 agg_params = _stack_shard_params(per_shard_aggs)
                 agg_key = tuple(akeys)
             k = min(max(size + from_, 1), max(self.sp.n_max * self.sp.S, 1))
+            keys = plan_key(keys)
             programs = len(self._cache)  # a miss adds one
             fn, buffers = self._packed_program(
-                node, tuple(keys), k, agg_nodes, agg_key, params, agg_params)
+                node, keys, k, agg_nodes, agg_key, params, agg_params)
             hit = len(self._cache) == programs
             plan.attributes["program_cache"] = "hit" if hit else "miss"
+            # the rows of the family's two lists it gathers, real and with
+            # padding: none where the query is no match
+            real, padded, tiers = match_rows(keys[0], params) or (0, 0, None)
+            if tiers is not None:
+                plan.attributes.update(dense_tier=tiers[0], rows_tier=tiers[1],
+                                       padded_rows=padded)
+        metrics.counters_add([(SOLO_ROWS, (real, padded))])
         from ..monitoring.xla_introspect import check_dispatch
-        from ..telemetry import metrics
 
         metrics.counter_inc("es.search.topk.xla_topk")
         check_dispatch("sharded.spmd_topk", fn, (self.dev, buffers),
@@ -1065,7 +1073,7 @@ class StackedSearcher:
                          **({} if hit else {"compiled": True})):
             outs, out_layout = self._launch(fn, buffers)
         return {
-            "node": node, "keys": tuple(keys), "k": k, "size": size,
+            "node": node, "keys": keys, "k": k, "size": size,
             "from_": from_, "agg_nodes": agg_nodes, "agg_key": agg_key,
             "params": params, "agg_params": agg_params,
             "outs": outs, "out_layout": out_layout,
@@ -1283,7 +1291,7 @@ class StackedSearcher:
         if search_after is not None:
             after = plan.after_keys(search_after, self.sp)
         fn = self._compiled_sorted(
-            node, tuple(keys_t), k, plan, search_after is not None, agg_nodes, agg_key
+            node, plan_key(keys_t), k, plan, search_after is not None, agg_nodes, agg_key
         )
         inv, keys_s, docs, totals, agg_out = jax.device_get(
             fn(self.dev, params, after, agg_params)

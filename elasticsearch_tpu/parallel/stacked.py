@@ -103,6 +103,10 @@ class _ShardView:
         dt = getattr(self.stacked, "dense_tf", None)
         return None if dt is None else dt[self.shard_index]
 
+    def impact_served(self):
+        # the stacked serving state: the same answer on every shard
+        return self.stacked.impact_serving()
+
     def impact_wscale(self, fld, term):
         """Impact-tier dequant scale (see ShardPack.impact_wscale), gated
         on the STACKED serving state: the searcher must have derived code

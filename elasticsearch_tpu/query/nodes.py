@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..index.pack import ShardPack
-from ..ops.scoring import DEAD_SLOT_PAD, bm25_idf, term_score_blocks
+from ..ops.scoring import DEAD_SLOT_PAD, bm25_idf
 
 MIN_BUCKET = 4
 
@@ -79,83 +79,179 @@ class QueryNode:
         raise NotImplementedError
 
 
+# The solo path's family of `match` programs (PR 38). A match (one term, or a
+# bool of terms on one field) plans as two padded lists, its dense terms and
+# its sparse terms' posting-block rows, and its program is keyed by the two
+# lists' tiers alone: (MATCH, field, scoring mode, dense tier, rows tier). A
+# query of unseen words then compiles nothing new.
+MATCH = "match"
+# the smallest rows tier (`search.solo.min_rows_tier`, powers of two from
+# it). Process-wide like the program caches it bounds; the engine's settings
+# consumer sets it.
+MATCH_MIN_ROWS = 8
+# the core of the ladders: dense tiers 0, 1, 2, 4, 8 by rows tiers m, 2m, 4m
+# (m = MATCH_MIN_ROWS): where a query of up to a dozen words lands. A query
+# outside it rides one program a level further out, both tiers at once:
+# (16, 16m), (32, 64m), ... so that the few longest queries of a stream share
+# one program instead of a rare pair each.
+MATCH_CORE_DENSE = 8
+
+
+def match_tiers(n_dense: int, n_rows: int) -> tuple[int, int]:
+    """-> (dense tier, rows tier) of a match with `n_dense` dense terms and
+    `n_rows` sparse posting-block rows: the ladders of `ops/batched` (powers
+    of two), the rows' at least MATCH_MIN_ROWS, inside the core box or on
+    its diagonal beyond (scripts/solo_family.py counts the family)."""
+    from ..ops.batched import BatchTermSearcher
+
+    pow2 = BatchTermSearcher.pow2_tier
+    td = pow2(n_dense) if n_dense else 0
+    tr = pow2(n_rows, MATCH_MIN_ROWS)
+    d_edge, r_edge = MATCH_CORE_DENSE, 4 * MATCH_MIN_ROWS
+    if td <= d_edge and tr <= r_edge:
+        return td, tr
+    while td > d_edge or tr > r_edge:
+        d_edge, r_edge = 2 * d_edge, 4 * r_edge
+    return d_edge, r_edge
+
+
+def _impact_mode(pack, terms) -> bool:
+    """Whether a match's sparse terms score from the impact tier's codes:
+    the pack serves them (the same answer on every shard of a stacked pack)
+    and no term asks for exact scores (mark_exact)."""
+    from ..ops.scoring import impact_enabled
+
+    served = getattr(pack, "impact_served", None)
+    return (impact_enabled() and served is not None and served()
+            and not any(t.exact_scores for t in terms))
+
+
+def match_params(pack, terms: list, threshold: int, boost: float):
+    """-> (params, key) of a match over `terms` (TermNodes of one field):
+    a document matches where at least `threshold` of them hold it, and its
+    score is `boost` x the sum of theirs. Canonical order: dense terms by
+    dense row, sparse terms by first block row; a term the shard lacks adds
+    nothing. Each term's weight is computed as the per-term plan did, so a
+    posting's contribution is the same f32 as before, summed in another
+    order (ulps)."""
+    fld = terms[0].fld
+    impact = _impact_mode(pack, terms)
+    doc_count = (pack.field_stats.get(fld, {}).get("doc_count")
+                 or pack.num_docs)
+    dense, sparse = [], []
+    for t in terms:
+        start, count, df = pack.term_blocks(fld, t.term)
+        weight = (np.float32(t.boost * bm25_idf(doc_count, df)) if df > 0
+                  else np.float32(0.0))
+        dr = pack.dense_row_of(fld, t.term)
+        if dr is not None:
+            dense.append((int(dr), weight))
+        elif count:
+            # wscale = boost·idf·ubf/qmax — score = wscale · code
+            wscale = (np.float32(weight * pack.impact_wscale(fld, t.term))
+                      if impact else np.float32(0.0))
+            sparse.append((start, count, weight, wscale))
+    dense.sort(key=lambda d: d[0])
+    sparse.sort(key=lambda s: s[0])
+    n_rows = sum(c for _s, c, _w, _ws in sparse)
+    td, tr = match_tiers(len(dense), n_rows)
+    rows = np.zeros(tr, np.int32)           # padding: the reserved row 0
+    rw = np.zeros(tr, np.float32)
+    rs = np.zeros(tr, np.float32)
+    at = 0
+    for start, count, weight, wscale in sparse:
+        rows[at:at + count] = np.arange(start, start + count, dtype=np.int32)
+        rw[at:at + count] = weight
+        rs[at:at + count] = wscale
+        at += count
+    dr = np.zeros(td, np.int32)             # padding: weight 0, no match
+    dw = np.zeros(td, np.float32)
+    dok = np.zeros(td, np.int32)
+    for i, (row, weight) in enumerate(dense):
+        dr[i], dw[i], dok[i] = row, weight, 1
+    # avgdl rides as a runtime param (not a trace constant) so compiled
+    # plans survive stat drift as tiered refreshes add documents
+    params = (rows, rw, rs, dr, dw, dok, np.int32(threshold),
+              np.float32(boost), np.float32(pack.avgdl(fld)))
+    return params, (MATCH, fld, "impact" if impact else "exact", td, tr)
+
+
+def match_eval(dev, params, ctx, fld: str, mode: str):
+    """The device side of `match_params`' plan (ops/scoring.match_scores).
+    `mode` is the key's: "impact" escalates to raw-postings BM25 where the
+    searcher holds no codes or the context's k1/b are not the pack's."""
+    from ..index.pack import BM25_B, BM25_K1
+    from ..ops.scoring import match_scores
+
+    impact = (mode == "impact" and "impact_codes" in dev
+              and (ctx.k1, ctx.b) == (BM25_K1, BM25_B))
+    return match_scores(dev, params, ctx.num_docs, ctx.k1, ctx.b,
+                        fld in ctx.has_norms, impact)
+
+
+def match_rows(key, params) -> tuple[int, int, tuple[int, int]] | None:
+    """-> (rows gathered for real, rows with padding, (dense tier, rows
+    tier)) of a plan whose key is a match family member (params stacked
+    [S, ...]: every shard's rows), else None. A real sparse row is never the
+    padding row 0; a real dense entry carries its flag."""
+    if not (isinstance(key, tuple) and key and key[0] == MATCH):
+        return None
+    rows, dok = np.asarray(params[0]), np.asarray(params[5])
+    real = int(np.count_nonzero(rows)) + int(np.count_nonzero(dok))
+    return real, rows.size + dok.size, (key[3], key[4])
+
+
+class _Ragged(Exception):
+    pass
+
+
+def plan_key(keys: list) -> tuple:
+    """The key of a plan over its shards' keys: where they differ only in
+    a match family member's tiers, each shard takes the largest shard's
+    (`_stack_shard_params` pads the lists to the widest with zeros, i.e.
+    padding), so the plan is one program of the family; else the shards'
+    keys as they are."""
+
+    def merge(ks):
+        k0 = ks[0]
+        if all(k == k0 for k in ks):
+            return k0
+        if not all(isinstance(k, tuple) and len(k) == len(k0) for k in ks):
+            raise _Ragged
+        if k0 and k0[0] == MATCH and all(k[:3] == k0[:3] for k in ks):
+            return (*k0[:3], max(k[3] for k in ks), max(k[4] for k in ks))
+        return tuple(merge(list(p)) for p in zip(*ks))
+
+    try:
+        return (merge(list(keys)),) * len(keys)
+    except _Ragged:
+        return tuple(keys)
+
+
 @dataclass
 class TermNode(QueryNode):
     """Exact term match with BM25 scoring (reference behavior:
-    index/query/TermQueryBuilder.java -> Lucene TermQuery).
-
-    When the pack carries the impact-scored sparse tier (BM25S,
-    index/pack.py) and nothing demands exact scores, evaluation is a pure
-    gather+sum over quantized impact codes: idf (from the ONE bm25_idf
-    implementation, effective dfs stats included) folds into a host-side
-    scalar and no tf/dl/avgdl math is traced. `exact_scores` (set by
-    mark_exact for explain / scripted similarity) and non-default
-    ctx.k1/b fall back to the raw-postings path at trace time."""
+    index/query/TermQueryBuilder.java -> Lucene TermQuery): a match of one
+    term (`match_params`). Where the pack carries the impact-scored sparse
+    tier (BM25S, index/pack.py) and nothing demands exact scores, a sparse
+    term is a pure gather+sum over quantized impact codes: idf (from the ONE
+    bm25_idf implementation, effective dfs stats included) folds into a
+    host-side scalar and no tf/dl/avgdl math is traced. `exact_scores` (set
+    by mark_exact for explain / scripted similarity) and non-default
+    ctx.k1/b fall back to the raw-postings path."""
 
     fld: str
     term: str
     boost: float = 1.0
     exact_scores: bool = False
-    _dense: bool = False
 
     def prepare(self, pack):
-        start, count, df = pack.term_blocks(self.fld, self.term)
-        if df > 0:
-            doc_count = pack.field_stats.get(self.fld, {}).get("doc_count") or pack.num_docs
-            weight = np.float32(self.boost * bm25_idf(doc_count, df))
-        else:
-            weight = np.float32(0.0)
-        dr = pack.dense_row_of(self.fld, self.term)
-        self._dense = dr is not None
-        # avgdl rides as a runtime param (not a trace constant) so compiled
-        # plans survive stat drift as tiered refreshes add documents
-        avgdl = np.float32(pack.avgdl(self.fld))
-        if self._dense:
-            return (np.int32(dr), weight, avgdl), ("term_dense", self.fld)
-        rows = _pad_rows(start, count)
-        if not self.exact_scores:
-            from ..ops.scoring import impact_enabled
-
-            isc = (pack.impact_wscale(self.fld, self.term)
-                   if impact_enabled() else None)
-            if isc is not None:
-                # wscale = boost·idf·ubf/qmax — score = wscale · code
-                return (rows, weight, avgdl, np.float32(weight * isc)), (
-                    "term_imp", self.fld, len(rows))
-        return (rows, weight, avgdl), ("term", self.fld, len(rows))
+        params, key = match_params(pack, [self], 1, 1.0)
+        self._mode = key[2]
+        return params, key
 
     def device_eval(self, dev, params, ctx):
-        if self._dense:
-            from ..ops.scoring import dense_term_scores
-
-            dr, weight, _avgdl = params
-            return dense_term_scores(dev["dense_tfn"][dr], weight, ctx.num_docs)
-        if len(params) == 4:
-            rows, weight, avgdl, wscale = params
-            from ..index.pack import BM25_B, BM25_K1
-            from ..ops.scoring import impact_term_scores
-
-            if ("impact_codes" in dev
-                    and (ctx.k1, ctx.b) == (BM25_K1, BM25_B)):
-                return impact_term_scores(
-                    dev["impact_codes"], dev["post_docids"], rows, wscale,
-                    ctx.num_docs)
-            # escalation: custom k1/b (scripted similarity contexts) or a
-            # searcher without resident codes — raw-postings BM25
-            params = (rows, weight, avgdl)
-        rows, weight, avgdl = params
-        return term_score_blocks(
-            dev["post_docids"],
-            dev["post_tfs"],
-            dev["post_dls"],
-            rows,
-            weight,
-            avgdl,
-            ctx.num_docs,
-            ctx.k1,
-            ctx.b,
-            has_norms=self.fld in ctx.has_norms,
-        )
+        return match_eval(dev, params, ctx, self.fld, self._mode)
 
 
 @dataclass
@@ -738,7 +834,25 @@ class BoolNode(QueryNode):
             return 1
         return 0
 
+    def _match_terms(self):
+        """-> (terms, threshold) where this bool is a `match`: TermNodes of
+        one field, all `must` (threshold: all of them) or all `should`
+        (threshold: minimum_should_match), nothing else; else None."""
+        if self.filter or self.must_not or bool(self.must) == bool(self.should):
+            return None
+        terms = self.must or self.should
+        if (any(type(c) is not TermNode for c in terms)
+                or len({c.fld for c in terms}) != 1):
+            return None
+        threshold = len(terms) if self.must else self._msm()
+        return (terms, threshold) if threshold >= 1 else None
+
     def prepare(self, pack):
+        mt = self._match_terms()
+        if mt is not None:
+            params, key = match_params(pack, *mt, self.boost)
+            self._mode = key[2]
+            return params, key
         groups = []
         keys = []
         for grp in (self.must, self.filter, self.should, self.must_not):
@@ -752,6 +866,9 @@ class BoolNode(QueryNode):
         )
 
     def device_eval(self, dev, params, ctx):
+        mt = self._match_terms()
+        if mt is not None:
+            return match_eval(dev, params, ctx, mt[0][0].fld, self._mode)
         groups, boost = params
         must_p, filter_p, should_p, not_p = groups
         n1 = ctx.num_docs + 1

@@ -174,6 +174,12 @@ ANNOTATED_STAGES = frozenset({
 })
 _STAGE_COUNTERS = {name: (f"es.span.{name}.ns", f"es.span.{name}.count")
                    for name in STAGES + WAVE_STAGES}
+# What every solo search's plan adds at once (parallel/sharded._agg_dispatch):
+# the rows of the match family's two lists (query/nodes.match_params) its
+# program gathers, the sparse block rows plus the dense rows over its shards,
+# without and with the padding to the family's tiers; 0 and 0 for a query
+# that is no match. A searcher ships both at 0 from its start.
+SOLO_ROWS = ("es.search.solo.rows", "es.search.solo.padded_rows")
 _annotation = None  # jax.profiler.TraceAnnotation while a capture runs
 
 

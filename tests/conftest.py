@@ -40,7 +40,23 @@ def pytest_addoption(parser):
     )
 
 
+# Tests that an accepted later change has made false and that the PR which
+# made them so may not edit (files of the benchmark's `paths`), each with
+# why: strict, so that the PR which re-bases one must take it off this list.
+STALE = {
+    "tests/benchmark/test_passage_wave_files.py::"
+    "test_the_additions_stand_at_the_end_of_their_lists":
+        "PR 38 appended its configuration, cell and metric after PR 35's, "
+        "which this test holds to stand last; a benchmark PR re-bases it "
+        "(PERF.md section 7)",
+}
+
+
 def pytest_collection_modifyitems(session, config, items):
+    for it in items:
+        if it.nodeid in STALE:
+            it.add_marker(pytest.mark.xfail(reason=STALE[it.nodeid],
+                                            strict=True))
     seed = config.getoption("--shuffle-modules")
     if seed is None:
         return

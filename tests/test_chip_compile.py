@@ -177,33 +177,45 @@ def test_impact_gather_pallas_compiles(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _match_plan(S, td=8, tr=32):
+    """A stacked plan of the match family (query/nodes.match_params):
+    rows, their weights and impact scales; dense rows, weights and flags;
+    threshold, boost and avgdl."""
+    f32, i32 = np.float32, np.int32
+    return (np.zeros((S, tr), i32), np.ones((S, tr), f32),
+            np.ones((S, tr), f32), np.zeros((S, td), i32),
+            np.ones((S, td), f32), np.ones((S, td), i32), np.ones((S,), i32),
+            np.ones((S,), f32), np.ones((S,), f32))
+
+
+@pytest.mark.parametrize("td, tr", [(0, 8), (8, 32), (16, 128)])
 def test_packed_parameters_feed_the_solo_scoring_and_scan(
-        one_chip, no_persistent_cache):
+        td, tr, one_chip, no_persistent_cache):
     """PR 27: the solo program takes a `match`'s parameters as one
     int32[S, W] buffer; its slices (and 32-bit bitcasts) feed the dense
-    row index, the impact tier's gather and the two-level selection."""
+    rows' indices, the impact tier's gather and the two-level selection.
+    PR 38: the parameters are a member of the match family's (its
+    smallest, a core one and the one beyond the core), scored by
+    `match_scores`."""
     import jax
     import jax.numpy as jnp
 
     from elasticsearch_tpu.index.pack import BLOCK
-    from elasticsearch_tpu.ops.scoring import (dense_term_scores,
-                                               impact_term_scores,
-                                               top_k_with_total)
+    from elasticsearch_tpu.ops.scoring import match_scores, top_k_with_total
     from elasticsearch_tpu.parallel.param_pack import (pack, pack_outputs,
                                                        unpack)
 
-    f32, i32 = np.float32, np.int32
-    dense = (np.zeros((1,), i32), np.ones((1,), f32), np.ones((1,), f32))
-    sparse = (np.zeros((1, 64), i32), np.ones((1,), f32), np.ones((1,), f32),
-              np.ones((1,), f32))
-    buffers, layout = pack(((dense, sparse), np.ones((1,), f32)))
-    assert [(b.shape, b.dtype) for b in buffers] == [((1, 71), np.dtype(i32))]
+    buffers, layout = pack(_match_plan(1, td, tr))
+    width = 3 * tr + 3 * td + 3
+    assert [(b.shape, b.dtype) for b in buffers] == [((1, width),
+                                                      np.dtype(np.int32))]
 
     def shard_body(dense_tfn, codes, docids, live, params):
-        ((dr, weight, _), (rows, _, _, wscale)), boost = params
-        s1, m1 = dense_term_scores(dense_tfn[dr], weight, N_DOCS)
-        s2, m2 = impact_term_scores(codes, docids, rows, wscale, N_DOCS)
-        return top_k_with_total(boost * (s1 + s2), m1 | m2, live, TOP_K)
+        dev = {"dense_tfn": dense_tfn, "impact_codes": codes,
+               "post_docids": docids}
+        scores, match = match_scores(dev, params, N_DOCS, 1.2, 0.75, True,
+                                     impact=True)
+        return top_k_with_total(scores, match, live, TOP_K)
 
     def solo(dense_tfn, codes, docids, live, buffers):
         ts, ti, tot = jax.vmap(shard_body)(dense_tfn, codes, docids, live,
@@ -242,9 +254,7 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from elasticsearch_tpu.index.pack import BLOCK
-    from elasticsearch_tpu.ops.scoring import (dense_term_scores,
-                                               impact_term_scores,
-                                               top_k_with_total)
+    from elasticsearch_tpu.ops.scoring import match_scores, top_k_with_total
     from elasticsearch_tpu.parallel.param_pack import (pack, pack_outputs,
                                                        unpack)
     from elasticsearch_tpu.parallel.spmd import (constrain, constrain_shards,
@@ -252,20 +262,16 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
 
     S, n = 4, 294_912
     nb = N_BLOCKS // 3          # ~1/3 of the 1M-doc pack's blocks a shard
-    f32, i32 = np.float32, np.int32
-    dense = (np.zeros((S,), i32), np.ones((S,), f32), np.ones((S,), f32))
-    sparse = (np.zeros((S, 64), i32), np.ones((S,), f32), np.ones((S,), f32),
-              np.ones((S,), f32))
-    buffers, layout = pack(((dense, sparse), np.ones((S,), f32)))
-    assert [(b.shape, b.dtype) for b in buffers] == [((S, 71), np.dtype(i32))]
+    buffers, layout = pack(_match_plan(S))
+    assert [(b.shape, b.dtype) for b in buffers] == [((S, 123),
+                                                      np.dtype(np.int32))]
 
     def shard_body(dev1, params):
-        ((dr, weight, _), (rows, _, _, wscale)), boost = params
         with jax.named_scope("score"):
-            s1, m1 = dense_term_scores(dev1["dense_tfn"][dr], weight, n)
-            s2, m2 = impact_term_scores(dev1["codes"], dev1["docids"], rows,
-                                        wscale, n)
-            scores, match = boost * (s1 + s2), m1 | m2
+            scores, match = match_scores(
+                {"dense_tfn": dev1["dense_tfn"], "impact_codes": dev1["codes"],
+                 "post_docids": dev1["docids"]}, params, n, 1.2, 0.75, True,
+                impact=True)
         with jax.named_scope("topk"):
             return top_k_with_total(scores, match, dev1["live"], TOP_K)
 
@@ -303,7 +309,7 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
         assert re.search(rf"{rows}\[({S * TOP_K}|{S},{TOP_K})\]", gathered), \
             f"the shards' {rows} rows are not gathered"
     # each chip is handed its own row of the packed parameters
-    assert "s32[1,71]" in text
+    assert "s32[1,123]" in text
     # and every chip holds the one buffer the host fetches from one of them
     _assert_one_replicated_buffer_of_words(compiled, 3 * TOP_K + 1)
 

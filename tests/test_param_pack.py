@@ -266,9 +266,13 @@ def test_a_search_answers_what_the_unpacked_program_answers(
 
 
 def test_the_pool_of_requests_mixes_dense_and_impact_terms(searcher):
+    # PR 38: one plan of the match family, its dense list and its impact
+    # scored rows both holding real entries
     st = searcher._agg_dispatch(**REQUESTS["match_12_mixed"])
-    kinds = {k[0] for k in st["keys"][0][1][2]}
-    assert kinds == {"term_dense", "term_imp"}, st["keys"][0]
+    tag, fld, mode, _td, _tr = st["keys"][0]
+    assert (tag, fld, mode) == ("match", "body", "impact"), st["keys"][0]
+    rows, dok = st["params"][0], st["params"][5]
+    assert np.count_nonzero(rows) and np.count_nonzero(dok)
 
 
 # -- (c) one host array a dtype class ----------------------------------------
@@ -309,11 +313,17 @@ def test_a_search_hands_its_program_one_host_array_a_dtype_class(
         assert len(handed) == buffers
 
 
-def test_nodes_stats_ships_one_buffer_a_search_of_the_rest_api():
+def test_nodes_stats_ships_one_buffer_a_search_of_the_rest_api(monkeypatch):
     """What the benchmark's `engine.dispatch_buffers` and
     `engine.fetch_buffers` read: the counters' rise over the searches
     `rest.search` counted, from `_nodes/stats`."""
     import asyncio
+
+    from elasticsearch_tpu.cache import request_cache
+
+    # the cluster setting below switches the node-wide cache off; a later
+    # module in this process (tests/test_spmd.py) needs it as it found it
+    monkeypatch.setattr(request_cache(), "_enabled", request_cache()._enabled)
     import json
 
     from aiohttp.test_utils import TestClient, TestServer

@@ -141,7 +141,12 @@ def test_one_search_yields_one_tree_of_stages_and_moves_their_counters():
         # inside the parent's interval, both on time.perf_counter's clock
         assert parent.start <= s.start <= s.end <= parent.end, s.name
     plan = next(s for s, _ in spans if s.name == "engine.plan")
-    assert plan.attributes == {"program_cache": "hit"}
+    # PR 38: a match's plan names its program's tiers in the family
+    at = plan.attributes
+    assert set(at) == {"program_cache", "dense_tier", "rows_tier",
+                       "padded_rows"}
+    assert at["program_cache"] == "hit"
+    assert at["padded_rows"] == at["dense_tier"] + at["rows_tier"]
     for name in STAGES:
         n = 2 if name == "engine.collect" else 1
         assert (after[f"es.span.{name}.count"]
