@@ -212,12 +212,13 @@ def top_k_with_total(
 
     Why not `lax.top_k` over the whole row: the TPU compiler turns it into
     its fast `TopK` call only where the operand is rank 2, which a rank-1
-    row is under one `vmap` and nowhere else. Inside a manual region (a
-    shard a chip) or unbatched (query/executor) it becomes a stable sort of
-    all N (score, index) pairs: at N = 294,912 on a v5e 342 us against
-    `TopK`'s 24, and ~20 s to compile, a program (scripts/topk_micro.py;
-    PERF.md section 6, PR 30). Here every selection is small, so either
-    lowering is cheap: 33 us at rank 2, 18 at rank 1.
+    row is under one `vmap` (as every shard body runs, one shard a chip
+    inside a manual region included) and nowhere else. Unbatched
+    (query/executor) it becomes a stable sort of all N (score, index)
+    pairs: at N = 294,912 on a v5e 342 us against `TopK`'s 24, and ~20 s
+    to compile, a program (scripts/topk_micro.py; PERF.md section 6,
+    PR 30). Here every selection is small, so either lowering is cheap:
+    33 us at rank 2, 18 at rank 1.
 
     Exact, not approximate: let document e of block g be in the true top-k
     by (score desc, docid asc) with g not chosen. Then k chosen blocks g'

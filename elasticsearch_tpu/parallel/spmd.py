@@ -205,37 +205,26 @@ def manual_shard_region(shard_body, mesh: Mesh | None, *, in_specs):
     top-k merge composes directly with the region's sharded outputs —
     this is the PR-11 closure of the fused-arm fork (ROADMAP item 1).
 
-    Off-mesh the same body runs under `vmap` over the stacked axis.
-    `in_specs` entries are `P("shards")` for [S, ...]-stacked pytree
-    args (squeezed to the shard-local slice for the body) or `P()` for
-    replicated args passed through whole. Outputs keep the leading
-    shard axis (out_specs P("shards"))."""
-    import jax.tree_util as jtu
+    The body runs under `vmap` over the leading shard axis, on and off
+    the mesh: off it over all S stacked shards, on it (inside the
+    `shard_map`) over each device's local slice of size 1. `in_specs`
+    entries are `P("shards")` for [S, ...]-stacked pytree args (mapped
+    on axis 0, so the body sees one shard's slice) or `P()` for
+    replicated args passed through whole (not mapped). Outputs keep the
+    leading shard axis (out_specs P("shards")).
 
+    Why `vmap` and not `x[0]` on the mesh: the compiler lays a local
+    [1, m, 128] leaf with m mod 8 != 0 out as T(1,128), and a squeeze
+    of it asks for T(8,128) — a relayout of the whole leaf every call."""
     shards_spec = P("shards")
+    axes = tuple(0 if s == shards_spec else None for s in in_specs)
+    region = jax.vmap(shard_body, in_axes=axes)
     if mesh is None:
-        axes = tuple(0 if s == shards_spec else None for s in in_specs)
-
-        def region(*args):
-            return jax.vmap(shard_body, in_axes=axes)(*args)
-
         return region
     from ..utils.jax_env import shard_map
 
-    def body(*args_s):
-        def one(spec, t):
-            if spec == shards_spec:
-                return jtu.tree_map(lambda x: x[0], t)
-            return t
-
-        outs = shard_body(*(one(s, a) for s, a in zip(in_specs, args_s)))
-        return jtu.tree_map(lambda x: jnp.asarray(x)[None], outs)
-
-    def region(*args):
-        return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=shards_spec)(*args)
-
-    return region
+    return shard_map(region, mesh=mesh, in_specs=tuple(in_specs),
+                     out_specs=shards_spec)
 
 
 # ---------------------------------------------------------------------------

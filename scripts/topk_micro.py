@@ -4,8 +4,8 @@
 
 `ops/scoring.top_k_with_total` (two levels) against `lax.top_k` over the whole
 masked row, each as the server compiles it in its two contexts: under one
-`vmap` (the row is rank 2: one chip, shards stacked) and unbatched (rank 1:
-inside `manual_shard_region`, a shard a chip, and in `query/executor`). The
+`vmap` (the row is rank 2: every shard body, on one chip or a shard a chip
+inside `manual_shard_region`) and unbatched (rank 1: `query/executor`). The
 TPU compiler lowers `lax.top_k` to its `TopK` call at rank 2 only; at rank 1
 it sorts the row whole. Time is the device's, from a capture of CALLS calls;
 one JSON line a variant, also in chiprun_out/topk_micro.json. PERF.md

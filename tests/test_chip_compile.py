@@ -238,17 +238,29 @@ def test_packed_parameters_feed_the_solo_scoring_and_scan(
     _assert_one_replicated_buffer_of_words(compiled, 3 * TOP_K + 1)
 
 
+def _whole_leaf_copies(text, nb):
+    """Instructions that copy a whole [nb, 128] pack leaf (with or without
+    its leading shard axis): a plain `copy` (what a relayout compiles to,
+    inside the fusion that calls it as well). A `copy-start`/`copy-done`
+    pair is the cross-program prefetch the one-chip program holds too."""
+    leaf = re.compile(rf"= (s32|u16)\[(1,)?{nb},128\]\S* copy\(")
+    return [ln.strip()[:160] for ln in text.splitlines() if leaf.search(ln)]
+
+
+@pytest.mark.parametrize("td, tr", [(0, 8), (8, 32), (16, 128)])
 def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
-        mesh4, no_persistent_cache):
+        td, tr, mesh4, no_persistent_cache):
     """PR 29: the program of `passage-4chip.solo.c8`, one `match` over four
     shards of 294,912 documents, one a chip: the packed `int32[4, W]`
     parameters, the impact tier's gather and `top_k_with_total` (plain XLA,
     so what compiles here is what the chip runs) inside
-    `manual_shard_region`, where the shard's row is rank 1 and a `lax.top_k`
-    over the whole of it would be a stable sort of 294,912 pairs; then the
-    replication constraint that gathers the shards' rows, and the global
-    top-k, packed into one replicated buffer (PR 32): `_compiled`'s shape,
-    built from the same pieces."""
+    `manual_shard_region`, under the same `vmap` as on one chip (the row is
+    rank 2; a rank-1 `lax.top_k` over it would be a stable sort of 294,912
+    pairs); then the replication constraint that gathers the shards' rows,
+    and the global top-k, packed into one replicated buffer (PR 32):
+    `_compiled`'s shape, built from the same pieces, for members of the
+    match family (PR 38). PR 39: a block count that is not a multiple of 8
+    costs no relayout of the whole `post_docids` leaf a search."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -262,8 +274,10 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
 
     S, n = 4, 294_912
     nb = N_BLOCKS // 3          # ~1/3 of the 1M-doc pack's blocks a shard
-    buffers, layout = pack(_match_plan(S))
-    assert [(b.shape, b.dtype) for b in buffers] == [((S, 123),
+    assert nb % 8, "the block count whose layout the compiler tiles (1,128)"
+    buffers, layout = pack(_match_plan(S, td, tr))
+    width = 3 * tr + 3 * td + 3
+    assert [(b.shape, b.dtype) for b in buffers] == [((S, width),
                                                       np.dtype(np.int32))]
 
     def shard_body(dev1, params):
@@ -306,10 +320,12 @@ def test_solo_search_compiles_on_four_chips_at_the_four_shard_cells_size(
     gathered = " ".join(ln.split(" all-")[0] for ln in text.splitlines()
                         if re.search(r" all-(gather|reduce)\(", ln))
     for rows in ("f32", "s32"):
-        assert re.search(rf"{rows}\[({S * TOP_K}|{S},{TOP_K})\]", gathered), \
-            f"the shards' {rows} rows are not gathered"
+        assert re.search(rf"{rows}\[({S * TOP_K}|{S},(1,)?{TOP_K})\]",
+                         gathered), f"the shards' {rows} rows are not gathered"
     # each chip is handed its own row of the packed parameters
-    assert "s32[1,123]" in text
+    assert f"s32[1,{width}]" in text
+    # and reads the rows of its leaves it gathers, not a relaid copy of them
+    assert not _whole_leaf_copies(text, nb)
     # and every chip holds the one buffer the host fetches from one of them
     _assert_one_replicated_buffer_of_words(compiled, 3 * TOP_K + 1)
 

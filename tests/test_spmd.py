@@ -221,21 +221,63 @@ def test_serving_wave_parity(sp, monkeypatch):
     assert (t_a == t_b).all()
 
 
+def _odd_block_counts(sp):
+    """Every shard's posting blocks, and the padded count, are not a
+    multiple of 8: the count at which a v5e lays a shard's [1, m, 128]
+    leaf out (1,128) and a squeeze of it would relay the whole leaf."""
+    assert sp.nb_max % 8 and all(p.num_blocks % 8 for p in sp.shards)
+
+
 def test_sorted_and_collapse_parity(sp, monkeypatch):
+    """The mesh's sorted, collapse and rescore-gather (`scores_at`)
+    programs answer as the shard_map oracle and as one device's `vmap`."""
     monkeypatch.setenv("ES_TPU_REQUEST_CACHE", "0")
+    _odd_block_counts(sp)
     pj = _searcher(sp, "pjit", monkeypatch)
     sm = _searcher(sp, "shardmap", monkeypatch)
+    sd = _searcher(sp, "pjit", monkeypatch, mesh=False)
     from elasticsearch_tpu.query.sort import parse_sort
 
     q = {"term": {"body": "w1"}}
     sort = parse_sort([{"bytes": "desc"}])
     h_pj = pj.search_sorted(q, sort, size=6)
-    h_sm = sm.search_sorted(q, sort, size=6)
-    assert h_pj[0] == h_sm[0] and h_pj[1] == h_sm[1]
+    for other in (sm, sd):
+        h = other.search_sorted(q, sort, size=6)
+        assert h_pj[0] == h[0] and h_pj[1] == h[1]
     c_pj = pj.search_collapse(q, "status", size=3)
-    c_sm = sm.search_collapse(q, "status", size=3)
-    assert c_pj.doc_ids.tolist() == c_sm.doc_ids.tolist()
-    assert c_pj.collapse_keys == c_sm.collapse_keys
+    for other in (sm, sd):
+        c = other.search_collapse(q, "status", size=3)
+        assert c_pj.doc_ids.tolist() == c.doc_ids.tolist()
+        assert c_pj.collapse_keys == c.collapse_keys
+        assert c_pj.total == c.total
+        np.testing.assert_array_max_ulp(c_pj.scores, c.scores, maxulp=1)
+    m = {"match": {"body": "w1 w7 rareterm"}}
+    hits = sd.search(m, size=20)
+    s_pj, ok_pj = pj.scores_at(m, hits.doc_shards, hits.doc_ids)
+    s_sd, ok_sd = sd.scores_at(m, hits.doc_shards, hits.doc_ids)
+    assert ok_pj.all() and ok_pj.tolist() == ok_sd.tolist()
+    np.testing.assert_array_max_ulp(s_pj, s_sd, maxulp=1)
+
+
+@pytest.mark.parametrize("arm", ["impact", "exact"])
+def test_match_on_the_mesh_answers_as_one_device(sp, arm, monkeypatch):
+    """The solo `match` program (`_compiled`) on the 4-device mesh, at
+    block counts that are not multiples of 8, against the one-device
+    `vmap` path: same shards and ids, same totals, scores within 1 ulp."""
+    monkeypatch.setenv("ES_TPU_REQUEST_CACHE", "0")
+    monkeypatch.setenv("ES_TPU_IMPACT", "1" if arm == "impact" else "0")
+    _odd_block_counts(sp)
+    pj = _searcher(sp, "pjit", monkeypatch)
+    sd = _searcher(sp, "pjit", monkeypatch, mesh=False)
+    for text in ("w1", "w3 w9 w41", "rareterm w2 w5 w8 w13 w21"):
+        req = dict(query={"match": {"body": text}}, size=7)
+        a, b = pj.search(**req), sd.search(**req)
+        assert a.doc_shards.tolist() == b.doc_shards.tolist(), text
+        assert a.doc_ids.tolist() == b.doc_ids.tolist(), text
+        assert a.total == b.total and a.total > 0, text
+        np.testing.assert_array_max_ulp(a.scores, b.scores, maxulp=1)
+    # the programs that answered are the arm's (the mode is in their key)
+    assert f"'{arm}'" in repr(list(pj._cache)), list(pj._cache)
 
 
 # ---------------------------------------------------------------------------
