@@ -1130,7 +1130,7 @@ class EsIndex:
             query = resolve_query_vector_builders(query, svc)
             knn = resolve_query_vector_builders(knn, svc)
         self.counters["query_total"] = self.counters.get("query_total", 0) + 1
-        from ..telemetry import TRACER, record_search_slowlog
+        from ..telemetry import TRACER, account, record_search_slowlog
 
         _t_search0 = time.monotonic()
         _trace_ctx = TRACER.span("executeQueryPhase", index=self.name)
@@ -1171,13 +1171,14 @@ class EsIndex:
             if runtime_mappings:
                 self.searcher.remove_runtime_fields(list(runtime_mappings))
             _trace_ctx.__exit__(None, None, None)
-            took_ms = (time.monotonic() - _t_search0) * 1000
-            self.counters["query_time_ms"] = (
-                self.counters.get("query_time_ms", 0) + int(took_ms))
-            record_search_slowlog(
-                self.name, self.settings, took_ms,
-                json.dumps(query)[:512] if query is not None else "{}",
-            )
+            with account():
+                took_ms = (time.monotonic() - _t_search0) * 1000
+                self.counters["query_time_ms"] = (
+                    self.counters.get("query_time_ms", 0) + int(took_ms))
+                record_search_slowlog(
+                    self.name, self.settings, took_ms,
+                    json.dumps(query)[:512] if query is not None else "{}",
+                )
 
     def _search_inner(
         self, query=None, size=10, from_=0, aggs=None, knn=None,
@@ -1643,7 +1644,6 @@ class EsIndex:
 
         from ..query.dsl import parse_query
         from ..serving.coalesce import term_disjunction_of
-        from ..telemetry import TRACER
 
         n = len(entries)
         job = {"entries": entries, "slots": [None] * n, "fmt": [None] * n,
@@ -1658,189 +1658,186 @@ class EsIndex:
                         # is the contract; extras (escalations, agg
                         # pass 2, starved-knn reruns) are counted here
                         "transitions": {"dispatch": 0, "fetch": 0}}}
-        with TRACER.span("servingWaveDispatch", index=self.name, entries=n,
-                         spmd=getattr(self._searcher, "_exec", "vmap")
-                         if self._searcher is not None else "vmap"):
-            self._maybe_refresh()
-            kinds = [None] * n
-            for i, e in enumerate(entries):
-                self.counters["query_total"] = (
-                    self.counters.get("query_total", 0) + 1)
-                try:
-                    if any(e.get(kk) is not None
-                           for kk in self._WAVE_UNSUPPORTED) or (
-                            e.get("knn") is not None
-                            and e.get("query") is not None):
-                        kinds[i] = "fallback"
-                    else:
-                        kinds[i] = "wave"
-                except Exception as ex:  # noqa: BLE001 - per-entry envelope
-                    job["slots"][i] = ("error", ex)
-            # fallback entries first: a non-tier-capable solo search may
-            # merge (base, tail) tiers, and the wave lanes must see the
-            # post-merge state exactly like solo sequential execution
-            for i, e in enumerate(entries):
-                if kinds[i] != "fallback":
-                    continue
-                try:
-                    job["slots"][i] = ("resp", self.search(**e))
-                except Exception as ex:  # noqa: BLE001
-                    job["slots"][i] = ("error", ex)
-            wave_ix = [i for i in range(n)
-                       if kinds[i] == "wave" and job["slots"][i] is None]
-            # per-entry effective kwargs + format context
-            plans = {}
-            for i in wave_ix:
-                e = entries[i]
-                try:
-                    query, knn = e.get("query"), e.get("knn")
-                    if self.engine is not None and (knn is not None
-                                                    or query is not None):
-                        from ..inference import resolve_query_vector_builders
+        self._maybe_refresh()
+        kinds = [None] * n
+        for i, e in enumerate(entries):
+            self.counters["query_total"] = (
+                self.counters.get("query_total", 0) + 1)
+            try:
+                if any(e.get(kk) is not None
+                       for kk in self._WAVE_UNSUPPORTED) or (
+                        e.get("knn") is not None
+                        and e.get("query") is not None):
+                    kinds[i] = "fallback"
+                else:
+                    kinds[i] = "wave"
+            except Exception as ex:  # noqa: BLE001 - per-entry envelope
+                job["slots"][i] = ("error", ex)
+        # fallback entries first: a non-tier-capable solo search may
+        # merge (base, tail) tiers, and the wave lanes must see the
+        # post-merge state exactly like solo sequential execution
+        for i, e in enumerate(entries):
+            if kinds[i] != "fallback":
+                continue
+            try:
+                job["slots"][i] = ("resp", self.search(**e))
+            except Exception as ex:  # noqa: BLE001
+                job["slots"][i] = ("error", ex)
+        wave_ix = [i for i in range(n)
+                   if kinds[i] == "wave" and job["slots"][i] is None]
+        # per-entry effective kwargs + format context
+        plans = {}
+        for i in wave_ix:
+            e = entries[i]
+            try:
+                query, knn = e.get("query"), e.get("knn")
+                if self.engine is not None and (knn is not None
+                                                or query is not None):
+                    from ..inference import resolve_query_vector_builders
 
-                        svc = self.engine.inference
-                        query = resolve_query_vector_builders(query, svc)
-                        knn = resolve_query_vector_builders(knn, svc)
-                    size = int(e.get("size", 10))
-                    from_ = int(e.get("from_", 0))
-                    plans[i] = {"query": query, "knn": knn, "size": size,
-                                "from_": from_,
-                                "tth": e.get("track_total_hits"),
-                                "aggs": e.get("aggs")}
-                except Exception as ex:  # noqa: BLE001
-                    job["slots"][i] = ("error", ex)
-            wave_ix = [i for i in wave_ix if job["slots"][i] is None]
-            # tiered lane: only when EVERY wave entry is tier-capable (a
-            # single generic entry would merge the tiers when run solo)
-            tiered_nodes = {}
-            if self._tails and wave_ix:
-                for i in wave_ix:
-                    p = plans[i]
-                    if p["aggs"] or p["knn"] is not None:
-                        tiered_nodes = None
-                        break
-                    nd = self._tier_node(p["query"])
-                    if nd is None:
-                        tiered_nodes = None
-                        break
-                    tiered_nodes[i] = nd
-            else:
-                tiered_nodes = None
-            if tiered_nodes:
-                reqs = []
-                for i in wave_ix:
-                    p = plans[i]
-                    q = (p["query"] if isinstance(p["query"], dict)
-                         or p["query"] is None else tiered_nodes[i])
-                    k = max(p["size"] + p["from_"], 1)
-                    reqs.append(dict(query=q, size=k, from_=0,
-                                     aggs=None, mappings=None))
-                    job["fmt"][i] = p
-                segs = list(self._tails)
-                job["tiered"] = {
-                    "ix": wave_ix,
-                    "base": (self._searcher,
-                             self._searcher.search_many_begin(reqs)),
-                    # one batched program per tail segment, all dispatched
-                    # here and pulled by the wave's single combined fetch;
-                    # shard_docs captured NOW — a background fold may swap
-                    # the live segment list before this wave finishes
-                    "tails": [
-                        (seg.searcher, seg.searcher.search_many_begin(
-                            [dict(r) for r in reqs]))
-                        for seg in segs
-                    ],
-                    "tail_shard_docs": [seg.shard_docs for seg in segs],
-                }
-                return self._wave_mark_dispatched(job)
-            if not wave_ix:
-                return self._wave_mark_dispatched(job)
-            searcher = self.searcher  # merges tiers when present, like solo
-            # term lane extraction (packs into one batched program per
-            # (field, k)); everything else goes generic
-            term_groups: dict[tuple, list] = {}
-            generic_ix, generic_reqs = [], []
+                    svc = self.engine.inference
+                    query = resolve_query_vector_builders(query, svc)
+                    knn = resolve_query_vector_builders(knn, svc)
+                size = int(e.get("size", 10))
+                from_ = int(e.get("from_", 0))
+                plans[i] = {"query": query, "knn": knn, "size": size,
+                            "from_": from_,
+                            "tth": e.get("track_total_hits"),
+                            "aggs": e.get("aggs")}
+            except Exception as ex:  # noqa: BLE001
+                job["slots"][i] = ("error", ex)
+        wave_ix = [i for i in wave_ix if job["slots"][i] is None]
+        # tiered lane: only when EVERY wave entry is tier-capable (a
+        # single generic entry would merge the tiers when run solo)
+        tiered_nodes = {}
+        if self._tails and wave_ix:
             for i in wave_ix:
                 p = plans[i]
-                spec = None
-                if (not p["aggs"] and p["knn"] is None
-                        and isinstance(p["query"], dict)
-                        and searcher is not None and searcher.sp.n_max > 0):
-                    t_parse = time.perf_counter_ns()
-                    try:
-                        spec = term_disjunction_of(
-                            parse_query(p["query"], self.mappings))
-                    except Exception:  # noqa: BLE001 - generic lane raises it
-                        spec = None
-                    job["meta"]["parse_ns"] += (time.perf_counter_ns()
-                                                - t_parse)
-                if spec is not None:
-                    fld, terms = spec
-                    k = max(p["size"] + p["from_"], 1)
-                    term_groups.setdefault((fld, k), []).append((i, terms))
-                    job["fmt"][i] = p
-                    continue
-                # generic (incl. knn-only): replicate _search_inner's
-                # eligible prologue
+                if p["aggs"] or p["knn"] is not None:
+                    tiered_nodes = None
+                    break
+                nd = self._tier_node(p["query"])
+                if nd is None:
+                    tiered_nodes = None
+                    break
+                tiered_nodes[i] = nd
+        else:
+            tiered_nodes = None
+        if tiered_nodes:
+            reqs = []
+            for i in wave_ix:
+                p = plans[i]
+                q = (p["query"] if isinstance(p["query"], dict)
+                     or p["query"] is None else tiered_nodes[i])
+                k = max(p["size"] + p["from_"], 1)
+                reqs.append(dict(query=q, size=k, from_=0,
+                                 aggs=None, mappings=None))
+                job["fmt"][i] = p
+            segs = list(self._tails)
+            job["tiered"] = {
+                "ix": wave_ix,
+                "base": (self._searcher,
+                         self._searcher.search_many_begin(reqs)),
+                # one batched program per tail segment, all dispatched
+                # here and pulled by the wave's single combined fetch;
+                # shard_docs captured NOW — a background fold may swap
+                # the live segment list before this wave finishes
+                "tails": [
+                    (seg.searcher, seg.searcher.search_many_begin(
+                        [dict(r) for r in reqs]))
+                    for seg in segs
+                ],
+                "tail_shard_docs": [seg.shard_docs for seg in segs],
+            }
+            return self._wave_mark_dispatched(job)
+        if not wave_ix:
+            return self._wave_mark_dispatched(job)
+        searcher = self.searcher  # merges tiers when present, like solo
+        # term lane extraction (packs into one batched program per
+        # (field, k)); everything else goes generic
+        term_groups: dict[tuple, list] = {}
+        generic_ix, generic_reqs = [], []
+        for i in wave_ix:
+            p = plans[i]
+            spec = None
+            if (not p["aggs"] and p["knn"] is None
+                    and isinstance(p["query"], dict)
+                    and searcher is not None and searcher.sp.n_max > 0):
+                t_parse = time.perf_counter_ns()
                 try:
-                    aggs_request = p["aggs"]
-                    from ..aggs.pipeline import strip_pipeline_aggs
+                    spec = term_disjunction_of(
+                        parse_query(p["query"], self.mappings))
+                except Exception:  # noqa: BLE001 - generic lane raises it
+                    spec = None
+                job["meta"]["parse_ns"] += (time.perf_counter_ns()
+                                            - t_parse)
+            if spec is not None:
+                fld, terms = spec
+                k = max(p["size"] + p["from_"], 1)
+                term_groups.setdefault((fld, k), []).append((i, terms))
+                job["fmt"][i] = p
+                continue
+            # generic (incl. knn-only): replicate _search_inner's
+            # eligible prologue
+            try:
+                aggs_request = p["aggs"]
+                from ..aggs.pipeline import strip_pipeline_aggs
 
-                    aggs, had_pipeline = strip_pipeline_aggs(aggs_request)
-                    aggs = aggs or None
-                    query, size = p["query"], p["size"]
-                    knn_clamp = None
-                    if p["knn"] is not None:
-                        from ..query.dsl import parse_knn
-                        from ..query.nodes import BoolNode
+                aggs, had_pipeline = strip_pipeline_aggs(aggs_request)
+                aggs = aggs or None
+                query, size = p["query"], p["size"]
+                knn_clamp = None
+                if p["knn"] is not None:
+                    from ..query.dsl import parse_knn
+                    from ..query.nodes import BoolNode
 
-                        knn = p["knn"]
-                        knn_nodes = [
-                            parse_knn(kn, self.mappings)
-                            for kn in (knn if isinstance(knn, list)
-                                       else [knn])
-                        ]
-                        self._apply_knn_settings(knn_nodes)
-                        k_total = sum(kn.k for kn in knn_nodes)
-                        query = (knn_nodes[0] if len(knn_nodes) == 1 else
-                                 BoolNode(should=knn_nodes,
-                                          minimum_should_match=1))
-                        size = min(size, max(k_total - p["from_"], 0))
-                        knn_clamp = k_total
-                    generic_ix.append(i)
-                    generic_reqs.append(dict(
-                        query=query, size=size, from_=p["from_"],
-                        aggs=aggs, mappings=None))
-                    job["fmt"][i] = {**p, "aggs_request": aggs_request,
-                                     "had_pipeline": had_pipeline,
-                                     "knn_clamp": knn_clamp,
-                                     "knn_query": (query if knn_clamp
-                                                   is not None else None),
-                                     "eff_size": size, "eff_aggs": aggs}
-                except Exception as ex:  # noqa: BLE001
+                    knn = p["knn"]
+                    knn_nodes = [
+                        parse_knn(kn, self.mappings)
+                        for kn in (knn if isinstance(knn, list)
+                                   else [knn])
+                    ]
+                    self._apply_knn_settings(knn_nodes)
+                    k_total = sum(kn.k for kn in knn_nodes)
+                    query = (knn_nodes[0] if len(knn_nodes) == 1 else
+                             BoolNode(should=knn_nodes,
+                                      minimum_should_match=1))
+                    size = min(size, max(k_total - p["from_"], 0))
+                    knn_clamp = k_total
+                generic_ix.append(i)
+                generic_reqs.append(dict(
+                    query=query, size=size, from_=p["from_"],
+                    aggs=aggs, mappings=None))
+                job["fmt"][i] = {**p, "aggs_request": aggs_request,
+                                 "had_pipeline": had_pipeline,
+                                 "knn_clamp": knn_clamp,
+                                 "knn_query": (query if knn_clamp
+                                               is not None else None),
+                                 "eff_size": size, "eff_aggs": aggs}
+            except Exception as ex:  # noqa: BLE001
+                job["slots"][i] = ("error", ex)
+        if generic_ix:
+            job["lanes"].append({
+                "ix": generic_ix, "searcher": searcher,
+                "state": searcher.search_many_begin(generic_reqs),
+            })
+        # term groups DISPATCH here and fetch with the rest of the
+        # wave: under the pjit model each (field, k) group
+        # is ONE merged SPMD program whose outputs join the wave's
+        # single combined device_get — the term lane no longer
+        # blocks the scheduler thread inside begin. Response
+        # building moved to search_wave_finish.
+        for (fld, k), members in sorted(term_groups.items()):
+            try:
+                from ..parallel.sharded import msearch_wave_begin
+
+                st = msearch_wave_begin(
+                    searcher, fld, [t for _, t in members], k)
+                job["term_lanes"].append(
+                    {"fld": fld, "k": k, "members": members, "st": st})
+            except Exception as ex:  # noqa: BLE001
+                for i, _terms in members:
                     job["slots"][i] = ("error", ex)
-            if generic_ix:
-                job["lanes"].append({
-                    "ix": generic_ix, "searcher": searcher,
-                    "state": searcher.search_many_begin(generic_reqs),
-                })
-            # term groups DISPATCH here and fetch with the rest of the
-            # wave (PR 11): under the pjit model each (field, k) group
-            # is ONE merged SPMD program whose outputs join the wave's
-            # single combined device_get — the term lane no longer
-            # blocks the scheduler thread inside begin. Response
-            # building moved to search_wave_finish.
-            for (fld, k), members in sorted(term_groups.items()):
-                try:
-                    from ..parallel.sharded import msearch_wave_begin
-
-                    st = msearch_wave_begin(
-                        searcher, fld, [t for _, t in members], k)
-                    job["term_lanes"].append(
-                        {"fld": fld, "k": k, "members": members, "st": st})
-                except Exception as ex:  # noqa: BLE001
-                    for i, _terms in members:
-                        job["slots"][i] = ("error", ex)
         return self._wave_mark_dispatched(job)
 
     @staticmethod
@@ -1925,134 +1922,132 @@ class EsIndex:
         """Finalize a fetched wave -> per-entry response dict (or the
         entry's exception object) in entry order. Engine thread only:
         response building reads shard docs and stores cache entries."""
-        from ..telemetry import TRACER, record_search_slowlog
+        from ..telemetry import record_search_slowlog
 
-        with TRACER.span("servingWaveFinalize", index=self.name,
-                         entries=len(job["entries"])):
-            for lane in job["lanes"]:
-                results = lane["searcher"].search_many_finish(
-                    lane["state"], raise_errors=False)
-                for i, res in zip(lane["ix"], results):
-                    if isinstance(res, Exception):
-                        job["slots"][i] = ("error", res)
-                        continue
-                    p = job["fmt"][i]
-                    try:
-                        if p.get("knn_clamp") is not None:
-                            # starved filtered-ANN retrieval re-runs solo
-                            # on the exact scan (same escalation as
-                            # _search_inner, so wave == solo results)
-                            if self._knn_mark_starved(
-                                    p["knn_query"],
-                                    len(res.doc_ids) + p["from_"],
-                                    p["eff_size"] + p["from_"]):
-                                tr = job["meta"]["transitions"]
-                                tr["dispatch"] += 1
-                                tr["fetch"] += 1
-                                res = lane["searcher"].search(
-                                    p["knn_query"], size=p["eff_size"],
-                                    from_=p["from_"], aggs=p["eff_aggs"])
-                            res.total = min(res.total, p["knn_clamp"])
-                        job["slots"][i] = ("resp", self._format_generic_hits(
-                            res, p["tth"],
-                            p.get("aggs_request"), p.get("had_pipeline"),
-                        ))
-                    except Exception as ex:  # noqa: BLE001
-                        job["slots"][i] = ("error", ex)
-            # deferred term lanes (PR 11): finish the merged programs and
-            # build responses here, after the wave's single fetch
-            import numpy as _np
-
-            for tl in job.get("term_lanes", ()):
-                members = tl["members"]
-                fld, k = tl["fld"], tl["k"]
-                try:
-                    from ..parallel.sharded import msearch_wave_finish
-
-                    (v, sh, dc, tt), tier = msearch_wave_finish(tl["st"])
-                    job["meta"]["term_packed"] += len(members)
-                    job["meta"]["term_waves"].append(
-                        (len(members), int(tier)))
-                    for row, (i, _terms) in enumerate(members):
-                        p = job["fmt"][i]
-                        nvalid = int(_np.isfinite(v[row]).sum())
-                        take = list(range(min(nvalid, k)))[
-                            p["from_"]: p["size"] + p["from_"]]
-                        hits = []
-                        for j in take:
-                            doc_id, src = self.shard_docs[
-                                int(sh[row][j])][int(dc[row][j])]
-                            hits.append({"_index": self.name,
-                                         "_id": doc_id,
-                                         "_score": float(v[row][j]),
-                                         "_source": src})
-                        hits_obj = {
-                            "total": {"value": int(tt[row]),
-                                      "relation": "eq"},
-                            "max_score": (float(v[row][0]) if nvalid
-                                          else None),
-                            "hits": hits,
-                        }
-                        if p["tth"] is False:
-                            del hits_obj["total"]
-                        job["slots"][i] = ("resp", {"hits": hits_obj})
-                except Exception as ex:  # noqa: BLE001
-                    for i, _terms in members:
-                        job["slots"][i] = ("error", ex)
-            t = job.get("tiered")
-            if t is not None:
-                base = t["base"][0].search_many_finish(
-                    t["base"][1], raise_errors=False)
-                tails = [s.search_many_finish(st, raise_errors=False)
-                         for s, st in t["tails"]]
-                for pos, i in enumerate(t["ix"]):
-                    rb = base[pos]
-                    rts = [tl[pos] for tl in tails]
-                    err = next((r for r in (rb, *rts)
-                                if isinstance(r, Exception)), None)
-                    if err is not None:
-                        job["slots"][i] = ("error", err)
-                        continue
-                    p = job["fmt"][i]
-                    try:
-                        job["slots"][i] = ("resp", self._tiered_merge(
-                            rb, rts, p["size"], p["from_"],
-                            p["tth"], t["tail_shard_docs"]))
-                    except Exception as ex:  # noqa: BLE001
-                        job["slots"][i] = ("error", ex)
-            # extra device rounds taken during finish (fused escalation,
-            # two-pass aggs) roll into the wave's transition meta —
-            # counted, never hidden
-            tr = job["meta"]["transitions"]
-            extra_states = [lane["state"] for lane in job["lanes"]]
-            extra_states += [tl["st"].get("merged")
-                             for tl in job.get("term_lanes", ())]
-            if t is not None:
-                extra_states += [t["base"][1]] + [st for _s, st
-                                                  in t["tails"]]
-            for s in extra_states:
-                if s is None:
+        for lane in job["lanes"]:
+            results = lane["searcher"].search_many_finish(
+                lane["state"], raise_errors=False)
+            for i, res in zip(lane["ix"], results):
+                if isinstance(res, Exception):
+                    job["slots"][i] = ("error", res)
                     continue
-                tr["dispatch"] += s.pop("extra_dispatches", 0)
-                tr["fetch"] += s.pop("extra_fetches", 0)
-            took_ms = (time.monotonic() - job["t0"]) * 1000
-            out = []
-            for i, slot in enumerate(job["slots"]):
-                if slot is None:  # cannot happen; fail loudly per entry
-                    slot = ("error",
-                            RuntimeError("serving wave lost an entry"))
-                kind, payload = slot
-                if kind == "resp":
-                    # the wave wall IS each member's service time; slowlog
-                    # and query_time attribute it per entry
-                    self.counters["query_time_ms"] = (
-                        self.counters.get("query_time_ms", 0)
-                        + int(took_ms))
-                    q = job["entries"][i].get("query")
-                    record_search_slowlog(
-                        self.name, self.settings, took_ms,
-                        json.dumps(q)[:512] if q is not None else "{}")
-                out.append(payload)
+                p = job["fmt"][i]
+                try:
+                    if p.get("knn_clamp") is not None:
+                        # starved filtered-ANN retrieval re-runs solo
+                        # on the exact scan (same escalation as
+                        # _search_inner, so wave == solo results)
+                        if self._knn_mark_starved(
+                                p["knn_query"],
+                                len(res.doc_ids) + p["from_"],
+                                p["eff_size"] + p["from_"]):
+                            tr = job["meta"]["transitions"]
+                            tr["dispatch"] += 1
+                            tr["fetch"] += 1
+                            res = lane["searcher"].search(
+                                p["knn_query"], size=p["eff_size"],
+                                from_=p["from_"], aggs=p["eff_aggs"])
+                        res.total = min(res.total, p["knn_clamp"])
+                    job["slots"][i] = ("resp", self._format_generic_hits(
+                        res, p["tth"],
+                        p.get("aggs_request"), p.get("had_pipeline"),
+                    ))
+                except Exception as ex:  # noqa: BLE001
+                    job["slots"][i] = ("error", ex)
+        # deferred term lanes: finish the merged programs and
+        # build responses here, after the wave's single fetch
+        import numpy as _np
+
+        for tl in job.get("term_lanes", ()):
+            members = tl["members"]
+            fld, k = tl["fld"], tl["k"]
+            try:
+                from ..parallel.sharded import msearch_wave_finish
+
+                (v, sh, dc, tt), tier = msearch_wave_finish(tl["st"])
+                job["meta"]["term_packed"] += len(members)
+                job["meta"]["term_waves"].append(
+                    (len(members), int(tier)))
+                for row, (i, _terms) in enumerate(members):
+                    p = job["fmt"][i]
+                    nvalid = int(_np.isfinite(v[row]).sum())
+                    take = list(range(min(nvalid, k)))[
+                        p["from_"]: p["size"] + p["from_"]]
+                    hits = []
+                    for j in take:
+                        doc_id, src = self.shard_docs[
+                            int(sh[row][j])][int(dc[row][j])]
+                        hits.append({"_index": self.name,
+                                     "_id": doc_id,
+                                     "_score": float(v[row][j]),
+                                     "_source": src})
+                    hits_obj = {
+                        "total": {"value": int(tt[row]),
+                                  "relation": "eq"},
+                        "max_score": (float(v[row][0]) if nvalid
+                                      else None),
+                        "hits": hits,
+                    }
+                    if p["tth"] is False:
+                        del hits_obj["total"]
+                    job["slots"][i] = ("resp", {"hits": hits_obj})
+            except Exception as ex:  # noqa: BLE001
+                for i, _terms in members:
+                    job["slots"][i] = ("error", ex)
+        t = job.get("tiered")
+        if t is not None:
+            base = t["base"][0].search_many_finish(
+                t["base"][1], raise_errors=False)
+            tails = [s.search_many_finish(st, raise_errors=False)
+                     for s, st in t["tails"]]
+            for pos, i in enumerate(t["ix"]):
+                rb = base[pos]
+                rts = [tl[pos] for tl in tails]
+                err = next((r for r in (rb, *rts)
+                            if isinstance(r, Exception)), None)
+                if err is not None:
+                    job["slots"][i] = ("error", err)
+                    continue
+                p = job["fmt"][i]
+                try:
+                    job["slots"][i] = ("resp", self._tiered_merge(
+                        rb, rts, p["size"], p["from_"],
+                        p["tth"], t["tail_shard_docs"]))
+                except Exception as ex:  # noqa: BLE001
+                    job["slots"][i] = ("error", ex)
+        # extra device rounds taken during finish (fused escalation,
+        # two-pass aggs) roll into the wave's transition meta —
+        # counted, never hidden
+        tr = job["meta"]["transitions"]
+        extra_states = [lane["state"] for lane in job["lanes"]]
+        extra_states += [tl["st"].get("merged")
+                         for tl in job.get("term_lanes", ())]
+        if t is not None:
+            extra_states += [t["base"][1]] + [st for _s, st
+                                              in t["tails"]]
+        for s in extra_states:
+            if s is None:
+                continue
+            tr["dispatch"] += s.pop("extra_dispatches", 0)
+            tr["fetch"] += s.pop("extra_fetches", 0)
+        took_ms = (time.monotonic() - job["t0"]) * 1000
+        out = []
+        for i, slot in enumerate(job["slots"]):
+            if slot is None:  # cannot happen; fail loudly per entry
+                slot = ("error",
+                        RuntimeError("serving wave lost an entry"))
+            kind, payload = slot
+            if kind == "resp":
+                # the wave wall IS each member's service time; slowlog
+                # and query_time attribute it per entry
+                self.counters["query_time_ms"] = (
+                    self.counters.get("query_time_ms", 0)
+                    + int(took_ms))
+                q = job["entries"][i].get("query")
+                record_search_slowlog(
+                    self.name, self.settings, took_ms,
+                    json.dumps(q)[:512] if q is not None else "{}")
+            out.append(payload)
         return out
 
     def search_wave(self, entries: list[dict]) -> list:

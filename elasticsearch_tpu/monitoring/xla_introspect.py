@@ -298,7 +298,13 @@ def check_dispatch(name: str, fn, args=(), kwargs=None,
                 return None
             _seen_sigs.add((name, sig))
             _capture_counts[name] = _capture_counts.get(name, 0) + 1
-        return _capture(name, fn, args, kwargs or {}, fields or {})
+        t0 = time.perf_counter_ns()
+        try:
+            return _capture(name, fn, args, kwargs or {}, fields or {})
+        finally:
+            # what the cross-checks cost the process: set-up's share of them
+            metrics.counter_inc("es.costmodel.xla_check.ns",
+                                time.perf_counter_ns() - t0)
     except Exception as e:  # noqa: BLE001 - accounting never fails a search
         log.debug("xla cross-check for [%s] failed: %s", name, e)
         return None

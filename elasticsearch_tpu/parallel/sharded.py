@@ -991,10 +991,11 @@ class StackedSearcher:
             with TRACER.span("engine.fetch"):
                 host = jax.device_get([s["outs"] for s in states])
         wave2 = []
-        for s, ho in zip(states, host):
-            s["host"] = self._fetched(ho, s["out_layout"])
-            if self._agg_pass2_dispatch(s):
-                wave2.append(s)
+        with TRACER.span("engine.unpack"):
+            for s, ho in zip(states, host):
+                s["host"] = self._fetched(ho, s["out_layout"])
+                if self._agg_pass2_dispatch(s):
+                    wave2.append(s)
         if wave2:
             host2 = jax.device_get([s["outs2"] for s in wave2])
             for s, h2 in zip(wave2, host2):
@@ -1020,7 +1021,7 @@ class StackedSearcher:
     def _agg_dispatch(self, query=None, size=10, from_=0, aggs=None,
                       mappings=None):
         """Plan + launch one request's pass-1 program (no device fetch)."""
-        from ..telemetry import SOLO_ROWS, TRACER, metrics
+        from ..telemetry import SOLO_ROWS, TRACER, account, metrics
 
         m = mappings if mappings is not None else self.sp.mappings
         node, agg_nodes = self._parsed(query, m, aggs)
@@ -1060,13 +1061,14 @@ class StackedSearcher:
             if tiers is not None:
                 plan.attributes.update(dense_tier=tiers[0], rows_tier=tiers[1],
                                        padded_rows=padded)
-        metrics.counters_add([(SOLO_ROWS, (real, padded))])
-        from ..monitoring.xla_introspect import check_dispatch
+        with account():
+            from ..monitoring.xla_introspect import check_dispatch
 
-        metrics.counter_inc("es.search.topk.xla_topk")
-        check_dispatch("sharded.spmd_topk", fn, (self.dev, buffers),
-                       fields={"queries": 1, "k": k,
-                               "num_docs": self.sp.S * self.sp.n_max})
+            metrics.counters_add([(SOLO_ROWS, (real, padded))])
+            metrics.counter_inc("es.search.topk.xla_topk")
+            check_dispatch("sharded.spmd_topk", fn, (self.dev, buffers),
+                           fields={"queries": 1, "k": k,
+                                   "num_docs": self.sp.S * self.sp.n_max})
         # one transfer per buffer and the launch; behind a miss also trace,
         # lower and compile
         with TRACER.span("engine.dispatch",

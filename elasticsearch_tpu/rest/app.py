@@ -16,14 +16,16 @@ from __future__ import annotations
 
 import asyncio
 import json
+import logging
 import time
 from concurrent.futures import ThreadPoolExecutor
 
 from aiohttp import web
+from aiohttp.web_log import AccessLogger
 
 from .. import __version__
 from ..engine import Engine
-from ..telemetry import TRACER
+from ..telemetry import TRACER, begin_lead, end_lead
 from ..utils.errors import ElasticsearchTpuError, IllegalArgumentError
 
 JSON = "application/json"
@@ -69,6 +71,25 @@ def _err_response(ex: Exception) -> web.Response:
     return web.json_response(body, status=status, headers=headers)
 
 
+class _AnswerStamp(AccessLogger):
+    """aiohttp's access logger, one a connection, which aiohttp calls once
+    an answer has been written (`write_eof`), the latest point it exposes:
+    it keeps that moment for the connection's next request, whose client gap
+    `_tracing_middleware` counts, and logs as aiohttp's own where its logger
+    is on."""
+
+    answered_ns: int | None = None
+
+    @property
+    def enabled(self) -> bool:
+        return True
+
+    def log(self, request, response, seconds: float) -> None:
+        self.answered_ns = time.perf_counter_ns()
+        if self.logger.isEnabledFor(logging.INFO):
+            super().log(request, response, seconds)
+
+
 @web.middleware
 async def _tracing_middleware(request: web.Request, handler):
     """Distributed tracing at the REST boundary: accept a W3C
@@ -76,13 +97,17 @@ async def _tracing_middleware(request: web.Request, handler):
     run the request under a root span, and hand the trace id back in the
     response headers — the reference's RestController + ThreadContext
     trace-header behavior, with the APM agent replaced by the in-process
-    tracer (telemetry.TRACER)."""
-    import time as _time
-
-    from ..telemetry import (TraceContext, activate_trace,
+    tracer (telemetry.TRACER). A request that follows an answer on its
+    connection adds the time between the two to the client gap."""
+    from ..telemetry import (CLIENT_GAP, TraceContext, activate_trace,
                              format_traceparent, metrics, new_trace_id,
                              parse_traceparent)
 
+    conn = getattr(request.protocol, "access_logger", None)
+    if isinstance(conn, _AnswerStamp) and conn.answered_ns is not None:
+        metrics.counters_add(
+            [(CLIENT_GAP, (time.perf_counter_ns() - conn.answered_ns, 1))])
+        conn.answered_ns = None
     parsed = parse_traceparent(request.headers.get("traceparent"))
     ctx = TraceContext(
         trace_id=parsed[0] if parsed else new_trace_id(),
@@ -94,7 +119,7 @@ async def _tracing_middleware(request: web.Request, handler):
     # document id would be a name per document wherever names are summed
     resource = request.match_info.route.resource
     route = resource.canonical if resource is not None else "<unmatched>"
-    t0 = _time.perf_counter()
+    t0 = time.perf_counter()
     with activate_trace(ctx, node=node):
         with TRACER.span(f"http {request.method} {route}",
                          method=request.method, path=request.path,
@@ -102,7 +127,7 @@ async def _tracing_middleware(request: web.Request, handler):
                          ) as span:
             resp = await handler(request)
             span.attributes["status"] = resp.status
-    ms = (_time.perf_counter() - t0) * 1000
+    ms = (time.perf_counter() - t0) * 1000
     metrics.histogram_record("es.rest.request.ms", ms)
     resp.headers["X-Trace-Id"] = ctx.trace_id
     resp.headers["traceparent"] = format_traceparent(ctx.trace_id,
@@ -176,6 +201,7 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
         client_max_size=512 * 1024 * 1024,
         middlewares=[_tracing_middleware, _xcontent_middleware,
                      _warnings_middleware, _security_middleware],
+        handler_args={"access_log_class": _AnswerStamp},
     )
     app["engine"] = engine
     # single-thread executor: serializes engine mutation, keeps the loop free
@@ -194,6 +220,15 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
     from ..monitoring import install_compile_listener
 
     install_compile_listener()
+    from ..telemetry import CLIENT_GAP, metrics
+
+    # shipped at 0 from the start: a reader gives 0 where the work did not
+    # happen, and nothing only on a server without these counters
+    metrics.counters_add(
+        [((f"es.span.{s}.ns", f"es.span.{s}.count"), (0, 0))
+         for s in ("engine.route", "engine.unpack", "engine.account",
+                   "rest.read", "rest.resume")] + [(CLIENT_GAP, (0, 0))])
+    metrics.counter_inc("es.costmodel.xla_check.ns", 0)
 
     async def call(fn, *args, **kwargs):
         loop = asyncio.get_running_loop()
@@ -204,22 +239,37 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
 
         ctx = contextvars.copy_context()
         cur = TRACER.current_span()
-        handed = time.perf_counter_ns()
+        search = cur is not None and cur.name == "rest.search"
+        handed = answered = time.perf_counter_ns()
 
         def on_engine_thread():
-            if cur is not None and cur.name == "rest.search":
+            nonlocal answered
+            if search:
                 # a search's wait behind the one engine thread, which no
                 # thread performs; any other endpoint's is in its root alone
                 TRACER.record("engine.queue", handed, time.perf_counter_ns())
-            return fn(*args, **kwargs)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                answered = time.perf_counter_ns()
 
-        return await loop.run_in_executor(
+        res = await loop.run_in_executor(
             app["pool"], ctx.run, on_engine_thread)
+        if search:
+            # the loop's wake-up once the answer is ready: its turn behind
+            # the other callbacks
+            TRACER.record("rest.resume", answered, time.perf_counter_ns())
+        return res
 
     def engine_search(fn, *args, **kwargs):
-        """Everything the engine thread does for one search, as one span."""
+        """Everything the engine thread does for one search, as one span;
+        its stretch up to the first stage is `engine.route`."""
         with TRACER.span("engine.search"):
-            return fn(*args, **kwargs)
+            begin_lead("engine.route")
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                end_lead()
 
     def handler(fn):
         async def wrapped(request: web.Request):
@@ -2137,7 +2187,8 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
     @handler
     async def search(request):
         with TRACER.span("rest.search"):
-            body = await body_json(request, {})
+            with TRACER.span("rest.read"):
+                body = await body_json(request, {})
             return await _run_search(request.match_info.get("index"), body,
                                      request.query, web.json_response)
 

@@ -191,11 +191,11 @@ class ServingService:
         _LIVE_SERVICES.add(self)
         # what a wave adds to (`_count_wave`) reads 0 from the start, not
         # nothing: a node whose front end is off has served no wave
-        from ..telemetry import WAVE_STAGES, metrics
+        from ..telemetry import WAVE_HANDOFFS, WAVE_STAGES, metrics
 
         metrics.counters_add(
             [((f"es.span.{s}.ns", f"es.span.{s}.count"), (0, 0))
-             for s in WAVE_STAGES] + [
+             for s in WAVE_STAGES + WAVE_HANDOFFS] + [
                 (("es.serving.wave.count", "es.serving.wave.members"), (0, 0)),
                 (("es.serving.wave.padded_rows", "es.serving.wave.wait_ns"),
                  (0, 0)),
@@ -421,6 +421,8 @@ class ServingService:
                           "completed"] += 1
         if ps.future.done():
             return
+        # where the member's `rest.resume` starts (`member_spans`)
+        ps.future.answered_ns = time.perf_counter_ns()
         if error is not None:
             ps.future.set_exception(error)
         else:
@@ -583,11 +585,6 @@ class ServingService:
                     with self._lock:
                         self._inflight_count -= 1
                     continue
-                # flight-recorder timestamps: contiguous boundaries so the
-                # per-wave segments sum to the wall time by construction
-                state["t_admit"] = min(ps.enqueue_t for ps in ready)
-                state["t_claim"] = now
-                state["t_dispatched"] = time.monotonic()
                 state["dropped"] = dropped
                 # depth-1 handoff: the double buffer — blocks only while
                 # the completer still owns the PREVIOUS wave
@@ -626,9 +623,9 @@ class ServingService:
             from ..telemetry import collect_profile_events
 
             try:
-                faults.check("serving.wave", n=state["n"])
                 with collect_profile_events() as events, \
                         state["stages"].stage("engine.wave_fetch"):
+                    faults.check("serving.wave", n=state["n"])
                     for idx, _members, job in state["jobs"]:
                         # engine-state-free device pull: overlaps the
                         # engine thread's planning of the next wave
@@ -636,7 +633,6 @@ class ServingService:
                 state.setdefault("events", []).extend(events)
             except Exception as ex:  # noqa: BLE001
                 state["fetch_error"] = ex
-            state["t_fetched"] = time.monotonic()
             try:
                 self._engine_submit(lambda: self._wave_finish(state)).result()
             except Exception as ex:  # noqa: BLE001
@@ -721,7 +717,7 @@ class ServingService:
         tenants: dict[str, int] = {}
         for ps in ready:
             tenants[ps.tenant] = tenants.get(ps.tenant, 0) + 1
-        state = {"t0": time.monotonic(), "jobs": [], "n": len(ready),
+        state = {"jobs": [], "n": len(ready),
                  "tenants": tenants, "tenant_cost": {}, "events": [],
                  "fallback_solo": 0}
         # internal lane (PR 15): background merges claimed into this
@@ -832,23 +828,26 @@ class ServingService:
 
     def _count_wave(self, state: dict, stages, rows: int) -> None:
         """What one wave adds to `_nodes/stats` -> metrics.counters, under
-        one acquisition of the lock: its four stages (`es.span.engine.
-        wave_*.ns` / `.count`), 1 to `es.serving.wave.count`, its members,
-        the rows its programs computed for them (a term lane's batch tier,
-        one for any other member) and the nanoseconds the members waited
-        between admission and the claim. A member's own `rest.search` gets
-        an equal share of each stage through `member_spans`."""
-        from ..telemetry import metrics
+        one acquisition of the lock: its four stages and three hand-offs
+        (`es.span.engine.wave_*.ns` / `.count`, which tile the claim to the
+        finish's end), 1 to `es.serving.wave.count`, its members, the rows
+        its programs computed for them (a term lane's batch tier, one for
+        any other member) and the nanoseconds the members waited between
+        admission and the claim. A member's own `rest.search` gets an equal
+        share of each of the four stages through `member_spans`."""
+        from ..telemetry import TRACER, metrics
 
         members = state["members"]
         n = len(members)
         wait_ns = sum(ps.claim_ns - ps.enqueue_ns for ps in members)
+        for name, t0, t1 in stages.handoffs(members[0].claim_ns):
+            TRACER.record(name, t0, t1)
         metrics.counters_add(stages.counter_pairs() + [
             (("es.serving.wave.count", "es.serving.wave.members"), (1, n)),
             (("es.serving.wave.padded_rows", "es.serving.wave.wait_ns"),
              (rows, wait_ns)),
         ])
-        done_ns = time.perf_counter_ns()
+        done_ns = stages.bounds["engine.wave_finish"][1]
         parse_ns = sum(job.get("meta", {}).get("parse_ns", 0)
                        for _idx, _members, job in state["jobs"])
         for ps in members:
@@ -865,13 +864,17 @@ class ServingService:
         `.collect`): engine- and completer-thread time a search, as in the
         cells without the front end. The rest of `engine.search` is the
         wait for the wave: the other members' shares, the device, the
-        hand-offs between the threads."""
+        hand-offs between the threads. Then `rest.resume`, from the answer
+        to the handler's first statement after its wait for it: the event
+        loop's wake-up (call it first thing after that wait)."""
         from ..telemetry import TRACER
 
+        resumed = time.perf_counter_ns()
         wave = getattr(future, "wave", None)
         if wave is None:
             return
         enq, claim, done, n, stages, parse_ns = wave
+        TRACER.record("rest.resume", future.answered_ns, resumed)
         TRACER.record("engine.queue", enq, claim)
         shares, at = [], claim
         for solo, ns in (
@@ -951,8 +954,9 @@ class ServingService:
                         self._occ_sum += q / max(tier, 1)
                         self._occ_n += 1
         state.setdefault("events", []).extend(fin_events)
-        t_complete = time.monotonic()
-        wave_ms = (t_complete - state["t0"]) * 1000
+        t_complete = time.perf_counter_ns()
+        wave_ms = (t_complete
+                   - state["stages"].bounds["engine.wave_plan"][0]) / 1e6
         with self._lock:
             self.counters["waves"] += 1
             if state["n"] > 1:
@@ -1010,26 +1014,31 @@ class ServingService:
     def _record_flight(self, state, t_complete, wave_tr, lanes, occ,
                        indices, err) -> None:
         """Append one per-wave record to the ring. Segment boundaries are
-        contiguous timestamps (admission→claim→dispatched→fetched→
-        complete), so segments_ms sums to wall_ms by construction —
-        asserted by tests. Never raises: the recorder is observability,
-        not the serving path."""
+        contiguous readings of the wave's own stage stamps (admission→
+        claim→planned→fetched→complete, `time.perf_counter_ns`), so
+        segments_ms sums to wall_ms by construction — asserted by tests;
+        handoffs_ms holds the waits between the threads inside them. Never
+        raises: the recorder is observability, not the serving path."""
         try:
-            t_admit = state.get("t_admit", state["t0"])
-            t_claim = state.get("t_claim", state["t0"])
-            t_disp = state.get("t_dispatched", state["t0"])
-            t_fetch = state.get("t_fetched", t_disp)
-            seg = {
+            members = state["members"]
+            t_admit = min(ps.enqueue_ns for ps in members)
+            t_claim = members[0].claim_ns
+            stages = state["stages"]
+            planned = stages.bounds["engine.wave_plan"][1]
+            fetched = stages.bounds["engine.wave_fetch"][1]
+            ms = {
                 # admission → wave claimed (queue wait + coalesce window)
-                "queue": (t_claim - t_admit) * 1000,
+                "queue": t_claim - t_admit,
                 # claim → every lane planned + dispatched (host plan cost)
-                "plan": (t_disp - t_claim) * 1000,
+                "plan": planned - t_claim,
                 # dispatch → combined fetch done (device execution + pull)
-                "device": (t_fetch - t_disp) * 1000,
+                "device": fetched - planned,
                 # fetch → futures resolved (host finish/merge/aggs)
-                "finish": (t_complete - t_fetch) * 1000,
+                "finish": t_complete - fetched,
             }
-            seg = {k: round(v, 4) for k, v in seg.items()}
+            seg = {k: round(v / 1e6, 4) for k, v in ms.items()}
+            handoffs = {name.rsplit("_", 1)[1]: round((t1 - t0) / 1e6, 4)
+                        for name, t0, t1 in stages.handoffs(t_claim)}
             kernels: dict = {}
             cache = {"hits": 0, "misses": 0}
             escalations = 0
@@ -1144,7 +1153,8 @@ class ServingService:
                     "indices": sorted(set(indices)),
                     "lanes": lanes,
                     "segments_ms": seg,
-                    "wall_ms": round((t_complete - t_admit) * 1000, 4),
+                    "handoffs_ms": handoffs,
+                    "wall_ms": round((t_complete - t_admit) / 1e6, 4),
                     "host_transitions": wave_tr,
                     "term_occupancy": (round(sum(occ) / len(occ), 4)
                                        if occ else None),

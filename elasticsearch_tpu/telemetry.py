@@ -148,11 +148,18 @@ def context_from_headers(headers: dict | None) -> TraceContext | None:
 # call), is recorded and summed nowhere: the counters stay one search's, and
 # a span named by a request's data can never mint one. A search that the
 # serving front end answered records them from its wave's stages
-# (`ServingService.member_spans`).
+# (`ServingService.member_spans`). Of the engine thread's work for a search,
+# `engine.route` is what comes before its parsing (the index's resolution,
+# the refresh check, the recovery wrapper, the request cache's look-up),
+# `engine.unpack` the fetched outputs' unpacking and `engine.account` the
+# bookkeeping of its measurements (`account()`); `rest.read` is the body's
+# reading and decoding on the event loop, and `rest.resume` the loop's
+# wake-up once the answer is ready, a wait that no thread performs.
 STAGES = (
     "rest.search", "engine.queue", "engine.search", "engine.parse",
     "engine.plan", "engine.dispatch", "engine.fetch", "engine.collect",
-    "rest.respond",
+    "rest.respond", "engine.route", "engine.unpack", "engine.account",
+    "rest.read", "rest.resume",
 )
 # The stages of a serving wave (serving/service.py), which several members
 # share and two threads carry: host planning on the engine thread up to each
@@ -163,17 +170,31 @@ STAGES = (
 # nest (a launch pauses the planning around it), so all four are leaves.
 WAVE_STAGES = ("engine.wave_plan", "engine.wave_launch", "engine.wave_fetch",
                "engine.wave_finish")
+# The hand-offs between a wave's threads, which no thread performs: from the
+# claim to the engine thread's start of the planning, from the last launch
+# to the completer's start of the fetch, from the fetch's end to the engine
+# thread's start of the finish. With the four stages above they tile a wave
+# from its claim to its answers (`WaveStages.handoffs`).
+WAVE_HANDOFFS = ("engine.wave_queue", "engine.wave_handoff",
+                 "engine.wave_rejoin")
 # The leaves among them also open a `jax.profiler.TraceAnnotation` while a
 # capture runs, which puts them on its host plane, on the capture's clock,
 # beside PJRT's own events. Parents stay off it: an idle gap of the device
 # runs from one request's fetch to the next one's launch, and a parent
-# would cover every gap and name none.
+# would cover every gap and name none. A leaf entered inside another one
+# (the solo path's stages inside a wave's) opens none either: annotations
+# never nest.
 ANNOTATED_STAGES = frozenset({
     "engine.parse", "engine.plan", "engine.dispatch", "engine.fetch",
-    "engine.collect", "rest.respond", *WAVE_STAGES,
+    "engine.collect", "rest.respond", "engine.route", "engine.unpack",
+    "engine.account", "rest.read", *WAVE_STAGES,
 })
 _STAGE_COUNTERS = {name: (f"es.span.{name}.ns", f"es.span.{name}.count")
-                   for name in STAGES + WAVE_STAGES}
+                   for name in STAGES + WAVE_STAGES + WAVE_HANDOFFS}
+# The time from a connection's last answer to its next request's arrival at
+# the REST layer (`rest/app.py`): the client's turnaround, the socket,
+# aiohttp's parsing and the event loop's delay in starting the handler.
+CLIENT_GAP = ("es.rest.client_gap.ns", "es.rest.client_gap.count")
 # What every solo search's plan adds at once (parallel/sharded._agg_dispatch):
 # the rows of the match family's two lists (query/nodes.match_params) its
 # program gathers, the sparse block rows plus the dense rows over its shards,
@@ -233,9 +254,12 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = self._tracer._current.set(self)
-        if _annotation is not None and self.name in ANNOTATED_STAGES:
-            self._ann = _annotation(self.name)
-            self._ann.__enter__()
+        if self.name in ANNOTATED_STAGES:
+            if _lead.get() is not None:
+                end_lead()
+            if _annotation is not None and not _in_stage_leaf(self._parent):
+                self._ann = _annotation(self.name)
+                self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -247,6 +271,16 @@ class Span:
         self._token = None  # it holds the parent: no cycle through it
         self._tracer._finish(self, t1)
         return False
+
+    def close(self, end_ns: int) -> None:
+        """Leave the span at `end_ns`, a `time.perf_counter_ns()` reading
+        that several intervals share as the end of one and the start of the
+        next (`WaveStages`)."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._current.reset(self._token)
+        self._token = None
+        self._tracer._finish(self, end_ns)
 
     @property
     def span_id(self) -> str:
@@ -281,6 +315,48 @@ class Span:
             "duration_ms": round(self.duration_ms, 3),
             "attributes": dict(self.attributes),
         }
+
+
+# a leaf stage that crosses functions: open, and no span's parent, until the
+# calling thread's next leaf stage begins or `end_lead()`
+_lead: contextvars.ContextVar[Span | None] = contextvars.ContextVar(
+    "lead_stage", default=None)
+
+
+def begin_lead(name: str) -> None:
+    """Open the leaf stage `name` under the current span, without making it
+    the current one: it ends where the next leaf stage of the calling thread
+    begins (`Span.__enter__`), or at `end_lead()`, whichever comes first. A
+    search's routing is such a stretch: from its index's resolution through
+    the refresh check, the recovery wrapper and the request cache's look-up
+    to its parsing, across the functions that call one another on the way."""
+    s = Span(TRACER, name, {})
+    if _annotation is not None and not _in_stage_leaf(s._parent):
+        s._ann = _annotation(name)
+        s._ann.__enter__()
+    _lead.set(s)
+    s._t0 = time.perf_counter_ns()
+
+
+def end_lead() -> None:
+    """End the calling thread's open lead stage, if any."""
+    s = _lead.get()
+    if s is None:
+        return
+    end_ns = time.perf_counter_ns()
+    _lead.set(None)
+    if s._ann is not None:
+        s._ann.__exit__(None, None, None)
+    TRACER._finish(s, end_ns)
+
+
+def _in_stage_leaf(span: Span | None) -> bool:
+    """Whether `span` is, or lies inside, an open leaf stage."""
+    while span is not None:
+        if span.name in ANNOTATED_STAGES:
+            return True
+        span = span._parent
+    return False
 
 
 def _walk_spans(span: Span):
@@ -340,8 +416,7 @@ class Tracer:
                 # one acquisition of the lock: every stage's count moves by
                 # the same searches, whatever window the counters are read in
                 metrics.counters_add(
-                    (keys, sums[name]) for name, keys in _STAGE_COUNTERS.items()
-                    if name in sums)
+                    (_STAGE_COUNTERS[name], rec) for name, rec in sums.items())
         parent, s._parent = s._parent, None  # children point down only
         if parent is not None:
             parent.children.append(s)
@@ -374,50 +449,87 @@ class Tracer:
 TRACER = Tracer()
 
 
+def account():
+    """`engine.account` around the bookkeeping of a search's measurements
+    (a dispatch's XLA cross-check and counters, `time_kernel`'s tail): a leaf
+    stage of the calling thread's search. Nothing where the thread carries
+    no span (a refresh in the background: no root per kernel call) or lies
+    inside another leaf (a wave's stages): that work stays in the leaf it
+    lies in, and is counted once."""
+    cur = TRACER._current.get()
+    if cur is None or _in_stage_leaf(cur):
+        return nullcontext()
+    return Span(TRACER, "engine.account", {})
+
+
 class WaveStages:
     """The stage spans of one serving wave, summed: `stage(name)` is a span
     of WAVE_STAGES around work of the calling thread. Entered inside another
     stage of the same wave on the same thread (a program's launch inside the
-    planning) it pauses that one, so the stages lie end to end and their sum
-    is wall time of the threads that carried the wave. `sums` is
-    {stage: [ns, spans]}; `counter_pairs()` is what `metrics.counters_add`
-    takes once the wave has ended."""
+    planning) it pauses that one, and the two share the reading at which one
+    ends and the other starts, so the stages lie end to end and their sum is
+    wall time of the threads that carried the wave. `sums` is
+    {stage: [ns, spans]}; `bounds` holds the first start and the last end of
+    each stage entered outside another (`handoffs` reads them);
+    `counter_pairs()` is what `metrics.counters_add` takes once the wave has
+    ended."""
 
-    __slots__ = ("sums", "_open")
+    __slots__ = ("sums", "bounds", "_open")
 
     def __init__(self):
         self.sums: dict[str, list] = {}
+        self.bounds: dict[str, list] = {}
         self._open: list = []     # (name, span) entered and not left, in order
 
-    def _enter(self, name: str) -> None:
+    def _enter(self, name: str, at: int) -> None:
         span = TRACER.span(name)
         span.__enter__()
+        span._t0 = at
         self._open.append((name, span))
 
-    def _leave(self) -> None:
+    def _leave(self, at: int) -> None:
         name, span = self._open.pop()
-        span.__exit__(None, None, None)
+        span.close(at)
         rec = self.sums.setdefault(name, [0, 0])
-        rec[0] += span._t1 - span._t0
+        rec[0] += at - span._t0
         rec[1] += 1
 
     @contextmanager
     def stage(self, name: str):
+        at = time.perf_counter_ns()
         outer = self._open[-1][0] if self._open else None
         if outer is not None:
-            self._leave()
+            self._leave(at)
+        else:
+            self.bounds.setdefault(name, [at, at])
         token = _wave_stages.set(self)
-        self._enter(name)
+        self._enter(name, at)
         try:
             yield
         finally:
-            self._leave()
+            at = time.perf_counter_ns()
+            self._leave(at)
             _wave_stages.reset(token)
             if outer is not None:
-                self._enter(outer)
+                self._enter(outer, at)
+            else:
+                self.bounds[name][1] = at
 
     def ns(self, name: str) -> int:
         return self.sums.get(name, (0, 0))[0]
+
+    def handoffs(self, claim_ns: int) -> list[tuple[str, int, int]]:
+        """(name, start, end) of WAVE_HANDOFFS, once the wave's finish has
+        begun: claim -> planning, planning -> fetch, fetch -> finish. Set in
+        the sums, so that the seven stages sum to the claim -> finish."""
+        b = self.bounds
+        plan, fetch, finish = (b["engine.wave_plan"], b["engine.wave_fetch"],
+                               b["engine.wave_finish"])
+        out = list(zip(WAVE_HANDOFFS, (claim_ns, plan[1], fetch[1]),
+                       (plan[0], fetch[0], finish[0])))
+        for name, t0, t1 in out:
+            self.sums[name] = [t1 - t0, 1]
+        return out
 
     def counter_pairs(self):
         return [(_STAGE_COUNTERS[name], rec)
@@ -527,49 +639,57 @@ def time_kernel(name: str, **fields):
     MFU + bandwidth utilization — per call into the profile event, and
     cumulatively into es.kernel.<name>.{flops,bytes} counters and
     .{mfu_pct,bw_pct} histograms (→ _nodes/stats device section,
-    Prometheus exposition, and the .monitoring-es-* collectors)."""
+    Prometheus exposition, and the .monitoring-es-* collectors). What it
+    records after the interval is `engine.account` of the search whose
+    thread it runs on (`account()`)."""
     t0 = time.perf_counter()
     try:
         yield
     finally:
         sec = time.perf_counter() - t0
-        ms = sec * 1000
-        metrics.histogram_record(f"es.kernel.{name}.ms", ms)
+        with account():
+            _account_kernel(name, fields, sec)
+
+
+def _account_kernel(name: str, fields: dict, sec: float) -> None:
+    """What `time_kernel` records of one interval of `sec` seconds."""
+    ms = sec * 1000
+    metrics.histogram_record(f"es.kernel.{name}.ms", ms)
+    util = None
+    try:
+        from .monitoring.costmodel import utilization
+
+        util = utilization(name, fields, sec)
+    except Exception:  # noqa: BLE001 - accounting never fails a search
         util = None
+    if util is not None:
         try:
-            from .monitoring.costmodel import utilization
+            # feed the execution planner's achieved-roofline
+            # EMA + predicted-vs-actual residual from the SAME
+            # utilization record (pre-augmented fields)
+            from .planner import execution_planner
 
-            util = utilization(name, fields, sec)
-        except Exception:  # noqa: BLE001 - accounting never fails a search
-            util = None
-        if util is not None:
-            try:
-                # PR 18: feed the execution planner's achieved-roofline
-                # EMA + predicted-vs-actual residual from the SAME
-                # utilization record (pre-augmented fields)
-                from .planner import execution_planner
-
-                execution_planner().observe(name, fields, sec, util)
-            except Exception:  # noqa: BLE001 - advice never fails a search
-                pass
-            metrics.counter_inc(f"es.kernel.{name}.flops", util["flops"])
-            metrics.counter_inc(f"es.kernel.{name}.bytes", util["bytes"])
-            metrics.histogram_record(f"es.kernel.{name}.mfu_pct",
-                                     util["mfu"] * 100.0)
-            metrics.histogram_record(f"es.kernel.{name}.bw_pct",
-                                     util["bw_util"] * 100.0)
-            fields = {**fields, "flops": util["flops"],
-                      "bytes": util["bytes"],
-                      "mfu": round(util["mfu"], 6),
-                      "bw_util": round(util["bw_util"], 6)}
-            if "ici_util" in util:
-                # collective kernels (PR 10): achieved interconnect
-                # utilization of the all-gather merge traffic
-                metrics.histogram_record(f"es.kernel.{name}.ici_pct",
-                                         util["ici_util"] * 100.0)
-                fields["ici_bytes"] = util["ici_bytes"]
-                fields["ici_util"] = round(util["ici_util"], 6)
-        profile_event("kernel", kernel=name, ms=round(ms, 4), **fields)
+            execution_planner().observe(name, fields, sec, util)
+        except Exception:  # noqa: BLE001 - advice never fails a search
+            pass
+        metrics.counter_inc(f"es.kernel.{name}.flops", util["flops"])
+        metrics.counter_inc(f"es.kernel.{name}.bytes", util["bytes"])
+        metrics.histogram_record(f"es.kernel.{name}.mfu_pct",
+                                 util["mfu"] * 100.0)
+        metrics.histogram_record(f"es.kernel.{name}.bw_pct",
+                                 util["bw_util"] * 100.0)
+        fields = {**fields, "flops": util["flops"],
+                  "bytes": util["bytes"],
+                  "mfu": round(util["mfu"], 6),
+                  "bw_util": round(util["bw_util"], 6)}
+        if "ici_util" in util:
+            # collective kernels: achieved interconnect
+            # utilization of the all-gather merge traffic
+            metrics.histogram_record(f"es.kernel.{name}.ici_pct",
+                                     util["ici_util"] * 100.0)
+            fields["ici_bytes"] = util["ici_bytes"]
+            fields["ici_util"] = round(util["ici_util"], 6)
+    profile_event("kernel", kernel=name, ms=round(ms, 4), **fields)
 
 
 # ---------------------------------------------------------------------------

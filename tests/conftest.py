@@ -171,6 +171,11 @@ def _module_hygiene():
     from elasticsearch_tpu.cache import request_cache
 
     request_cache().lru.clear()
+    # and its switch back at the setting's default: an engine sets it from
+    # its own `indices.requests.cache.enable`, and a module that turned it
+    # off must not leave it off for a next module's searcher built without
+    # an engine
+    request_cache().set_enabled(True)
     # metrics hygiene: the registry is a process-global singleton; one
     # module's recordings (counters, latency histograms) must not leak
     # into another module's snapshot/percentile assertions

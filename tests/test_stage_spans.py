@@ -25,18 +25,25 @@ from elasticsearch_tpu.telemetry import (ANNOTATED_STAGES, STAGES, TRACER,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORDS = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta"]
-# the stages of a search's tree, each with its parent; a search without
-# aggregations enters `engine.collect` twice (the result, then its hits)
+# the stages of a search's tree, each with the parents it may have
 PARENT = {
-    "rest.search": "http POST /{index}/_search",
-    "engine.queue": "rest.search", "engine.search": "rest.search",
-    "rest.respond": "rest.search",
-    "executeQueryPhase": "engine.search",
-    "engine.parse": "executeQueryPhase", "engine.plan": "executeQueryPhase",
-    "engine.dispatch": "executeQueryPhase",
-    "engine.fetch": "executeQueryPhase",
-    "engine.collect": "executeQueryPhase",
+    "rest.search": ("http POST /{index}/_search",),
+    "engine.queue": ("rest.search",), "engine.search": ("rest.search",),
+    "rest.respond": ("rest.search",), "rest.read": ("rest.search",),
+    "rest.resume": ("rest.search",),
+    "executeQueryPhase": ("engine.search",), "engine.route": ("engine.search",),
+    "engine.parse": ("executeQueryPhase",), "engine.plan": ("executeQueryPhase",),
+    "engine.dispatch": ("executeQueryPhase",),
+    "engine.fetch": ("executeQueryPhase",),
+    "engine.unpack": ("executeQueryPhase",),
+    "engine.collect": ("executeQueryPhase",),
+    # the dispatch's and the fetch's bookkeeping, then the search's slow log
+    "engine.account": ("executeQueryPhase", "engine.search"),
 }
+# the stages a search without aggregations enters more than once:
+# `engine.collect` twice (the result, then its hits), `engine.account` three
+# times (PARENT)
+ENTERED = {"engine.collect": 2, "engine.account": 3}
 
 
 def _stage_counters() -> dict:
@@ -130,13 +137,13 @@ def test_one_search_yields_one_tree_of_stages_and_moves_their_counters():
     spans = list(_walk(root))
     names = collections.Counter(s.name for s, _ in spans)
     assert names == {"http POST /{index}/_search": 1, "executeQueryPhase": 1,
-                     **{n: 1 for n in STAGES}, "engine.collect": 2}
+                     **{n: ENTERED.get(n, 1) for n in STAGES}}
     assert {s.trace_id for s, _ in spans} == {root.trace_id}
     assert len({s.span_id for s, _ in spans}) == len(spans)
     for s, parent in spans:
         if parent is None:
             continue
-        assert parent.name == PARENT[s.name], (s.name, parent.name)
+        assert parent.name in PARENT[s.name], (s.name, parent.name)
         assert s.parent_span_id == parent.span_id
         # inside the parent's interval, both on time.perf_counter's clock
         assert parent.start <= s.start <= s.end <= parent.end, s.name
@@ -148,7 +155,7 @@ def test_one_search_yields_one_tree_of_stages_and_moves_their_counters():
     assert at["program_cache"] == "hit"
     assert at["padded_rows"] == at["dense_tier"] + at["rows_tier"]
     for name in STAGES:
-        n = 2 if name == "engine.collect" else 1
+        n = ENTERED.get(name, 1)
         assert (after[f"es.span.{name}.count"]
                 - before[f"es.span.{name}.count"]) == n
         assert after[f"es.span.{name}.ns"] > before[f"es.span.{name}.ns"]
@@ -222,7 +229,7 @@ def test_other_endpoints_between_two_searches_leave_the_stage_counters_alone():
     assert [c.name for c in doc_root.children] == []
     assert others == mid
     for name in STAGES:
-        n = 2 if name == "engine.collect" else 1
+        n = ENTERED.get(name, 1)
         for a, b in ((before, mid), (others, after)):
             assert (b[f"es.span.{name}.count"]
                     - a[f"es.span.{name}.count"]) == n, name
@@ -516,3 +523,323 @@ def test_trace_dump_renders_the_stage_tree():
     assert [next(n for n in ("http POST", "rest.search", "engine.search")
                  if n in ln) for ln in rows[:3]] == [
         "http POST", "rest.search", "engine.search"]
+
+
+# ---- the host time between and beneath the stages ---------------------------
+
+def _reader(name: str):
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    try:
+        import run as harness
+    finally:
+        sys.path.pop(0)
+    return harness, harness.layer_reader(os.path.join(REPO, "benchmark"),
+                                          name)
+
+
+def test_the_new_stages_move_with_every_search_and_with_nothing_else():
+    async def go():
+        client = await _client_with_index()
+        try:
+            await _search(client, "alpha gamma")
+            before = _stage_counters()
+            for text in ("beta delta", "gamma zeta", "alpha eta"):
+                await _search(client, text)
+            mid = _stage_counters()
+            assert (await client.get("/i/_count")).status == 200
+            return before, mid, _stage_counters()
+        finally:
+            await _close(client)
+
+    before, mid, after = asyncio.run(go())
+    searches = (mid["es.span.rest.search.count"]
+                - before["es.span.rest.search.count"])
+    assert searches == 3
+    for name in ("engine.route", "engine.unpack", "engine.account",
+                 "rest.read", "rest.resume"):
+        moved = (mid[f"es.span.{name}.count"]
+                 - before[f"es.span.{name}.count"])
+        assert moved == ENTERED.get(name, 1) * searches, name
+        assert mid[f"es.span.{name}.ns"] > before[f"es.span.{name}.ns"]
+    assert after == mid
+
+
+def test_engine_search_less_its_stages_is_what_engine_unnamed_ms_reads():
+    """One search: its `engine.search` less every stage under it, from its
+    own tree, is what the reader gives on the counters around it."""
+    async def go():
+        client = await _client_with_index()
+        try:
+            await _search(client, "alpha gamma")
+            before = telemetry.metrics.snapshot()
+            r = await _search(client, "beta delta")
+            return (before, telemetry.metrics.snapshot(),
+                    _root_of(r.headers["X-Trace-Id"]))
+        finally:
+            await _close(client)
+
+    before, after, root = asyncio.run(go())
+    search = next(s for s, _ in _walk(root) if s.name == "engine.search")
+    children = [s for s, _ in _walk(search)
+                if s is not search and s.name in STAGES]
+    assert {s.name for s in children} == {
+        "engine.route", "engine.parse", "engine.plan", "engine.account",
+        "engine.dispatch", "engine.fetch", "engine.unpack", "engine.collect"}
+    unnamed_ns = (search._t1 - search._t0) - sum(s._t1 - s._t0
+                                                 for s in children)
+    assert unnamed_ns > 0
+    harness, read = _reader("engine.unnamed_ms")
+    run = harness.Run()
+    run.before, run.after = before, after
+    assert read(run) == pytest.approx(unnamed_ns * 1e-6, rel=1e-12)
+
+
+def test_the_client_gap_counts_the_second_request_on_a_connection():
+    """A request that follows an answer on its kept-alive connection adds
+    the time between them; the first request of a connection adds none."""
+    import aiohttp
+
+    def gap():
+        c = telemetry.metrics.snapshot()["counters"]
+        return (c["es.rest.client_gap.ns"], c["es.rest.client_gap.count"])
+
+    async def go():
+        client = await _client_with_index()
+        url = client.make_url("/i/_search")
+        body = {"query": {"match": {"body": "alpha"}}}
+        try:
+            async with aiohttp.ClientSession() as fresh:
+                g0 = gap()
+                async with fresh.post(url, json=body) as r:
+                    assert r.status == 200
+                    await r.read()
+                g1 = gap()
+                async with fresh.post(url, json=body) as r:
+                    assert r.status == 200
+                    await r.read()
+                g2 = gap()
+            return g0, g1, g2
+        finally:
+            await _close(client)
+
+    g0, g1, g2 = asyncio.run(go())
+    assert g1 == g0                      # a new connection: no answer before
+    assert g2[1] == g1[1] + 1 and g2[0] > g1[0]
+
+
+class _Annotations:
+    """A stand-in for `jax.profiler.TraceAnnotation` that holds, for each
+    thread, how many are open at once."""
+
+    def __init__(self):
+        self.opened, self.deepest = [], {}
+        self._depth = collections.Counter()
+        self._lock = threading.Lock()
+
+    def __call__(self, name):
+        outer = self
+
+        class Annotation:
+            def __enter__(self):
+                me = threading.get_ident()
+                with outer._lock:
+                    outer.opened.append(name)
+                    outer._depth[me] += 1
+                    outer.deepest[me] = max(outer.deepest.get(me, 0),
+                                            outer._depth[me])
+                return self
+
+            def __exit__(self, *exc):
+                with outer._lock:
+                    outer._depth[threading.get_ident()] -= 1
+                return False
+
+        return Annotation()
+
+
+def test_no_two_annotations_are_open_at_once_on_the_solo_path():
+    stub = _Annotations()
+
+    async def go():
+        client = await _client_with_index()
+        try:
+            await _search(client, "alpha gamma")
+            telemetry._annotation = stub
+            try:
+                await _search(client, "beta delta")
+            finally:
+                telemetry.annotate_stages(False)
+        finally:
+            await _close(client)
+
+    asyncio.run(go())
+    assert set(stub.opened) == ANNOTATED_STAGES - set(WAVE_STAGES)
+    assert collections.Counter(stub.opened)["engine.account"] == 3
+    assert max(stub.deepest.values()) == 1
+
+
+def test_no_two_annotations_are_open_at_once_on_the_wave_threads():
+    """A wave carries the solo path's stages inside its own (a generic
+    lane's parsing, planning and launch inside `engine.wave_plan`, its hits'
+    `engine.collect` inside `engine.wave_finish`): they open none."""
+    stub = _Annotations()
+
+    async def go():
+        client = await _client_with_index()
+        try:
+            await client.put("/_cluster/settings", json={"persistent": {
+                "serving.enabled": True}})
+            body = {"query": {"match": {"body": "alpha"}},
+                    "aggs": {"n": {"value_count": {"field": "_id"}}}}
+            r = await client.post("/i/_search", json=body)  # compiles
+            assert r.status == 200, await r.text()
+            telemetry._annotation = stub
+            try:
+                rs = await asyncio.gather(*(
+                    client.post("/i/_search", json=b) for b in (
+                        body, {"query": {"match": {"body": "beta"}}})))
+                assert [r.status for r in rs] == [200, 200]
+            finally:
+                telemetry.annotate_stages(False)
+        finally:
+            await _close(client)
+
+    asyncio.run(go())
+    assert {"engine.wave_plan", "engine.wave_fetch",
+            "engine.wave_finish"} <= set(stub.opened)
+    assert max(stub.deepest.values()) == 1
+
+
+def test_time_kernels_tail_is_engine_account_only_in_a_searchs_own_work():
+    from elasticsearch_tpu.telemetry import WaveStages, time_kernel
+
+    with TRACER.span("rest.search") as search:
+        with time_kernel("test.kernel", queries=1):
+            pass
+    assert [c.name for c in search.children] == ["engine.account"]
+    n = len(TRACER.finished)
+    with time_kernel("test.kernel", queries=1):    # a thread with no span
+        pass
+    assert len(TRACER.finished) == n
+    stages = WaveStages()
+    with stages.stage("engine.wave_fetch"):
+        with time_kernel("test.kernel", queries=1):
+            pass
+    fetch = TRACER.finished[-1]
+    assert fetch.name == "engine.wave_fetch" and fetch.children == []
+
+
+def test_the_xla_check_counts_what_it_spends():
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.monitoring import xla_introspect
+
+    def ns():
+        return telemetry.metrics.snapshot()["counters"].get(
+            "es.costmodel.xla_check.ns", 0)
+
+    before = ns()
+    fn = jax.jit(lambda x: x * 2)
+    x = jnp.arange(8.0)
+    xla_introspect.check_dispatch("test.xla_check_ns", fn, (x,))
+    after = ns()
+    assert after > before
+    xla_introspect.check_dispatch("test.xla_check_ns", fn, (x,))  # seen
+    assert ns() == after
+
+
+@pytest.fixture
+def waves_of(tmp_path, monkeypatch):
+    """A live serving front end over one index, and every wave's stages
+    with its claim, as `_count_wave` counts them."""
+    from elasticsearch_tpu.engine import Engine
+    from elasticsearch_tpu.serving.service import ServingService
+
+    engine = Engine(str(tmp_path / "data"))
+    idx = engine.create_index("w", {"properties": {
+        "body": {"type": "text"}, "tag": {"type": "keyword"}}})
+    for i in range(300):
+        idx.index_doc(str(i), {
+            "body": f"{WORDS[i % 7]} {WORDS[(i + 3) % 7]} common",
+            "tag": WORDS[i % 3]})
+    idx.refresh()
+    counted = []
+    original = ServingService._count_wave
+
+    def count_wave(self, state, stages, rows):
+        original(self, state, stages, rows)
+        counted.append((state["members"][0].claim_ns, stages, state))
+
+    monkeypatch.setattr(ServingService, "_count_wave", count_wave)
+    svc = engine.serving
+    try:
+        yield svc, counted
+    finally:
+        svc.stop()
+        engine.close()
+
+
+def test_every_waves_seven_stages_sum_to_its_claim_to_done(waves_of):
+    from concurrent.futures import wait
+
+    svc, counted = waves_of
+    bodies = [{"query": {"match": {"body": w}}, "size": 5}
+              for w in ("alpha", "beta gamma", "delta", "common")]
+    bodies.append({"query": {"term": {"tag": "beta"}}, "size": 3,
+                   "aggs": {"t": {"terms": {"field": "tag"}}}})
+    for _ in range(3):
+        futs = [svc.submit(svc.classify("w", b, {})) for b in bodies]
+        wait(futs, timeout=120)
+        assert all(f.exception() is None for f in futs)
+    svc.drain()
+    assert counted
+    seven = WAVE_STAGES + telemetry.WAVE_HANDOFFS
+    for claim, stages, state in counted:
+        done = stages.bounds["engine.wave_finish"][1]
+        # a wave whose lanes launched nothing of their own has no launch
+        assert set(seven) - {"engine.wave_launch"} <= set(stages.sums) \
+            <= set(seven)
+        assert sum(stages.ns(s) for s in seven) == done - claim
+        assert all(stages.ns(s) >= 0 for s in seven)
+        assert [stages.sums[s][1] for s in telemetry.WAVE_HANDOFFS] \
+            == [1, 1, 1]
+    # the flight recorder reads the same stamps: its hand-offs are the
+    # stages', and its segments end where the stages do
+    recs = svc.flight_recorder()["waves"][-len(counted):]
+    for rec, (claim, stages, _state) in zip(recs, counted):
+        assert rec["handoffs_ms"] == {
+            short: round(stages.ns(f"engine.wave_{short}") / 1e6, 4)
+            for short in ("queue", "handoff", "rejoin")}
+        b = stages.bounds
+        assert rec["segments_ms"]["plan"] == round(
+            (b["engine.wave_plan"][1] - claim) / 1e6, 4)
+
+
+def test_a_served_search_reads_and_resumes_under_its_rest_search():
+    async def go():
+        client = await _client_with_index()
+        try:
+            await client.put("/_cluster/settings", json={"persistent": {
+                "serving.enabled": True}})
+            await _search(client, "alpha gamma")
+            before = _stage_counters()
+            r = await _search(client, "beta delta")
+            return (before, _stage_counters(),
+                    _root_of(r.headers["X-Trace-Id"]))
+        finally:
+            await _close(client)
+
+    before, after, root = asyncio.run(go())
+    by_name = collections.defaultdict(list)
+    for s, parent in _walk(root):
+        by_name[s.name].append(parent.name if parent is not None else None)
+    assert by_name["rest.read"] == ["rest.search"]
+    assert by_name["rest.resume"] == ["rest.search"]
+    for name in ("engine.route", "engine.unpack", "engine.account"):
+        assert name not in by_name
+        assert (after[f"es.span.{name}.count"]
+                == before[f"es.span.{name}.count"]), name
+    for name in ("rest.read", "rest.resume", "rest.search"):
+        assert (after[f"es.span.{name}.count"]
+                - before[f"es.span.{name}.count"]) == 1, name
