@@ -497,9 +497,13 @@ def run_with_device_recovery(engine, fn, where: str):
     except Exception as ex:  # noqa: BLE001 - OOM-classified below
         if not is_device_oom(ex):
             raise
-        engine.device_degradation.on_oom(ex, where)
         from ..planner import execution_planner
+        from .engine_thread import engine_turn
 
-        with execution_planner().reprice(
-                ("fused", "impact"), reason=f"device_oom:{where}"):
-            return fn()
+        # a solo search's fetch may have failed on the completer: the
+        # degradation and the re-run are the engine thread's work
+        with engine_turn():
+            engine.device_degradation.on_oom(ex, where)
+            with execution_planner().reprice(
+                    ("fused", "impact"), reason=f"device_oom:{where}"):
+                return fn()

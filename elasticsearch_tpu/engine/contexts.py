@@ -114,7 +114,9 @@ class ContextRegistry:
 def pinned(engine, ctx: SearchCtx):
     """Swap each pinned index's live snapshot for the context's pinned one
     for the duration of a search. Engine work is serialized on one executor
-    thread (rest/app.py), so the swap is not observable concurrently."""
+    thread (rest/app.py), and a scroll or PIT search starts only once no
+    solo search is with the completer (common/engine_thread), so the swap
+    is not observable concurrently."""
     saved = []
     try:
         for pin in ctx.pins:

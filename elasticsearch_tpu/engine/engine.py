@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -120,6 +121,7 @@ class EsIndex:
         self.name = name
         self.mappings = mappings
         self.engine = None  # owning Engine backref (query-time inference)
+        self._query_time_lock = threading.Lock()
         self.settings = {"number_of_shards": 1, "number_of_replicas": 0, "refresh_interval": "1s"}
         # nested slowlog-group bodies flatten to the dotted keys the
         # telemetry threshold reader consumes (same normalization as
@@ -624,7 +626,7 @@ class EsIndex:
         self._searcher = value
 
     def refresh(self, mesh=None):
-        from ..common import faults
+        from ..common import engine_thread, faults
         from ..monitoring.refresh_profile import profile_refresh
 
         faults.check("refresh.build", index=self.name)
@@ -633,6 +635,8 @@ class EsIndex:
             h()
         if self._searcher is not None and not self._pending and not self._dirty:
             return  # nothing written since the last refresh
+        # a search with the completer still reads the pack this replaces
+        engine_thread.drain()
         if self._can_refresh_incremental():
             with profile_refresh(self, "incremental"):
                 self._refresh_incremental()
@@ -712,12 +716,13 @@ class EsIndex:
         pack passed breaker admission — an injected `refresh.build`
         fault (stage=merge) or a real build failure leaves the old
         tiers fully serving."""
-        from ..common import faults
+        from ..common import engine_thread, faults
         from ..monitoring.refresh_profile import (
             build_stage, profile_refresh, refresh_stage)
         from ..parallel.stacked import build_stacked_pack_routed, route_docs
 
         faults.check("refresh.build", index=self.name, stage="merge")
+        engine_thread.drain()  # as a refresh's
         base = self._searcher
         visible = []
         for s, lst in enumerate(self.shard_docs):
@@ -1173,8 +1178,11 @@ class EsIndex:
             _trace_ctx.__exit__(None, None, None)
             with account():
                 took_ms = (time.monotonic() - _t_search0) * 1000
-                self.counters["query_time_ms"] = (
-                    self.counters.get("query_time_ms", 0) + int(took_ms))
+                # a search that finishes on the completer adds here while
+                # the engine thread runs the next one
+                with self._query_time_lock:
+                    self.counters["query_time_ms"] = (
+                        self.counters.get("query_time_ms", 0) + int(took_ms))
                 record_search_slowlog(
                     self.name, self.settings, took_ms,
                     json.dumps(query)[:512] if query is not None else "{}",
@@ -1403,8 +1411,12 @@ class EsIndex:
             res.scores = np.asarray([x[2] for x in order], np.float32)
             res.max_score = float(order[0][2]) if order else None
         else:
+            # nothing but the hits' formatting follows where there is no knn
+            # section and no runtime field: the fetch may leave the engine
+            # thread (common/engine_thread)
             res = self.searcher.search(query, size=size, from_=from_, aggs=aggs,
-                                       mappings=m_eff)
+                                       mappings=m_eff,
+                                       hand_off=knn is None and m_eff is None)
             if knn is not None and self._knn_mark_starved(
                     query, len(res.doc_ids) + from_, size + from_):
                 # filtered ANN retrieval could not reach k: re-run with
@@ -2975,6 +2987,8 @@ class Engine:
         this coordinator (reference behavior: TransportSearchAction shards
         span all resolved indices; merge in SearchPhaseController). Parts
         like `remote:index` fan out to registered remote clusters (CCS)."""
+        from ..common import engine_thread
+
         if isinstance(expression, str) and ":" in expression:
             remotes = self.remote_clusters()
             local_parts, remote_parts = [], []
@@ -2991,10 +3005,11 @@ class Engine:
                     )
                 subs = []
                 if local_parts:
-                    subs.append(self.search_multi(
-                        ",".join(local_parts),
-                        ignore_unavailable=ignore_unavailable,
-                        allow_no_indices=allow_no_indices, **kwargs))
+                    with engine_thread.serial():  # the remotes follow
+                        subs.append(self.search_multi(
+                            ",".join(local_parts),
+                            ignore_unavailable=ignore_unavailable,
+                            allow_no_indices=allow_no_indices, **kwargs))
                 for alias, url, rest in remote_parts:
                     subs.append(self._search_remote(url, rest, alias, kwargs))
                 size = kwargs.get("size", 10)
@@ -3084,7 +3099,8 @@ class Engine:
             try:
                 faults.check("shard.search", index=idx.name,
                              node=node_name)
-                sub_results.append(idx.search(**kw))
+                with engine_thread.serial():  # the next index follows
+                    sub_results.append(idx.search(**kw))
             except IllegalArgumentError:
                 raise  # a malformed request is the caller's 400, not a
                 # shard failure to paper over

@@ -199,6 +199,31 @@ def _stacked_result_nbytes(res: StackedResult) -> int:
     return n
 
 
+def _two_pass(state: dict) -> bool:
+    """Whether a launched request's terms aggregations need a second pass
+    (`_agg_pass2_dispatch`)."""
+    if not state["agg_nodes"]:
+        return False
+    from ..aggs import two_pass_plan
+
+    return bool(two_pass_plan(state["agg_nodes"]))
+
+
+def _hand_off() -> None:
+    """Let the completer finish the solo search whose programs were just
+    launched, where one may and another search waits for the engine thread
+    (`common/engine_thread.hand_off`); the hand-over is its span."""
+    import time as _time
+
+    from ..common import engine_thread
+    from ..telemetry import HANDED_OFF, SOLO_HANDOFF, TRACER, metrics
+
+    t0 = _time.perf_counter_ns()
+    if engine_thread.hand_off():
+        TRACER.record(SOLO_HANDOFF, t0, _time.perf_counter_ns())
+        metrics.counter_inc(HANDED_OFF)
+
+
 class StackedSearcher:
     """Multi-shard searcher: one mesh-resident stacked pack + compiled plans.
 
@@ -776,11 +801,13 @@ class StackedSearcher:
         from_: int = 0,
         aggs: dict | None = None,
         mappings=None,
+        hand_off: bool = False,
     ) -> StackedResult:
         """Plain-DSL requests are served from the shard request cache when
         warm (whole-searcher scope: the merged result depends on every
         shard, so any shard's epoch bump invalidates it); QueryNode
-        requests and per-request mapping overrides bypass the cache."""
+        requests and per-request mapping overrides bypass the cache.
+        `hand_off`: as `search_batch`'s."""
         from ..cache import request_cache
 
         rc = request_cache()
@@ -807,8 +834,8 @@ class StackedSearcher:
         m = mappings if mappings is not None else self.sp.mappings
         node, _ = self._parsed(query, m)
         res = self.search_batch(
-            [dict(query=node, size=size, from_=from_, aggs=aggs, mappings=m)]
-        )[0]
+            [dict(query=node, size=size, from_=from_, aggs=aggs, mappings=m)],
+            hand_off=hand_off)[0]
         _elapsed_ms = (_time.perf_counter() - _t0) * 1000
         _metrics.histogram_record("es.shard.search.ms", _elapsed_ms)
         if ck is not None:
@@ -900,16 +927,10 @@ class StackedSearcher:
             st["host"] = []
             return
         from ..common import faults
-        from ..telemetry import TRACER, time_kernel
 
         faults.check("device.fetch", shards=self.sp.S,
                      requests=len(st["pending"]))
-        with time_kernel("sharded.spmd_topk", shards=self.sp.S,
-                         requests=len(st["pending"]),
-                         queries=len(st["pending"]),
-                         num_docs=self.sp.S * self.sp.n_max):
-            with TRACER.span("engine.fetch"):
-                st["host"] = jax.device_get(st["pending"])
+        st["host"] = self._fetch(st["pending"])
 
     def search_many_finish(self, st: dict,
                            raise_errors: bool = True) -> list:
@@ -920,23 +941,14 @@ class StackedSearcher:
         stores and host merges touch shared state."""
         import time as _time
 
-        host = iter(st.get("host") or [])
         from ..cache import request_cache
 
         rc = request_cache()
         out = []
-        wave2 = []
-        for i, s in enumerate(st["states"]):
-            if s is not None:
-                s["host"] = self._fetched(next(host), s["out_layout"])
-                if self._agg_pass2_dispatch(s):
-                    wave2.append(s)
-        if wave2:
+        if self._unpack([s for s in st["states"] if s is not None],
+                        st.get("host") or []):
             # rare two-pass terms aggs: one extra dispatch + fetch round,
             # recorded so the wave's host-transition meta stays honest
-            host2 = jax.device_get([s["outs2"] for s in wave2])
-            for s, h2 in zip(wave2, host2):
-                s["host2"] = self._fetched(h2, s["out_layout2"])
             st["extra_dispatches"] = st.get("extra_dispatches", 0) + 1
             st["extra_fetches"] = st.get("extra_fetches", 0) + 1
         from ..telemetry import metrics as _metrics
@@ -969,7 +981,8 @@ class StackedSearcher:
         self.search_many_fetch(st)
         return self.search_many_finish(st, raise_errors=raise_errors)
 
-    def search_batch(self, requests: list[dict]) -> list:
+    def search_batch(self, requests: list[dict],
+                     hand_off: bool = False) -> list:
         """Execute several search/agg requests with batched device
         round-trips: every request's program is dispatched before any
         result is fetched, so the fixed dispatch+fetch latency is paid
@@ -977,30 +990,55 @@ class StackedSearcher:
         Two waves maximum: pass-1 for everything, then pass-2 for
         requests whose high-cardinality terms aggs use the two-pass
         candidate scheme. Each request dict: query (dict | QueryNode |
-        None), size, from_, aggs, mappings.
+        None), size, from_, aggs, mappings. The cut between launch, fetch
+        and finish is the serving waves' (`search_many_begin` / `_fetch` /
+        `_finish`).
+
+        `hand_off`: the caller returns what this returns and does no more
+        engine work (a solo `_search`): once the programs are launched,
+        the fetch and all after it may move to the completer
+        (`common/engine_thread.hand_off`), unless a second pass needs the
+        engine thread for its launch.
 
         The reference has no agg-batching analog (each search is its own
         scatter/gather); this is the same discipline `ops/batched` applies
         to the query path, extended to aggregations."""
-        from ..telemetry import TRACER, time_kernel
+        from ..telemetry import TRACER
 
         states = [self._agg_dispatch(**r) for r in requests]
+        if hand_off and not any(_two_pass(s) for s in states):
+            _hand_off()
+        host = self._fetch([s["outs"] for s in states])
+        with TRACER.span("engine.unpack"):
+            self._unpack(states, host)
+        return [self._agg_finalize(s) for s in states]
+
+    def _fetch(self, outs: list) -> list:
+        """`jax.device_get` of launched programs' packed outputs: the wait
+        for the programs and the copies. Touches no engine or searcher
+        state, so a completer thread may run it."""
+        from ..telemetry import TRACER, time_kernel
+
         with time_kernel("sharded.spmd_topk", shards=self.sp.S,
-                         requests=len(requests), queries=len(requests),
+                         requests=len(outs), queries=len(outs),
                          num_docs=self.sp.S * self.sp.n_max):
             with TRACER.span("engine.fetch"):
-                host = jax.device_get([s["outs"] for s in states])
+                return jax.device_get(outs)
+
+    def _unpack(self, states: list[dict], host: list) -> bool:
+        """Cut each state's result tree out of its fetched buffers; launch
+        and fetch the second pass of the requests that need one. -> whether
+        any did."""
         wave2 = []
-        with TRACER.span("engine.unpack"):
-            for s, ho in zip(states, host):
-                s["host"] = self._fetched(ho, s["out_layout"])
-                if self._agg_pass2_dispatch(s):
-                    wave2.append(s)
+        for s, ho in zip(states, host):
+            s["host"] = self._fetched(ho, s["out_layout"])
+            if self._agg_pass2_dispatch(s):
+                wave2.append(s)
         if wave2:
             host2 = jax.device_get([s["outs2"] for s in wave2])
             for s, h2 in zip(wave2, host2):
                 s["host2"] = self._fetched(h2, s["out_layout2"])
-        return [self._agg_finalize(s) for s in states]
+        return bool(wave2)
 
     def _parsed(self, query, m, aggs=None):
         """-> (the query's node, its aggregation nodes or None); a query

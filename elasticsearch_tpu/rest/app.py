@@ -17,13 +17,14 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 
 from aiohttp import web
 from aiohttp.web_log import AccessLogger
 
 from .. import __version__
+from ..common.engine_thread import EngineExecutor
 from ..engine import Engine
 from ..telemetry import TRACER, begin_lead, end_lead
 from ..utils.errors import ElasticsearchTpuError, IllegalArgumentError
@@ -69,6 +70,23 @@ def _err_response(ex: Exception) -> web.Response:
     if retry_after is not None:
         headers = {"Retry-After": str(int(max(1, retry_after)))}
     return web.json_response(body, status=status, headers=headers)
+
+
+class _SearchesWaiting:
+    """The searches handed to the engine thread and not yet started: while
+    one waits, a solo search's fetch moves to the completer
+    (`EngineExecutor.hand_off`)."""
+
+    def __init__(self):
+        self._n = 0
+        self._lock = threading.Lock()
+
+    def add(self, k: int) -> None:
+        with self._lock:
+            self._n += k
+
+    def __call__(self) -> int:
+        return self._n
 
 
 class _AnswerStamp(AccessLogger):
@@ -204,8 +222,10 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
         handler_args={"access_log_class": _AnswerStamp},
     )
     app["engine"] = engine
-    # single-thread executor: serializes engine mutation, keeps the loop free
-    app["pool"] = ThreadPoolExecutor(max_workers=1, thread_name_prefix="engine")
+    # the engine thread: serializes engine mutation, keeps the loop free;
+    # a solo search's fetch may finish on its completer
+    waiting = _SearchesWaiting()
+    app["pool"] = EngineExecutor(waiting)
     # the background monitoring tick serializes its engine access through
     # the same worker instead of racing REST traffic (monitoring/service)
     engine.monitoring.submit = app["pool"].submit
@@ -220,18 +240,25 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
     from ..monitoring import install_compile_listener
 
     install_compile_listener()
-    from ..telemetry import CLIENT_GAP, metrics
+    from ..telemetry import CLIENT_GAP, HANDED_OFF, SOLO_HANDOFF, metrics
 
     # shipped at 0 from the start: a reader gives 0 where the work did not
     # happen, and nothing only on a server without these counters
     metrics.counters_add(
         [((f"es.span.{s}.ns", f"es.span.{s}.count"), (0, 0))
          for s in ("engine.route", "engine.unpack", "engine.account",
-                   "rest.read", "rest.resume")] + [(CLIENT_GAP, (0, 0))])
+                   "rest.read", "rest.resume", SOLO_HANDOFF)]
+        + [(CLIENT_GAP, (0, 0))])
     metrics.counter_inc("es.costmodel.xla_check.ns", 0)
+    metrics.counter_inc(HANDED_OFF, 0)
 
     async def call(fn, *args, **kwargs):
-        loop = asyncio.get_running_loop()
+        return await on_engine(fn, args, kwargs)
+
+    async def on_engine(fn, args, kwargs, solo=False):
+        """`fn` on the engine thread; `solo`: a `_search` on the solo path,
+        which may start while the search before it is with the completer
+        (every other task waits for the completer to be empty)."""
         # carry the request's contextvars (trace context, active span,
         # profile collector) onto the engine worker thread, so spans and
         # profiling events recorded there belong to THIS request
@@ -245,6 +272,7 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
         def on_engine_thread():
             nonlocal answered
             if search:
+                waiting.add(-1)
                 # a search's wait behind the one engine thread, which no
                 # thread performs; any other endpoint's is in its root alone
                 TRACER.record("engine.queue", handed, time.perf_counter_ns())
@@ -253,8 +281,15 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
             finally:
                 answered = time.perf_counter_ns()
 
-        res = await loop.run_in_executor(
-            app["pool"], ctx.run, on_engine_thread)
+        pool = app["pool"]
+        if search:
+            waiting.add(1)
+        fut = (pool.submit_search if solo else pool.submit)(
+            ctx.run, on_engine_thread)
+        if search:
+            # one cancelled before it started never counts itself off
+            fut.add_done_callback(lambda f: f.cancelled() and waiting.add(-1))
+        res = await asyncio.wrap_future(fut)
         if search:
             # the loop's wake-up once the answer is ready: its turn behind
             # the other callbacks
@@ -270,6 +305,12 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                 return fn(*args, **kwargs)
             finally:
                 end_lead()
+
+    def solo_search(*args, **kwargs):
+        """`engine.search_multi` for a `_search` on the solo path: once its
+        program is launched, the rest of it may finish on the completer."""
+        with app["pool"].solo():
+            return engine_search(engine.search_multi, *args, **kwargs)
 
     def handler(fn):
         async def wrapped(request: web.Request):
@@ -2032,12 +2073,11 @@ def make_app(engine: Engine | None = None, data_path: str | None = None) -> web.
                     # the wave's stages, as this search's own
                     sv.member_spans(served)
                 else:
-                    res = await call(
-                        engine_search, engine.search_multi, expression,
+                    res = await on_engine(solo_search, (expression,), dict(
                         ignore_unavailable=_bool_param(query_params, "ignore_unavailable"),
                         allow_no_indices=_bool_param(query_params, "allow_no_indices", True),
                         **kwargs,
-                    )
+                    ), solo=True)
         finally:
             if _prof_cm is not None:
                 _prof_cm.__exit__(None, None, None)

@@ -189,8 +189,16 @@ ANNOTATED_STAGES = frozenset({
     "engine.collect", "rest.respond", "engine.route", "engine.unpack",
     "engine.account", "rest.read", *WAVE_STAGES,
 })
+# A solo search's hand-over of the rest of its work to the completer
+# (common/engine_thread), which no thread performs: from its launch's end to
+# the moment another thread takes the engine, the wait for the completer to
+# finish the search before it included. Only a search that hands over has one.
+SOLO_HANDOFF = "engine.handoff"
+# the solo searches whose fetch and what follows it ran on the completer
+HANDED_OFF = "es.search.solo.handed_off"
 _STAGE_COUNTERS = {name: (f"es.span.{name}.ns", f"es.span.{name}.count")
-                   for name in STAGES + WAVE_STAGES + WAVE_HANDOFFS}
+                   for name in STAGES + WAVE_STAGES + WAVE_HANDOFFS
+                   + (SOLO_HANDOFF,)}
 # The time from a connection's last answer to its next request's arrival at
 # the REST layer (`rest/app.py`): the client's turnaround, the socket,
 # aiohttp's parsing and the event loop's delay in starting the handler.

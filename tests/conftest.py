@@ -49,6 +49,11 @@ STALE = {
         "PR 38 appended its configuration, cell and metric after PR 35's, "
         "which this test holds to stand last; a benchmark PR re-bases it "
         "(PERF.md section 7)",
+    "tests/benchmark/test_host_stage_readers.py::"
+    "test_the_new_metrics_are_appended_and_every_cell_resolves_them":
+        "solo.overlap_share and engine.handoff_ms were appended after the "
+        "eleven readers this test holds to stand last; a change to the "
+        "benchmark's own files re-bases it (PERF.md section 7)",
 }
 
 
