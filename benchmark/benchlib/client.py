@@ -10,9 +10,6 @@ import json
 import threading
 import time
 
-import numpy as np
-
-from .corpus import Corpus, bulk_payload
 from .stats import Request
 
 JSON = {"Content-Type": "application/json"}
@@ -47,21 +44,18 @@ class Client:
         return next(iter(self.call("GET", "/_nodes/stats")["nodes"].values()))
 
 
-def load(c: Client, index: str, corpus: Corpus, field: str, bulk_docs: int,
-         number_of_shards: int, say) -> dict:
-    """Create the index, `_bulk` every document, `_refresh`. Raises on an
-    unacknowledged item or a failed shard. -> seconds of each phase."""
-    c.call("PUT", f"/{index}", {
-        "settings": {"number_of_shards": number_of_shards,
-                     "number_of_replicas": 0},
-        "mappings": {"properties": {field: {"type": "text"}}}})
-    words = np.array([f"t{i}" for i in range(corpus.vocab)])
-    n = corpus.n_docs
+def load(c: Client, index: str, create: dict, n: int, payload, bulk_docs: int,
+         say) -> dict:
+    """Create the index with the body `create`, `_bulk` documents 0..n-1 in
+    requests of `bulk_docs` (`payload(lo, hi)` makes each body), `_refresh`.
+    Raises on an unacknowledged item or a failed shard. -> seconds of each
+    phase."""
+    c.call("PUT", f"/{index}", create)
     t0 = time.perf_counter()
     acked = 0
     with cf.ThreadPoolExecutor(1) as pool:  # build the next body meanwhile
         def body(lo):
-            return bulk_payload(corpus, field, lo, min(lo + bulk_docs, n), words)
+            return payload(lo, min(lo + bulk_docs, n))
         nxt = pool.submit(body, 0)
         for lo in range(0, n, bulk_docs):
             data = nxt.result()
@@ -87,7 +81,8 @@ def load(c: Client, index: str, corpus: Corpus, field: str, bulk_docs: int,
 
 class LoadGenerator:
     """Sends `POST /<index>/_search` for queries of the pool over `clients`
-    kept-alive connections, one thread each.
+    kept-alive connections, one thread each. `digest(answer)` gives what a
+    `Request` keeps of an answer from `took_ms` on (the cell's kind's).
 
     Closed loop (`rate` None): a client sends its next request when the last
     is answered, starting at its own offset (`pool / clients` apart).
@@ -95,11 +90,12 @@ class LoadGenerator:
     on a fixed schedule and are timed from when they were due.
     """
 
-    def __init__(self, port: int, index: str, bodies: list[bytes],
+    def __init__(self, port: int, index: str, bodies: list[bytes], digest,
                  clients: int, rate: float | None = None,
                  request_timeout: float = 120.0):
         self.port, self.path = port, f"/{index}/_search"
-        self.bodies, self.clients, self.rate = bodies, clients, rate
+        self.bodies, self.digest = bodies, digest
+        self.clients, self.rate = clients, rate
         self.request_timeout = request_timeout
 
     def _send(self, conn, q: int, sent: float, t_zero: float) -> Request:
@@ -114,11 +110,7 @@ class LoadGenerator:
         if resp.status != 200:
             return Request(q, sent, done, resp.status)
         try:
-            res = json.loads(raw)
-            hits = res["hits"]
-            return Request(q, sent, done, 200, float(res["took"]),
-                           [int(h["_id"]) for h in hits["hits"]],
-                           [h["_score"] for h in hits["hits"]], hits["total"])
+            return Request(q, sent, done, 200, *self.digest(json.loads(raw)))
         except (ValueError, KeyError, TypeError):
             return Request(q, sent, done, -1)  # 200 with an unreadable body
 

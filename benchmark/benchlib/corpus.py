@@ -1,9 +1,10 @@
-"""The one general generator: corpus and query pool from `--seed` and the
-parameters of a configuration file (`corpus`, `query`) and a traffic file
-(`pool`). Grown from chip_smoke.py's `build_corpus` / `sample_queries`
-(themselves bench.py's); the original stays where it is.
+"""The generator of the kind `bm25_match` (`kinds/bm25_match.py`): corpus and
+query pool from `--seed` and the parameters of a configuration file
+(`corpus`, `query`) and a traffic file (`pool`). Grown from chip_smoke.py's
+`build_corpus` / `sample_queries` (themselves bench.py's); the original stays
+where it is.
 
-Documents are token-id arrays; the loader turns id `i` into the word `t<i>`.
+Documents are token-id arrays; `bulk_payload` turns id `i` into the word `t<i>`.
 Corpus and queries come from two independent streams of the seed, so that
 the pool's size never changes the corpus.
 """

@@ -20,6 +20,7 @@ class Request:
     ids: list | None = None      # hits' _id as int, in rank order
     scores: list | None = None   # hits' _score
     total: object = None         # hits.total as answered
+    answer: object = None        # what else the cell's kind keeps of it
 
     @property
     def ok(self) -> bool:

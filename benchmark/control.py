@@ -46,8 +46,11 @@ def answers_of(ref: Reference, pool, queries, k: int) -> list[Request]:
 
 
 def reference_control(spec: dict, seed: int, precision: str = "bf16") -> dict:
+    """The control of the kind `bm25_match` (the only kind it knows)."""
     config, traffic = spec["config"], spec["traffic"]
-    corpus, pool, _ = harness.make_inputs(config, traffic, seed)
+    kind = harness.kind_module(HERE, harness.DEFAULT_KIND)
+    corpus = kind.corpus(seed, config)
+    pool = kind.pool(seed, corpus, config, traffic)
     k = int(config["search"]["size"])
     shards = int(config["number_of_shards"])
     sample = cmp.draw_sample(seed, list(range(len(pool))), pool,

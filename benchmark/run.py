@@ -17,7 +17,10 @@ compare the window's own answers with the NumPy reference -> last line.
 
 A cell is data: `BENCHMARK.json` names its configuration and traffic mix, and
 `benchmark/configs/<config>.json`, `benchmark/traffic/<traffic>.json` and
-`benchmark/layer_metrics/<metric>.py` are found by those names (README.md).
+`benchmark/layer_metrics/<metric>.py` are found by those names; the
+configuration's `kind` names `benchmark/kinds/<kind>.py`, which makes the
+corpus, the pool, the bodies and the index, keeps what an answer says and
+judges the answers (README.md). Nothing here knows one kind from another.
 """
 
 from __future__ import annotations
@@ -39,15 +42,16 @@ ROOT = os.path.dirname(HERE)
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
-from benchlib import compare as cmp  # noqa: E402
-from benchlib import corpus as gen  # noqa: E402
 from benchlib import stats, trace  # noqa: E402
 from benchlib.client import LoadGenerator, load  # noqa: E402
-from benchlib.reference import Reference  # noqa: E402
 from benchlib.server import Server  # noqa: E402
 
 INDEX = "c1"
 CAPTURE_S = 3.0  # the traced part of a --trace 1 window, at its end
+DEFAULT_KIND = "bm25_match"  # a configuration that names none
+# what a kind module provides (README.md, "A kind of deployment")
+KIND_SEAMS = ("corpus", "pool", "body", "index_body", "bulk", "digest",
+              "judge")
 
 
 def say(msg: str) -> None:
@@ -72,8 +76,8 @@ def read_json(path: str) -> dict:
 
 
 def resolve(spec_root: str, workload: str) -> dict:
-    """-> {"cell", "config", "traffic", "bench_dir", "end_to_end", "per_layer"}
-    for one cell of <spec_root>/BENCHMARK.json."""
+    """-> {"cell", "config", "traffic", "kind", "bench_dir", "end_to_end",
+    "per_layer"} for one cell of <spec_root>/BENCHMARK.json."""
     bench = read_json(os.path.join(spec_root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -96,10 +100,23 @@ def resolve(spec_root: str, workload: str) -> dict:
     def mine(metric):
         return workload in metric.get("workloads", [workload])
 
+    bench_dir = os.path.join(spec_root, bench_dir)
     return {"cell": cell, "config": config, "traffic": traffic,
-            "bench_dir": os.path.join(spec_root, bench_dir),
+            "kind": kind_module(bench_dir, config.get("kind", DEFAULT_KIND)),
+            "bench_dir": bench_dir,
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
+
+
+def load_module(path: str, prefix: str, name: str):
+    """The module at `path`, under the name `prefix` + `name` in
+    `sys.modules` (where a dataclass of it looks itself up)."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def layer_reader(bench_dir: str, name: str):
@@ -107,11 +124,22 @@ def layer_reader(bench_dir: str, name: str):
     path = os.path.join(bench_dir, "layer_metrics", name + ".py")
     if not os.path.isfile(path):
         raise BenchError(f"per-layer metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, "layer_metric_", name).read
+
+
+def kind_module(bench_dir: str, name: str):
+    """The module benchmark/kinds/<name>.py, which provides every one of
+    KIND_SEAMS."""
+    path = os.path.join(bench_dir, "kinds", name + ".py")
+    if not os.path.isfile(path):
+        there = sorted(f[:-3] for f in os.listdir(os.path.dirname(path))
+                       if f.endswith(".py"))
+        raise BenchError(f"no kind {name!r}: benchmark/kinds/ has {there}")
+    mod = load_module(path, "kind_", name)
+    lacks = [s for s in KIND_SEAMS if not callable(getattr(mod, s, None))]
+    if lacks:
+        raise BenchError(f"kind {name!r} ({path}) lacks {lacks}")
+    return mod
 
 
 def device_of(node: dict, peaks: dict, chips: int, require_chip: bool) -> dict:
@@ -192,20 +220,16 @@ class Run:
         self.after: dict = {}                     # ... and after it
         self.setup: dict = {}                     # load_s, refresh_s, docs
         self.peak: dict = {}                      # peaks.json row of the device
-        self.pool: list[list[int]] = []
+        self.pool: list = []
         self.corpus = None
+        self.kind = None                          # the cell's kind module
         self._df = None
 
     def df(self):
         """[vocab] documents that hold each term, from the generator's own
-        arrays."""
+        arrays: the kind's `df(corpus)`."""
         if self._df is None:
-            import numpy as np
-
-            c = self.corpus
-            doc = np.repeat(np.arange(c.n_docs, dtype=np.int64), c.lens)
-            pairs = np.unique(c.tok.astype(np.int64) * c.n_docs + doc)
-            self._df = np.bincount(pairs // c.n_docs, minlength=c.vocab)
+            self._df = self.kind.df(self.corpus)
         return self._df
 
 
@@ -220,12 +244,12 @@ def start_capture(client, run: Run) -> None:
         run.capture = {"error": f"{type(e).__name__}: {e}"}
 
 
-def make_inputs(config: dict, traffic: dict, seed: int):
-    """-> (corpus, pool, request bodies) of one seed."""
-    corpus = gen.build_corpus(seed, int(config["documents"]), config["corpus"])
-    pool = gen.build_pool(seed, corpus, config["query"], int(traffic["pool"]))
-    bodies = [json.dumps(gen.search_body(q, config["search"])).encode()
-              for q in pool]
+def make_inputs(spec: dict, seed: int):
+    """-> (corpus, pool, request bodies) of one seed, by the cell's kind."""
+    kind, config = spec["kind"], spec["config"]
+    corpus = kind.corpus(seed, config)
+    pool = kind.pool(seed, corpus, config, spec["traffic"])
+    bodies = [json.dumps(kind.body(q, config)).encode() for q in pool]
     return corpus, pool, bodies
 
 
@@ -247,12 +271,15 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     if traced and seconds < 2 * CAPTURE_S:
         raise BenchError(f"--trace 1 needs --seconds >= {2 * CAPTURE_S:.0f}")
 
+    kind = spec["kind"]
     run = Run()
     run.seconds = float(seconds)
+    run.kind = kind
     t0 = time.perf_counter()
-    run.corpus, run.pool, bodies = make_inputs(config, traffic, seed)
-    say(f"corpus: {run.corpus.n_docs} docs, {len(run.corpus.tok)} tokens, "
-        f"pool {len(run.pool)}, seed {seed}, {time.perf_counter() - t0:.1f} s")
+    run.corpus, run.pool, bodies = make_inputs(spec, seed)
+    say(f"corpus: {run.corpus.n_docs} docs of kind "
+        f"{config.get('kind', DEFAULT_KIND)}, pool {len(run.pool)}, seed {seed}, "
+        f"{time.perf_counter() - t0:.1f} s")
 
     work = os.path.join(program_root, ".bench_work", workload)
     shutil.rmtree(work, ignore_errors=True)
@@ -274,9 +301,9 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
             settings["xpack.profiling.trace_dir"] = os.path.join(work, "trace")
         if settings:
             c.call("PUT", "/_cluster/settings", {"persistent": settings})
-        run.setup = load(c, INDEX, run.corpus, config["search"]["field"],
-                         int(config["bulk_docs"]),
-                         int(config["number_of_shards"]), say)
+        run.setup = load(c, INDEX, kind.index_body(config), run.corpus.n_docs,
+                         kind.bulk(run.corpus, config),
+                         int(config["bulk_docs"]), say)
         node = c.node_stats()
         fd = node["breakers"]["fielddata"]
         say(f"breaker: the packs charge {fd['estimated_size_in_bytes']} bytes "
@@ -285,8 +312,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
             held = pack_spread(node, int(config["chips"]), require_chip)
             say(f"spread: bytes held per device {held}")
 
-        lg = LoadGenerator(server.port, INDEX, bodies, int(traffic["clients"]),
-                           traffic.get("rate"))
+        lg = LoadGenerator(server.port, INDEX, bodies, kind.digest,
+                           int(traffic["clients"]), traffic.get("rate"))
         last = counters_of(c.node_stats())
         for n_pass in range(1, int(traffic["warmup_max_passes"]) + 1):
             t0 = time.perf_counter()
@@ -357,12 +384,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
 
     # the comparison, once the window has closed and the server is gone
     t0 = time.perf_counter()
-    ref = Reference(run.corpus.lens, run.corpus.tok,
-                    int(config["number_of_shards"]))
-    sample = cmp.draw_sample(seed, [r.query for r in run.requests if r.ok],
-                             run.pool, int(traffic["check_sample"]))
-    verdict = cmp.compare(ref, run.pool, run.requests, sample,
-                          int(config["search"]["size"]), config["limits"])
+    verdict = kind.judge(seed, run.corpus, run.pool, run.requests, config,
+                         traffic)
     say(f"reference: {verdict['compared']} answers to {verdict['queries']} "
         f"queries compared in {time.perf_counter() - t0:.1f} s")
 

@@ -64,8 +64,8 @@ def check_contract_shape(root: str) -> None:
 
 
 def check_cell(root: str, cell: str) -> None:
-    """The cell resolves to its files, and the files say what any cell's
-    must."""
+    """The cell resolves to its files, its configuration's kind to a module
+    that provides every seam, and the files say what any cell's must."""
     spec = harness.resolve(root, cell)
     config, traffic = spec["config"], spec["traffic"]
     assert config["chips"] == spec["cell"]["chips"] and config["chips"] in (1, 4)
@@ -80,6 +80,9 @@ def check_cell(root: str, cell: str) -> None:
     assert traffic["pool"] >= traffic["check_sample"] >= 1
     assert traffic["rate"] is None or traffic["rate"] > 0
     assert {m["name"] for m in spec["end_to_end"]} == END_TO_END
+    assert NAME.match(config.get("kind", harness.DEFAULT_KIND))
+    for seam in harness.KIND_SEAMS:
+        assert callable(getattr(spec["kind"], seam, None)), seam
     assert spec["per_layer"]
     for m in spec["per_layer"]:
         assert callable(harness.layer_reader(spec["bench_dir"], m["name"]))

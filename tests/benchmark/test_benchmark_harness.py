@@ -222,7 +222,8 @@ def test_files_alone_add_a_cell_and_the_resolver_finds_them(scratch_root):
     # no list: every cell, so the new one has the stage split from its first run
     assert {"engine.took_mean_ms", "postings_roofline", "engine.dispatch_ms",
             "device.topk_ms"} <= set(names)
-    assert names[:-1] == [m["name"] for m in bench_json()["per_layer"]]
+    assert names[:-1] == [m["name"] for m in bench_json()["per_layer"]
+                          if "workloads" not in m]
     old = harness.resolve(scratch_root, "passage.solo.c1")
     assert "scratch.answers_per_client" not in [m["name"] for m in old["per_layer"]]
 
@@ -271,8 +272,8 @@ def test_the_repos_own_file_grown_by_files_alone_passes_every_check(grown_root):
     assert spec["config"]["documents"] == 1179648 and spec["cell"]["chips"] == 4
     # the cell reports what every cell does, and its own metric besides
     names = [m["name"] for m in spec["per_layer"]]
-    assert names == [m["name"] for m in bench_json()["per_layer"]] + [
-        "scratch.busy_skew"]
+    assert names == [m["name"] for m in bench_json()["per_layer"]
+                     if "workloads" not in m] + ["scratch.busy_skew"]
     run = harness.Run()
     read = harness.layer_reader(spec["bench_dir"], "scratch.busy_skew")
     assert read(run) is None

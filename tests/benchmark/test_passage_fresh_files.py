@@ -29,6 +29,10 @@ SAME = ("corpus", "query", "search", "bulk_docs", "documents",
         "source_documents", "reduced", "precision", "guarantees", "limits",
         "chips", "number_of_shards")
 ROWS, PADDED = "es.search.solo.rows", "es.search.solo.padded_rows"
+# the cells accepted when the metric was added: each of them reports it,
+# whether it has no `workloads` list or a list that names them
+ACCEPTED = ("passage.solo.c1", "passage.solo.c8", "passage-4chip.solo.c8",
+            "passage.wave.c64", CELL)
 
 
 def _config(name: str = CONFIG) -> dict:
@@ -116,15 +120,12 @@ def test_the_mix_is_eight_callers_on_a_pool_no_window_replays():
 
 
 def test_the_metric_is_declared_and_every_cell_resolves_it():
-    """No list (two accepted tests hold a scratch cell's metrics against the
-    whole of the repo's `per_layer`), so every cell reports it: 0 where no
-    solo search ran (the wave cell)."""
+    """Every cell accepted with it reports it (it came with no list): 0 where
+    no solo search ran (the wave cell)."""
     want = {"name": METRIC, "unit": "%", "better": "lower",
             "source": "program_counter", "layer": "device programs",
             "moves": "search_qps"}
-    checks.check_declared(REPO, want, checks.cells_of())
-    entry = next(m for m in bench_json()["per_layer"] if m["name"] == METRIC)
-    assert "workloads" not in entry
+    checks.check_declared(REPO, want, ACCEPTED)
 
 
 # -- the reader ----------------------------------------------------------------
